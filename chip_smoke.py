@@ -1,0 +1,477 @@
+"""chip_smoke.py — the quickest proof that lodestar-tpu still starts on the chip.
+
+Drives the served verify path and the state-root path once, through the
+entry points a deployment uses and at the sizes the reference runs, on
+whatever accelerator JAX finds, and checks every answer against a plain
+CPU reference (the pure-Python BLS oracle, hashlib):
+
+1. a beacon node booted with default options (`BeaconNode.init`) must
+   resolve a device verifier and a device hasher;
+2. every verify program that can serve a verdict by default (the single
+   launch, and the staged three-jit schedule over fused device prep)
+   must agree with the oracle on a valid, a tampered and a structurally
+   invalid batch at both size classes the run dispatches (128, 512);
+3. the offload server as `offload.server.main()` builds its backend,
+   behind real gRPC on localhost, answers eight concurrent 128-set jobs,
+   one tampered job and one malformed job through `BlsOffloadClient`;
+4. the node's own `BlsDeviceVerifierPool` takes 1,024 sets at
+   gossip-attestation priority, so packages reach the 512-set cap;
+5. `merkle_root_device` at 2^20 chunks (the 1M-validator shape) and
+   `DirtyCollector` flushes of a 2^20-leaf stack with 512 and 2^17 dirty
+   leaves must give hashlib's roots with `backend == "device"`;
+6. no fallback, degradation or wedge counter may have moved, and the
+   launch ledger must name the programs and size classes that ran.
+
+One process holds the chip (the server runs on threads). Any failed
+check or any exception exits non-zero; so does a host where JAX finds no
+accelerator. The last line of standard output is
+`{"ok": true, "device": {"platform", "kind", "count"}}`.
+
+    python3 chip_smoke.py [--seed N]
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import concurrent.futures
+import faulthandler
+import hashlib
+import json
+import os
+import random
+import sys
+import time
+
+SIZE_CLASSES = (128, 512)  # MAX_SIGNATURE_SETS_PER_JOB and the pool's package cap
+TREE_DEPTH = 20  # 2^20 chunks: BASELINE config 4, the 1M-validator shape
+DIRTY_COUNTS = (512, 1 << 17)
+ZERO_COUNTERS = (
+    "lodestar_bls_prep_fallback_total",
+    "lodestar_bls_single_launch_fallback_total",
+    "lodestar_ssz_htr_fallback_total",
+    "lodestar_kzg_device_fallback_total",
+    "lodestar_resilience_fallback_total",
+    "lodestar_resilience_fallback_skipped_total",
+    "lodestar_sched_lane_wedge_trips_total",
+)
+
+
+class SmokeFailure(Exception):
+    pass
+
+
+def check(cond, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+def log(msg: str) -> None:
+    print(f"[chip_smoke +{time.monotonic() - _T0:6.1f}s] {msg}", file=sys.stderr, flush=True)
+
+
+_T0 = time.monotonic()
+
+
+# --- data, made from the seed -------------------------------------------------
+
+
+def make_batches(seed: int) -> dict:
+    """The valid / tampered / structurally invalid batch per size class,
+    plus the served path's malformed job. Signing is pure Python, so 128
+    distinct sets are made once and reused: the 512 batch is four copies
+    (every launch draws fresh blinding, so copies do not cancel)."""
+    from lodestar_tpu.crypto.bls import curve, fields, serdes
+    from lodestar_tpu.crypto.bls.api import SignatureSet
+    from lodestar_tpu.models.batch_verify import make_synthetic_sets
+
+    base = make_synthetic_sets(SIZE_CLASSES[0], seed=seed)
+    rng = random.Random(seed)
+    # a pubkey on the curve but outside the r-subgroup: right length,
+    # right flags, so only the subgroup check (on the device) rejects it
+    while True:
+        x = rng.randrange(fields.P)
+        y = fields.fp_sqrt((x * x * x + 4) % fields.P)
+        if y is not None and not curve.g1_in_subgroup((x, y)):
+            off_subgroup_pk = serdes.g1_to_bytes((x, y))
+            break
+    batches = {}
+    for size in SIZE_CLASSES:
+        valid = base * (size // len(base))
+        k = rng.randrange(size)
+        tampered = list(valid)
+        tampered[k] = SignatureSet(
+            pubkey=valid[k].pubkey,
+            message=valid[k].message,
+            signature=valid[(k + 1) % len(base)].signature,
+        )
+        j = rng.randrange(size)
+        invalid = list(valid)
+        invalid[j] = SignatureSet(
+            pubkey=off_subgroup_pk, message=valid[j].message, signature=valid[j].signature
+        )
+        batches[size] = {"valid": valid, "tampered": tampered, "invalid": invalid}
+    malformed = list(base)
+    malformed[rng.randrange(len(base))] = SignatureSet(
+        pubkey=b"\x00" * 48, message=base[0].message, signature=b"\xff" * 96
+    )
+    batches["malformed"] = malformed
+    return batches
+
+
+def oracle_verdicts(batches: dict) -> dict:
+    """The CPU oracle's verdict for every batch the run submits."""
+    from lodestar_tpu.crypto.bls.api import verify_signature_sets
+
+    out = {"malformed": verify_signature_sets(batches["malformed"])}
+    for size in SIZE_CLASSES:
+        for kind, sets in batches[size].items():
+            out[(size, kind)] = verify_signature_sets(sets)
+    return out
+
+
+def hashlib_root(leaves: bytes) -> bytes:
+    level = leaves
+    while len(level) > 32:
+        level = b"".join(
+            hashlib.sha256(level[i : i + 64]).digest() for i in range(0, len(level), 64)
+        )
+    return level
+
+
+# --- phases -------------------------------------------------------------------
+
+
+async def boot_node():
+    """A node with default options, as `python -m lodestar_tpu beacon`
+    builds it (REST off, manual clock: nothing here needs either)."""
+    from lodestar_tpu import params
+    from lodestar_tpu.config import minimal_chain_config
+    from lodestar_tpu.node import BeaconNode, BeaconNodeOptions
+    from lodestar_tpu.state_transition.genesis import create_interop_genesis_state
+
+    params.set_active_preset("minimal")
+    p = params.active_preset()
+    far = 2**64 - 1
+    cc = minimal_chain_config().replace(
+        ALTAIR_FORK_EPOCH=far, BELLATRIX_FORK_EPOCH=far,
+        CAPELLA_FORK_EPOCH=far, DENEB_FORK_EPOCH=far,
+    )
+    genesis = create_interop_genesis_state(8, p=p, genesis_fork_version=cc.GENESIS_FORK_VERSION)
+    return await BeaconNode.init(
+        anchor_state=genesis,
+        chain_config=cc,
+        opts=BeaconNodeOptions(rest_enabled=False, manual_clock=True),
+        p=p,
+        time_fn=lambda: 0.0,
+    )
+
+
+def phase_programs(batches: dict, oracle) -> dict:
+    """Every default verify program against the oracle (ROADMAP S1 gate)."""
+    from lodestar_tpu.models import batch_verify as bv
+
+    check(bv.single_launch_active(), "single launch does not resolve active by default here")
+    check(bv.device_prep_active(), "device prep does not resolve active by default here")
+    programs = {
+        "single_launch": bv.verify_sets_single_launch,
+        "staged_fused_prep": bv._verify_sets_split,
+    }
+    out = {}
+    for size in SIZE_CLASSES:
+        for name, fn in programs.items():
+            for kind, sets in batches[size].items():
+                t0 = time.monotonic()
+                got = bool(fn(sets))
+                secs = time.monotonic() - t0
+                want = oracle()[(size, kind)]
+                log(f"program {name}/{size}/{kind}: {got} (oracle {want}) {secs:.1f}s")
+                check(got == want, f"{name} at {size} on the {kind} batch: {got}, oracle {want}")
+                out[f"{name}/{size}/{kind}"] = got
+    return out
+
+
+async def phase_served(backend, batches: dict, oracle) -> dict:
+    """The offload host's default backend behind real gRPC."""
+    from lodestar_tpu.offload.client import BlsOffloadClient
+    from lodestar_tpu.offload.server import BlsOffloadServer
+
+    check(backend.description["verifier"] == "device",
+          f"offload server resolved {backend.description}")
+    server = BlsOffloadServer(
+        backend.verify, port=0, chip_status_fn=backend.chip_status_fn
+    )
+    server.start()
+    client = BlsOffloadClient(f"127.0.0.1:{server.port}")
+    size = SIZE_CLASSES[0]
+    jobs = [("valid", batches[size]["valid"])] * 8 + [
+        ("tampered", batches[size]["tampered"]),
+        ("malformed", batches["malformed"]),
+    ]
+    try:
+        got = await asyncio.gather(*(client.verify_signature_sets(s) for _, s in jobs))
+    finally:
+        await client.close()
+        server.stop()
+    for (kind, _), verdict in zip(jobs, got):
+        want = oracle()["malformed" if kind == "malformed" else (size, kind)]
+        check(verdict == want, f"served {kind} job: {verdict}, oracle {want}")
+    log(f"served path: {len(jobs)} jobs, verdicts {[bool(v) for v in got]}")
+    return {"description": backend.description, "verdicts": [bool(v) for v in got],
+            "lanes": backend.mesh.lane_states()}
+
+
+async def phase_node_pool(node, batches: dict, oracle) -> dict:
+    """1,024 sets in flight on the node's own pool."""
+    from lodestar_tpu.chain.bls import BlsDeviceVerifierPool, VerifySignatureOpts
+    from lodestar_tpu.scheduler import PriorityClass
+
+    pool = node.bls
+    check(isinstance(pool, BlsDeviceVerifierPool), f"node verifier is {type(pool).__name__}")
+    cap = SIZE_CLASSES[1]
+    # eight 128-set jobs; the pool packages them 512 at a time, and each
+    # package is exactly the valid 512 batch the oracle judged
+    sets = batches[cap]["valid"] * 2
+    got = await pool.verify_signature_sets(
+        sets, VerifySignatureOpts(batchable=True, priority=PriorityClass.GOSSIP_ATTESTATION)
+    )
+    want = oracle()[(cap, "valid")]
+    check(got == want, f"node pool on {len(sets)} sets: {got}, oracle {want}")
+    m = dict(pool.metrics)
+    check(m["sig_sets_started"] == len(sets), f"pool started {m['sig_sets_started']} sets")
+    check(m["batch_sigs_success"] == len(sets), f"pool batch path served {m}")
+    for key in ("errors", "batch_retries", "sharded_fallbacks"):
+        check(m[key] == 0, f"pool {key} = {m[key]}")
+    log(f"node pool: {len(sets)} sets -> {got}; {m}")
+    return {"verdict": bool(got), "metrics": m, "lanes": pool.mesh.lane_states()}
+
+
+def phase_state_root(seed: int) -> dict:
+    import jax
+    import numpy as np
+
+    from lodestar_tpu.ops import sha256 as S
+    from lodestar_tpu.ssz.device_htr import DirtyCollector
+    from lodestar_tpu.ssz.hash import hash_nodes_cpu
+
+    n = 1 << TREE_DEPTH
+    rng = np.random.default_rng(seed)
+    leaves = rng.integers(0, 256, size=(n, 32), dtype=np.uint8)
+    want = hashlib_root(leaves.tobytes())
+    t0 = time.monotonic()
+    got = S.bytes_from_words(np.asarray(S.merkle_root_device(
+        jax.device_put(S.words_from_bytes(leaves.tobytes()))
+    )))
+    check(got == want, f"merkle_root_device at 2^{TREE_DEPTH} chunks differs from hashlib")
+    out = {"merkle_root_device_s": round(time.monotonic() - t0, 2), "flushes": []}
+    log(f"merkle_root_device 2^{TREE_DEPTH}: equal to hashlib")
+
+    # the retained level stack a state tracker holds, built on the CPU path
+    levels = [leaves]
+    while levels[-1].shape[0] > 1:
+        levels.append(hash_nodes_cpu(levels[-1]).copy())  # the collector writes into it
+    check(levels[-1][0].tobytes() == want, "CPU level stack root differs from hashlib")
+    for count in DIRTY_COUNTS:
+        dirty = rng.choice(n, size=count, replace=False)
+        leaves[dirty] = rng.integers(0, 256, size=(count, 32), dtype=np.uint8)
+        want = hashlib_root(leaves.tobytes())
+        coll = DirtyCollector()
+        coll.add_stack_job(levels, dirty)
+        stats = coll.flush()
+        check(stats["backend"] == "device", f"collector flush backend {stats['backend']}")
+        check(levels[-1][0].tobytes() == want,
+              f"collector flush with {count} dirty leaves differs from hashlib")
+        stats["seconds"] = round(stats["seconds"], 3)
+        out["flushes"].append(stats)
+        log(f"collector flush, {count} dirty leaves: {stats}")
+    # the sparse flush stays on the host by size (every level under the
+    # device threshold); the wide one must really have launched
+    check(out["flushes"][-1]["launches"] > 0, "the 2^17-leaf flush launched nothing")
+    return out
+
+
+def phase_lanes(backend_mesh, pool_mesh, batches: dict) -> dict:
+    """More than one chip: every lane served, and lane i's arrays lived
+    on device i (only its allocation count moves while it verifies)."""
+    import jax
+
+    devices = jax.devices()
+    out = {}
+    for name, mesh in (("server", backend_mesh), ("pool", pool_mesh)):
+        check(len(mesh) == len(devices), f"{name} mesh has {len(mesh)} lanes")
+        for lane in mesh.lanes:
+            before = [d.memory_stats()["num_allocs"] for d in devices]
+            check(lane.verify_fn(batches[SIZE_CLASSES[0]]["valid"]), f"{lane.label} rejected")
+            moved = [
+                i for i, d in enumerate(devices)
+                if d.memory_stats()["num_allocs"] != before[i]
+            ]
+            check(moved == [lane.index], f"{name} {lane.label} allocated on devices {moved}")
+        out[name] = [lane.label for lane in mesh.lanes]
+    return out
+
+
+def ledger_summary() -> dict:
+    """Programs and size classes that ran: first call (set-up: trace +
+    compile or cache load) against later calls, from the launch ledger."""
+    from lodestar_tpu import telemetry
+
+    out: dict = {}
+    for e in telemetry.launch_ledger():
+        row = out.setdefault(
+            f"{e['program']}/{e['size_class']}",
+            {"first_call_s": 0.0, "first_calls": 0, "cached_calls": 0},
+        )
+        if e["compile"]:
+            row["first_calls"] += 1
+            row["first_call_s"] = round(row["first_call_s"] + e["seconds"], 2)
+        else:
+            row["cached_calls"] += 1
+    return out
+
+
+def counter_totals(registry) -> dict:
+    """Each watched counter summed over its label sets."""
+    totals = dict.fromkeys(ZERO_COUNTERS, 0.0)
+    for family in registry.collect():
+        for sample in family.samples:
+            if sample.name in totals:
+                totals[sample.name] += sample.value
+    return totals
+
+
+# --- main ---------------------------------------------------------------------
+
+
+async def run(seed: int, device: dict, compile_stats: dict) -> dict:
+    from lodestar_tpu import native, telemetry
+    from lodestar_tpu.native import bls as native_bls
+    from lodestar_tpu.offload.server import build_backend
+    from lodestar_tpu.ops import fp_pallas
+
+    check("LODESTAR_FP_PALLAS" not in os.environ, "LODESTAR_FP_PALLAS is set")
+    check(fp_pallas.use_pallas(), "use_pallas() is false on this backend")
+    report: dict = {
+        "native": {"bls": native_bls.available(), "sha256": native.sha256_backend()},
+    }
+
+    node = await boot_node()
+    try:
+        # room for every dispatch of the run (the default bounds a slot)
+        telemetry.configure_launch_telemetry(ledger_size=4096)
+        report["node"] = node.device_runtime
+        check(node.device_runtime["verifier"] == "device", f"node: {node.device_runtime}")
+        check(node.device_runtime["hasher"] == "device", f"node: {node.device_runtime}")
+        check(
+            (node.device_runtime["platform"], node.device_runtime["count"])
+            == (device["platform"], device["count"]),
+            f"node saw {node.device_runtime}, smoke saw {device}",
+        )
+
+        batches = make_batches(seed)
+        log("signature sets made")
+        # the oracle is pure Python: it works on a thread while the
+        # device programs compile, and every comparison waits for it
+        with concurrent.futures.ThreadPoolExecutor(1) as ex:
+            oracle = ex.submit(oracle_verdicts, batches).result
+            report["programs"] = phase_programs(batches, oracle)
+            backend = build_backend()  # default flags, as server.main() does
+            report["served"] = await phase_served(backend, batches, oracle)
+            report["node_pool"] = await phase_node_pool(node, batches, oracle)
+        report["oracle"] = {str(k): v for k, v in oracle().items()}
+        report["state_root"] = phase_state_root(seed)
+        if device["count"] > 1:
+            report["lanes"] = phase_lanes(backend.mesh, node.bls.mesh, batches)
+
+        report["counters"] = counter_totals(node.metrics.creator.registry)
+        for name, value in report["counters"].items():
+            check(value == 0, f"{name} = {value}")
+        for lanes in (report["served"]["lanes"], report["node_pool"]["lanes"]):
+            for lane in lanes:
+                check(lane["wedge_trips"] == 0 and not lane["wedged"], f"lane {lane}")
+    finally:
+        await node.close()
+
+    report["ledger"] = ledger_summary()
+    ran = set(report["ledger"])
+    for size in SIZE_CLASSES:
+        for program in ("_single_launch_verify", "_prep_field_stage", "_prep_subgroup_stage",
+                        "hash_finish", "batch_verify_staged", "bls_lane_verify"):
+            check(f"{program}/{size}" in ran, f"ledger has no {program}/{size}: {sorted(ran)}")
+    check(f"_merkle_root_fixed/{1 << TREE_DEPTH}" in ran, f"ledger has no merkle root: {sorted(ran)}")
+    check(any(k.startswith("merkle_level/") for k in ran), "ledger has no merkle_level launch")
+    report["compile"] = {k: round(v, 1) for k, v in compile_stats.items()}
+    return report
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=21)
+    args = ap.parse_args(argv)
+    # a hung chip must not outlive the 1200 s the contract allows
+    faulthandler.dump_traceback_later(1170, exit=True)
+
+    import jax
+
+    from lodestar_tpu.utils import enable_compile_cache, probe_accelerator
+
+    cache_dir = enable_compile_cache()
+    # persistent-cache traffic and time inside backend compilation, as
+    # JAX itself reports them
+    stats = {"requests": 0, "persistent_hits": 0, "persistent_writes": 0, "backend_compile_s": 0.0}
+    events = {
+        "/jax/compilation_cache/compile_requests_use_cache": "requests",
+        "/jax/compilation_cache/cache_hits": "persistent_hits",
+        "/jax/compilation_cache/cache_misses": "persistent_writes",
+    }
+
+    def on_event(event, **_):
+        if event in events:
+            stats[events[event]] += 1
+
+    def on_duration(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            stats["backend_compile_s"] += duration
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+    accel = probe_accelerator()
+    device = {"platform": accel["platform"], "kind": accel["device_kind"], "count": accel["count"]}
+    if device["platform"] != "tpu":
+        print(
+            f"chip_smoke: no chip found: JAX's default backend is {device['platform']!r} "
+            f"({device['kind']}); this check only means something on the accelerator",
+            file=sys.stderr,
+        )
+        return 2
+    log(f"device {device}, jax {jax.__version__}, compile cache {cache_dir}")
+
+    report = asyncio.run(run(args.seed, device, stats))
+    report.update(
+        jax=jax.__version__, seed=args.seed, cache_dir=cache_dir,
+        wall_s=round(time.monotonic() - _T0, 1),
+    )
+    out_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)), "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "chip_smoke_report.json"), "a") as f:
+        f.write(json.dumps(report) + "\n")
+    summary = {
+        "jax": report["jax"],
+        "device": device,
+        "wall_s": report["wall_s"],
+        "compile": report["compile"],
+        # program/size class: [set-up seconds of first calls, cached calls]
+        "ledger": {k: [v["first_call_s"], v["cached_calls"]] for k, v in sorted(report["ledger"].items())},
+        "node": report["node"],
+        "server": report["served"]["description"],
+        "counters_zero": sorted(report["counters"]),
+        "native": report["native"],
+    }
+    print(json.dumps(summary))
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,8 +1,8 @@
 """Verifier mesh: per-device launch lanes behind one verifier pool.
 
 The device core passed the 8-device dryrun (`verify_signature_sets_sharded`,
-MULTICHIP_r0*.json) but until PR 8 the production pool drove one chip.
-This module is the mesh's serving shape:
+`__graft_entry__.dryrun_multichip`) but until PR 8 the production pool
+drove one chip. This module is the mesh's serving shape:
 
 * `MeshLane` — one chip: its own verify callable, its own EWMA
   `OccupancyTracker`, and its own wedge `CircuitBreaker` so a sick
@@ -15,11 +15,12 @@ This module is the mesh's serving shape:
   occupancy over *available* chips and the per-chip table (a wedged
   chip drops out of the advertised capacity).
 * `build_device_mesh` — production construction from the models layer's
-  device enumeration. `"auto"` engages only when the Pallas backend is
-  live AND more than one device is visible (the same doctrine as
+  device enumeration. `"auto"` engages only when the backend is a TPU
+  AND more than one device is visible (the same doctrine as
   `--bls-device-prep auto`): on the CPU-forced 8-device test platform
   auto stays single-lane, so a default pool behaves exactly like the
-  pre-mesh code unless a test asks for the mesh explicitly.
+  pre-mesh code unless a test asks for the mesh explicitly. A backend
+  that cannot initialise raises — it is never read as "one CPU lane".
 
 Placement policy lives in the pool (`chain/bls/pool.py`): latency-class
 work dequeues to the least-occupied free lane; bulk work shards across
@@ -346,69 +347,46 @@ def build_device_mesh(
 ) -> VerifierMesh:
     """Production mesh from the models layer's device enumeration.
 
-    mode "off" (or any enumeration problem, or a single visible device)
-    yields the single-lane shape around `fallback_verify_fn` (default:
+    mode "off" (or a single visible device) yields the single-lane
+    shape around `fallback_verify_fn` (default:
     `verify_signature_sets_device`) — bit-identical to the pre-mesh
-    pool. mode "auto" requires the Pallas backend live (same doctrine
-    as device prep auto); mode "on" forces the mesh whenever more than
-    one device is visible."""
+    pool. mode "auto" requires the TPU backend (same doctrine as device
+    prep auto); mode "on" forces the mesh whenever more than one device
+    is visible. Import and backend-initialisation errors propagate: the
+    caller asked for a device verifier, and a host whose chip is taken
+    must say so instead of serving something else unseen."""
     if mode not in MESH_MODES:
         raise ValueError(f"bls_mesh must be one of {MESH_MODES}, got {mode!r}")
+    from lodestar_tpu.models import batch_verify as bv
 
     def _single() -> VerifierMesh:
-        fn = fallback_verify_fn
-        prepared_fn = None
-        single_fn = None
-        if fn is None:
-            try:
-                from lodestar_tpu.models.batch_verify import (
-                    verify_prepared,
-                    verify_sets_single_launch,
-                    verify_signature_sets_device,
-                )
-
-                fn = verify_signature_sets_device
-                prepared_fn = verify_prepared
-                single_fn = verify_sets_single_launch
-            except Exception:
-                # a host without a usable jax stack (the standalone
-                # offload server historically served the pure-CPU
-                # oracle) must degrade, not crash at startup
-                from lodestar_tpu.crypto.bls.api import verify_signature_sets
-
-                fn = verify_signature_sets
+        if fallback_verify_fn is not None:
+            return single_lane_mesh(fallback_verify_fn, wedge_threshold=wedge_threshold)
         return single_lane_mesh(
-            fn,
+            bv.verify_signature_sets_device,
             wedge_threshold=wedge_threshold,
-            verify_prepared_fn=prepared_fn,
-            verify_single_fn=single_fn,
+            verify_prepared_fn=bv.verify_prepared,
+            verify_single_fn=bv.verify_sets_single_launch,
         )
 
     if mode == "off":
         return _single()
-    try:
-        from lodestar_tpu.models import batch_verify as bv
+    if mode == "auto":
+        from lodestar_tpu.ops import fp_pallas
 
-        if mode == "auto":
-            from lodestar_tpu.ops import fp_pallas
-
-            if not fp_pallas.use_pallas():
-                return _single()
-        n = bv.mesh_device_count()
-        if n <= 1:
+        if not fp_pallas.use_pallas():
             return _single()
-        lanes = [
-            MeshLane(
-                i,
-                bv.make_lane_verify_fn(i),
-                wedge_threshold=wedge_threshold,
-                verify_prepared_fn=bv.make_lane_verify_prepared_fn(i),
-                verify_single_fn=bv.make_lane_verify_single_fn(i),
-            )
-            for i in range(n)
-        ]
-        return VerifierMesh(lanes, sharded_fn=bv.make_mesh_sharded_fn())
-    except Exception:
-        # enumeration failures must not take the verifier down — serve
-        # on the single-device path the pool always supported
+    n = bv.mesh_device_count()
+    if n <= 1:
         return _single()
+    lanes = [
+        MeshLane(
+            i,
+            bv.make_lane_verify_fn(i),
+            wedge_threshold=wedge_threshold,
+            verify_prepared_fn=bv.make_lane_verify_prepared_fn(i),
+            verify_single_fn=bv.make_lane_verify_single_fn(i),
+        )
+        for i in range(n)
+    ]
+    return VerifierMesh(lanes, sharded_fn=bv.make_mesh_sharded_fn())

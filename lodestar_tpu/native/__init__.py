@@ -16,44 +16,68 @@ Current components:
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 
 import numpy as np
 
-__all__ = ["sha256_available", "sha256_backend", "hash_pairs", "load_sha256"]
+__all__ = [
+    "build_shared_lib",
+    "sha256_available",
+    "sha256_backend",
+    "hash_pairs",
+    "load_sha256",
+]
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_DIR, "sha256_batch.cpp")
-_SO = os.path.join(_DIR, "libsha256batch.so")
+_CXX = ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread"]
 
 _lock = threading.Lock()
 _lib = None
 _load_failed = False
 
 
-def _build() -> bool:
-    """Compile the shared lib if missing or stale. Returns success."""
+def build_shared_lib(stem: str, sources: list[str], timeout_s: float) -> str | None:
+    """Path of the shared library built from `sources` (`sources[0]` is
+    compiled, the rest are headers it includes), compiling it when no
+    binary of this exact source content and command exists. The content
+    hash is part of the file name, so a copied or freshly checked-out
+    tree never loads a binary its sources did not produce, whatever the
+    mtimes say. None when the toolchain is missing or the build fails
+    (consumers fall back to the pure-Python paths and report it)."""
     try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-            return True
+        h = hashlib.sha256(" ".join(_CXX).encode())
+        for name in sources:
+            with open(os.path.join(_DIR, name), "rb") as f:
+                h.update(f.read())
+        so = os.path.join(_DIR, f"{stem}-{h.hexdigest()[:16]}.so")
+        if os.path.exists(so):
+            return so
         # pid-unique temp target: concurrent builders (multiple node
         # processes, pytest-xdist) must not publish each other's
         # half-written output through the shared rename
-        tmp = f"{_SO}.{os.getpid()}.tmp"
-        cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread", _SRC, "-o", tmp]
+        tmp = f"{so[:-3]}.{os.getpid()}.so.tmp"
         try:
-            res = subprocess.run(cmd, capture_output=True, timeout=120)
+            res = subprocess.run(
+                [*_CXX, os.path.join(_DIR, sources[0]), "-o", tmp],
+                capture_output=True,
+                timeout=timeout_s,
+            )
             if res.returncode != 0:
-                return False
-            os.replace(tmp, _SO)
+                return None
+            os.replace(tmp, so)
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
-        return True
+        for stale in glob.glob(os.path.join(_DIR, f"{stem}*.so")):
+            if stale != so:
+                os.unlink(stale)
+        return so
     except (OSError, subprocess.SubprocessError):
-        return False
+        return None
 
 
 def load_sha256():
@@ -66,11 +90,12 @@ def load_sha256():
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not _build():
+        so = build_shared_lib("libsha256batch", ["sha256_batch.cpp"], 120)
+        if so is None:
             _load_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
             lib.sha256_pairs.argtypes = [
                 ctypes.POINTER(ctypes.c_uint8),
                 ctypes.c_uint64,

@@ -9,11 +9,11 @@ pure-Python oracle as the correctness anchor.
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 
 import numpy as np
+
+from . import build_shared_lib
 
 __all__ = [
     "available",
@@ -23,37 +23,9 @@ __all__ = [
     "g2_decompress_check_native",
 ]
 
-_DIR = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_DIR, "bls_host.cpp")
-_HDR = os.path.join(_DIR, "bls_host_constants.h")
-_SO = os.path.join(_DIR, "libblshost.so")
-
 _lock = threading.Lock()
 _lib = None
 _load_failed = False
-
-
-def _build() -> bool:
-    try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= max(
-            os.path.getmtime(_SRC), os.path.getmtime(_HDR)
-        ):
-            return True
-        tmp = f"{_SO}.{os.getpid()}.tmp"
-        cmd = [
-            "g++", "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread", _SRC, "-o", tmp,
-        ]
-        try:
-            res = subprocess.run(cmd, capture_output=True, timeout=180)
-            if res.returncode != 0:
-                return False
-            os.replace(tmp, _SO)
-        finally:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
 
 
 def _load():
@@ -65,11 +37,14 @@ def _load():
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not _build():
+        so = build_shared_lib(
+            "libblshost", ["bls_host.cpp", "bls_host_constants.h"], 180
+        )
+        if so is None:
             _load_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
             u8p = ctypes.POINTER(ctypes.c_uint8)
             i32p = ctypes.POINTER(ctypes.c_int32)
             lib.bls_prepare_sets.argtypes = [

@@ -19,7 +19,7 @@ from lodestar_tpu.logger import get_logger
 from lodestar_tpu.metrics import BeaconMetrics, MetricsServer, create_metrics
 from lodestar_tpu.params import BeaconPreset, active_preset
 
-__all__ = ["BeaconNode", "BeaconNodeOptions"]
+__all__ = ["BeaconNode", "BeaconNodeOptions", "configure_device_runtime"]
 
 
 class BeaconNodeOptions:
@@ -31,7 +31,7 @@ class BeaconNodeOptions:
         rest_enabled: bool = True,
         metrics_port: int = 8008,
         metrics_enabled: bool = False,
-        use_device_verifier: bool = False,
+        use_device_verifier: bool | None = None,
         manual_clock: bool = False,
         p2p_enabled: bool = False,
         p2p_port: int = 0,
@@ -69,6 +69,10 @@ class BeaconNodeOptions:
         self.rest_enabled = rest_enabled
         self.metrics_port = metrics_port
         self.metrics_enabled = metrics_enabled
+        # local BLS verifier when no offload endpoint is given: None
+        # (the default) resolves from the backend node init observes —
+        # BlsDeviceVerifierPool on a TPU, BlsSingleThreadVerifier on a
+        # CPU; True/False force (tests boot the device pool on the CPU)
         self.use_device_verifier = use_device_verifier
         self.manual_clock = manual_clock
         self.p2p_enabled = p2p_enabled
@@ -246,6 +250,71 @@ class BeaconNodeOptions:
         self.slo_slack_floor_ms = slo_slack_floor_ms
 
 
+def _device_pool(opts: BeaconNodeOptions, metrics: BeaconMetrics):
+    """The in-process device verifier as this node's options shape it."""
+    from lodestar_tpu.chain.bls import BlsDeviceVerifierPool
+
+    return BlsDeviceVerifierPool(
+        scheduler_enabled=opts.scheduler_enabled,
+        sched_metrics=metrics.sched,
+        mesh_mode=opts.bls_mesh,
+        pipeline=opts.bls_pipeline,
+        pipeline_metrics=metrics.bls_pipeline,
+    )
+
+
+def configure_device_runtime(opts: BeaconNodeOptions, metrics: BeaconMetrics) -> dict:
+    """Observe the backend once and configure the process-global device
+    seams from it (they live in the model/ssz/ops layers, below any
+    node object): batch-verify prep placement and single-launch mode,
+    state hashTreeRoot placement, the KZG fallback counter and launch
+    telemetry, each with its metric family. Returns what the node logs
+    once at start: platform, device_kind, count, verifier, hasher.
+
+    A node that verifies through `--bls-offload` without a local device
+    fallback leaves the chip to the process that owns it: it
+    initialises no backend and every "auto" resolves to the host."""
+    from lodestar_tpu import telemetry
+    from lodestar_tpu.crypto.kzg import configure_kzg_fallback_counter
+    from lodestar_tpu.models.batch_verify import (
+        configure_device_prep,
+        configure_single_launch,
+    )
+    from lodestar_tpu.ssz.device_htr import configure_device_htr, device_htr_active
+    from lodestar_tpu.utils import probe_accelerator
+
+    owns_device = not opts.offload_endpoints or opts.offload_fallback == "device"
+    accel = (
+        probe_accelerator()
+        if owns_device
+        else {"platform": "none", "device_kind": "none", "count": 0}
+    )
+    on_tpu = accel["platform"] == "tpu"
+
+    configure_device_prep(mode=opts.bls_device_prep, metrics=metrics.bls_prep)
+    # single-launch mode rides the same seam; metrics shared with prep
+    configure_single_launch(mode=opts.bls_single_launch)
+    configure_device_htr(
+        mode=opts.htr_device, metrics=metrics.ssz_htr, accelerator=on_tpu
+    )
+    configure_kzg_fallback_counter(metrics.kzg.device_fallbacks)
+    telemetry.configure_launch_telemetry(
+        mode=opts.launch_telemetry, metrics=metrics.device_launch
+    )
+
+    if opts.offload_endpoints:
+        verifier = "offload+" + opts.offload_fallback
+    elif opts.use_device_verifier or (opts.use_device_verifier is None and on_tpu):
+        verifier = "device"
+    else:
+        verifier = "cpu"
+    return {
+        **accel,
+        "verifier": verifier,
+        "hasher": "device" if device_htr_active() else "cpu",
+    }
+
+
 class BeaconNode:
     def __init__(
         self, *, chain, clock, db, metrics, rest_server, metrics_server, bls, processor=None
@@ -259,6 +328,7 @@ class BeaconNode:
         self.bls = bls
         self.processor = processor
         self.network = None  # Libp2pBeaconNetwork when p2p is enabled
+        self.device_runtime: dict = {}  # configure_device_runtime's answer, set by init
         self._drain_task = None
         self.log = get_logger(name="lodestar.node")
 
@@ -341,46 +411,15 @@ class BeaconNode:
 
             _tracing.configure(lag_ms_supplier=lag_sampler.last_lag_ms)
 
-        # 2d. batch-verify input prep placement + lodestar_bls_prep_*
-        # metrics: process-global like the tracer (the prep runs inside
-        # the model layer, below any node object)
-        from lodestar_tpu.models.batch_verify import (
-            configure_device_prep,
-            configure_single_launch,
-        )
-
-        configure_device_prep(mode=opts.bls_device_prep, metrics=metrics.bls_prep)
-        # single-launch verification mode rides the same process-global
-        # seam (the router lives in the model layer, below any node
-        # object); metrics are shared with the prep family above
-        configure_single_launch(mode=opts.bls_single_launch)
-
-        # 2e. state hashTreeRoot placement + lodestar_ssz_htr_* metrics:
-        # process-global like the prep mode (the collector runs inside
-        # the ssz/state-transition layers, below any node object)
-        from lodestar_tpu.ssz.device_htr import configure_device_htr
-
-        configure_device_htr(mode=opts.htr_device, metrics=metrics.ssz_htr)
-
-        # KZG device-pairing degradation counter: process-global like the
-        # prep/HTR seams (the fallback happens inside crypto/kzg.py,
-        # below any node object)
-        from lodestar_tpu.crypto.kzg import configure_kzg_fallback_counter
-
-        configure_kzg_fallback_counter(metrics.kzg.device_fallbacks)
-
-        # 2f. device launch telemetry: mode + the lodestar_device_launch_*
-        # sink (process-global — the dispatch seams live in ops/ssz/mesh
-        # layers below any node object); the slow-slot dump hook makes a
-        # slow slot name its launches inline
-        from lodestar_tpu import telemetry as _telemetry
-
-        _telemetry.configure_launch_telemetry(
-            mode=opts.launch_telemetry, metrics=metrics.device_launch
-        )
+        # 2d. the one backend observation + the process-global device
+        # seams configured from it (prep / single launch / HTR / KZG
+        # counter / launch telemetry)
+        device_runtime = configure_device_runtime(opts, metrics)
         if opts.tracing_enabled:
+            from lodestar_tpu import telemetry as _telemetry
             from lodestar_tpu import tracing as _tracing
 
+            # the slow-slot dump hook makes a slow slot name its launches
             _tracing.configure(launches_supplier=_telemetry.slow_slot_launches)
 
         # 3. bls verifier — offload endpoints get the resilience stack:
@@ -473,32 +512,11 @@ class BeaconNode:
 
                 layers: list = [("offload", client)]
                 if opts.offload_fallback == "device":
-                    from lodestar_tpu.chain.bls import BlsDeviceVerifierPool
-
-                    layers.append(
-                        (
-                            "device_pool",
-                            BlsDeviceVerifierPool(
-                                scheduler_enabled=opts.scheduler_enabled,
-                                sched_metrics=metrics.sched,
-                                mesh_mode=opts.bls_mesh,
-                                pipeline=opts.bls_pipeline,
-                                pipeline_metrics=metrics.bls_pipeline,
-                            ),
-                        )
-                    )
+                    layers.append(("device_pool", _device_pool(opts, metrics)))
                 layers.append(("cpu", BlsSingleThreadVerifier()))
                 bls = DegradingBlsVerifier(layers, metrics=metrics.resilience)
-        elif opts.use_device_verifier:
-            from lodestar_tpu.chain.bls import BlsDeviceVerifierPool
-
-            bls = BlsDeviceVerifierPool(
-                scheduler_enabled=opts.scheduler_enabled,
-                sched_metrics=metrics.sched,
-                mesh_mode=opts.bls_mesh,
-                pipeline=opts.bls_pipeline,
-                pipeline_metrics=metrics.bls_pipeline,
-            )
+        elif device_runtime["verifier"] == "device":
+            bls = _device_pool(opts, metrics)
         else:
             bls = BlsSingleThreadVerifier()
 
@@ -604,6 +622,8 @@ class BeaconNode:
             # reqresp + router metric bridges (ReqRespMetrics hook; the
             # notifier's per-slot tick snapshots router/peer gauges)
             node.network.reqresp.metrics = metrics.reqresp
+        node.device_runtime = device_runtime
+        node.log.info("device runtime", device_runtime)
         node.log.info(
             f"beacon node up: slot {clock.current_slot}, "
             f"rest {'on :' + str(rest_server.port) if rest_server else 'off'}"

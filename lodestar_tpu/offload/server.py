@@ -33,6 +33,7 @@ from __future__ import annotations
 import threading
 import time
 from concurrent import futures
+from typing import Callable, NamedTuple
 
 import grpc
 
@@ -56,6 +57,8 @@ from .tenancy import TenantScheduler
 
 __all__ = [
     "BlsOffloadServer",
+    "VerifyBackend",
+    "build_backend",
     "SERVICE_NAME",
     "VERIFY_METHOD",
     "STATUS_METHOD",
@@ -451,11 +454,70 @@ class BlsOffloadServer:
         self._server.stop(grace)
 
 
+class VerifyBackend(NamedTuple):
+    """What `build_backend` resolved: the verify callable, the mesh
+    behind it (None on the CPU oracle; its `chip_table` feeds the Status
+    frame) and the description the process logs once at start."""
+
+    verify: Callable
+    mesh: object | None
+    description: dict
+
+    @property
+    def chip_status_fn(self) -> Callable | None:
+        return None if self.mesh is None else self.mesh.chip_table
+
+
+def build_backend(bls_mesh: str = "auto", bls_single_launch: str = "auto") -> VerifyBackend:
+    """The standalone host's verify backend, chosen from what the
+    process observes. Initialises the JAX backend, i.e. takes the chip
+    (`utils.probe_accelerator`; a chip owned by another process raises
+    `AcceleratorUnavailable`).
+
+    On a TPU backend the device verifier serves whatever the device
+    count: `auto`/`on` give one launch lane per chip plus the sharded
+    collective when more than one is visible, `off` (as on the node) one
+    lane on the device. On a CPU backend the CPU oracle serves — a
+    jax-on-CPU lane would trade it for minutes-long first-use XLA
+    compiles — unless `on` forces the mesh (tests on the virtual
+    mesh)."""
+    from lodestar_tpu.utils import probe_accelerator
+
+    accel = probe_accelerator()
+    if accel["platform"] != "tpu" and bls_mesh != "on":
+        from lodestar_tpu.crypto.bls.api import verify_signature_sets
+
+        return VerifyBackend(
+            verify_signature_sets, None, {**accel, "verifier": "cpu-oracle", "lanes": 0}
+        )
+    # the mesh lanes route through the process-global single-launch
+    # mode (models/batch_verify); pin it from the server's own flag so a
+    # serving host is never one env change away from a surprise
+    # first-use compile of the monolithic program
+    from lodestar_tpu.chain.bls.mesh import build_device_mesh, mesh_launch
+    from lodestar_tpu.models.batch_verify import configure_single_launch
+
+    configure_single_launch(mode=bls_single_launch)
+    # serve the mesh synchronously: mesh_launch keeps the per-chip
+    # wedge accounting + cross-lane error retry (a sick chip trips ITS
+    # breaker, drops out of the advertised chip table, and self-offers
+    # after the reset delay); the server's slot scheduler bounds
+    # concurrency per tenant above it
+    mesh = build_device_mesh(bls_mesh)
+
+    def verify(sets) -> bool:
+        return mesh_launch(mesh, sets)[0]
+
+    return VerifyBackend(verify, mesh, {**accel, "verifier": "device", "lanes": len(mesh)})
+
+
 def main() -> int:
-    """Standalone entry: host the repo's own verifier (the mesh-backed
-    device pool when devices are visible, the CPU oracle otherwise)."""
+    """Standalone entry: host the repo's own verifier (`build_backend`:
+    the device verifier on a TPU backend, the CPU oracle on a CPU
+    backend)."""
     import argparse
     import json
+    import sys
 
     from .tenancy import (
         DEFAULT_TENANT_REJECT_DEPTH,
@@ -472,9 +534,11 @@ def main() -> int:
     )
     ap.add_argument(
         "--bls-mesh", choices=["auto", "on", "off"], default="auto",
-        help="serve the device mesh: per-chip launch lanes + data-parallel "
-        "bulk sharding (auto = when the Pallas backend is live and more "
-        "than one device is visible); off = CPU oracle backend",
+        help="lane layout of the served device verifier: auto = one launch "
+        "lane per chip plus data-parallel bulk sharding when the backend is "
+        "a TPU with more than one device, on = the same on any backend, "
+        "off = one lane on the device. A CPU backend serves the CPU oracle "
+        "unless this is on.",
     )
     ap.add_argument(
         # literal copy of models.batch_verify.SINGLE_LAUNCH_MODES
@@ -519,49 +583,14 @@ def main() -> int:
     )
     args = ap.parse_args()
 
-    from lodestar_tpu.crypto.bls.api import verify_signature_sets
+    from lodestar_tpu.utils import AcceleratorUnavailable, enable_compile_cache
 
-    chip_status_fn = None
-    backend = verify_signature_sets
-    if args.bls_mesh != "off":
-        # the mesh lanes route through the process-global single-launch
-        # mode (models/batch_verify); pin it from the server's own flag
-        # so a serving host is never one env change away from a surprise
-        # first-use compile of the monolithic program. Inside the mesh
-        # branch on purpose: a --bls-mesh off server keeps the CPU
-        # oracle backend, which never consults the mode — pinning it
-        # would pay the whole jax/model import at startup for nothing
-        try:
-            from lodestar_tpu.models.batch_verify import configure_single_launch
-        except ImportError:
-            # a host without a usable jax stack serves the CPU oracle
-            # (same doctrine as build_device_mesh's fallback import) —
-            # there is no single-launch program to configure. Import
-            # errors ONLY: a ValueError from configure (the literal
-            # argparse copy drifting from SINGLE_LAUNCH_MODES) must be
-            # a loud startup failure exactly as on the node path
-            pass
-        else:
-            configure_single_launch(mode=args.bls_single_launch)
-        # serve the mesh synchronously: mesh_launch keeps the per-chip
-        # wedge accounting + cross-lane error retry (a sick chip trips
-        # ITS breaker, drops out of the advertised chip table, and
-        # self-offers after the reset delay); the server's slot
-        # scheduler bounds concurrency per tenant above it
-        from lodestar_tpu.chain.bls.mesh import build_device_mesh, mesh_launch
-
-        mesh = build_device_mesh(args.bls_mesh)
-        if args.bls_mesh == "auto" and len(mesh) == 1:
-            # auto found no live multi-chip mesh: keep the historical
-            # CPU-oracle backend — a single jax-on-CPU lane would
-            # silently trade it for minutes-long first-use XLA compiles
-            pass
-        else:
-            chip_status_fn = mesh.chip_table
-
-            def backend(sets, _mesh=mesh):
-                ok, _lane = mesh_launch(_mesh, sets)
-                return ok
+    enable_compile_cache()
+    try:
+        backend = build_backend(args.bls_mesh, args.bls_single_launch)
+    except AcceleratorUnavailable as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 1
 
     metrics_server = None
     tenant_metrics = None
@@ -587,7 +616,7 @@ def main() -> int:
         )
 
     server = BlsOffloadServer(
-        backend,
+        backend.verify,
         port=args.port,
         max_workers=args.workers,
         tenant_weights=parse_tenant_weights(args.tenant_weight),
@@ -599,9 +628,11 @@ def main() -> int:
         tenant_shed_depth=args.tenant_shed_depth,
         tenant_reject_depth=args.tenant_reject_depth,
         tenant_metrics=tenant_metrics,
-        chip_status_fn=chip_status_fn,
+        chip_status_fn=backend.chip_status_fn,
         deadline_model=deadline_model,
     )
+    # what this process runs on and what serves, once, at start
+    server.log.info("offload device runtime", backend.description)
     # surface the effective tenancy config once, for operators' logs
     server.log.info(
         "offload tenancy",

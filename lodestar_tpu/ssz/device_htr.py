@@ -31,9 +31,10 @@ always degrade.
 
 Mode selection is process-global like the BLS prep mode
 (`--htr-device {auto,on,off}` through cli ↔ BeaconNodeOptions ↔ node):
-"auto" rides the device only when the Pallas backend is live, "on"
-forces the device kernel (tests / benches on any backend), "off"
-restores the pure host path everywhere.
+"auto" rides the device when the process that configured it observed a
+TPU backend (node init passes what it saw, once; a process that never
+configures stays on the host), "on" forces the device kernel (tests /
+benches on any backend), "off" restores the pure host path everywhere.
 
 Importing this module never initializes a JAX backend — `ops.sha256` is
 imported lazily inside the launch path (the r3 multichip-gate
@@ -68,6 +69,7 @@ HTR_MODES = ("auto", "on", "off")
 # `configure_device_prep`). Reads race benignly: a flush observes either
 # the old or the new mode, both of which produce correct roots.
 _htr_mode = "auto"  # guarded by: config-time (node init / test setup writes; hot-path reads tolerate either value)
+_htr_accelerator = False  # guarded by: config-time (what "auto" resolves to: the backend node init observed)
 _htr_metrics = None  # guarded by: config-time (node init / test setup writes; hot-path reads tolerate either value)
 
 # Cumulative device-level launch counter: every padded `hash_pairs`
@@ -98,41 +100,38 @@ def _min_flush_pairs() -> int:
     return DEVICE_MIN_PAIRS
 
 
-def configure_device_htr(mode: str | None = None, metrics=None) -> str:
-    """Set the process-wide HTR placement mode and/or the
-    lodestar_ssz_htr_* metric family (node init; tests and benches flip
-    the mode around calls). Returns the PREVIOUS mode so callers can
-    save/restore."""
-    global _htr_mode, _htr_metrics
+def configure_device_htr(
+    mode: str | None = None, metrics=None, accelerator: bool | None = None
+) -> str:
+    """Set the process-wide HTR placement mode, what "auto" resolves to
+    (`accelerator`: whether the configuring process observed a TPU
+    backend) and/or the lodestar_ssz_htr_* metric family (node init;
+    tests and benches flip the mode around calls). Returns the PREVIOUS
+    mode so callers can save/restore."""
+    global _htr_mode, _htr_metrics, _htr_accelerator
     prev = _htr_mode
     if mode is not None:
         if mode not in HTR_MODES:
             raise ValueError(f"htr_device must be one of {HTR_MODES}, got {mode!r}")
         _htr_mode = mode
+    if accelerator is not None:
+        _htr_accelerator = bool(accelerator)
     if metrics is not None:
         _htr_metrics = metrics
     return prev
 
 
 def device_htr_active(mode: str | None = None) -> bool:
-    """Resolve an HTR mode ("auto" follows the Pallas backend, exactly
-    like `models.batch_verify.device_prep_active`)."""
+    """Resolve an HTR mode. "auto" is what `configure_device_htr` was
+    told about the backend — never probed here: this runs per root on
+    pure-host consumers too (db serdes hash through ssz.batch), which
+    must neither import JAX nor take the chip."""
     mode = mode or _htr_mode
     if mode == "on":
         return True
     if mode == "off":
         return False
-    # auto: a Pallas backend can only be live if JAX is already loaded —
-    # resolving that must not ITSELF drag JAX into pure-host consumers
-    # (db serdes hash through ssz.batch; the ssz/hash.py lazy-import
-    # doctrine)
-    import sys
-
-    if "jax" not in sys.modules:
-        return False
-    from lodestar_tpu.ops import fp_pallas
-
-    return fp_pallas.use_pallas()
+    return _htr_accelerator
 
 
 def launch_count() -> int:

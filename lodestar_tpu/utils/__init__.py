@@ -30,52 +30,67 @@ __all__ = [
     "ErrorAborted",
     "TimeoutError_",
     "enable_compile_cache",
+    "AcceleratorUnavailable",
+    "probe_accelerator",
 ]
 
 
-def enable_compile_cache(repo_root: str | None = None) -> None:
-    """Persistent XLA compile cache under `<repo>/.jax_cache` — the
-    pairing/batch-verify graphs compile once per machine instead of once
-    per process. Shared by bench.py, __graft_entry__.py and tests."""
+def enable_compile_cache() -> str:
+    """Persistent XLA compile cache, placed from outside or at one fixed
+    path. Where `JAX_COMPILATION_CACHE_DIR` is set JAX reads it itself
+    and no directory is set here; otherwise the cache is
+    `<checkout>/.jax_cache`, found from this file and never from the
+    working directory (the path is part of every entry's key, so a
+    directory that moves never hits). Every process that compiles a
+    verify program calls this once at start. Returns the directory."""
     import os
 
     import jax
 
-    if repo_root is None:
-        repo_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    # Key the cache by a host fingerprint: XLA:CPU AOT entries embed the
-    # compile machine's feature set and loading one compiled elsewhere can
-    # SIGILL (observed as cpu_aot_loader machine-feature mismatch spew in
-    # the r3 multichip gate). A fingerprint subdir turns "stale cache from
-    # another machine/jax" into a clean cache miss.
-    import hashlib
-    import platform
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        checkout = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        cache_dir = os.path.join(checkout, ".jax_cache")
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
+    return cache_dir
 
-    cpu_flags = b""
+
+class AcceleratorUnavailable(RuntimeError):
+    """The JAX backend could not initialise — on a chip host, typically
+    because the chip belongs to another process."""
+
+
+def probe_accelerator() -> dict:
+    """Initialise this process's JAX backend (taking the chip where
+    there is one) and describe it as JAX reports it: `platform`,
+    `device_kind`, `count`. The processes that build a verifier call
+    this once at start and resolve every "auto" from the answer;
+    processes that build none (validator client, light client, db
+    tools) never do, so they never take the chip.
+
+    An accelerator belongs to one process at a time, so a second node
+    or server on a chip host fails here. That is raised with the ways
+    out, never answered with a silent CPU fallback."""
+    import jax
+
     try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith("flags"):
-                    cpu_flags = " ".join(sorted(line.split(":", 1)[1].split())).encode()
-                    break
-    except OSError:
-        pass
-    fp = hashlib.sha1(
-        b"|".join([platform.machine().encode(), jax.__version__.encode(), cpu_flags])
-    ).hexdigest()[:12]
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir", os.path.join(repo_root, ".jax_cache", fp)
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-        # A stale/corrupt cache entry (e.g. written by a different libtpu or
-        # machine feature set) must degrade to a cache MISS, never kill the
-        # process — r3's multichip gate died partly on fragile AOT cache
-        # deserialization.
-        jax.config.update("jax_raise_persistent_cache_errors", False)
-    except Exception:
-        # unknown flag on this jax version / unwritable dir: run uncached
-        pass
+        devices = jax.devices()
+    except RuntimeError as e:
+        raise AcceleratorUnavailable(
+            f"cannot initialise the JAX backend: {e}\n"
+            "An accelerator belongs to one process at a time. If another node "
+            "or offload server on this host owns the chip, either start this "
+            "process with JAX_PLATFORMS=cpu (it then verifies and hashes on the "
+            "CPU) or route its verification to the owner with --bls-offload "
+            "HOST:PORT."
+        ) from e
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "count": len(devices),
+    }
 
 
 class ErrorAborted(Exception):

@@ -490,27 +490,34 @@ def test_sharded_lane_subset_is_index_ordered():
     assert 2 not in idx  # the hottest lane was dropped by the subset pick
 
 
-def test_build_device_mesh_degrades_to_cpu_oracle_when_device_model_unimportable(
-    monkeypatch,
-):
-    """Review regression: enumeration-failure fallback must not itself
-    import the device model (a jax-less host serves the CPU oracle)."""
+def test_build_device_mesh_does_not_hide_a_missing_device(monkeypatch):
+    """A caller of build_device_mesh asked for a device verifier: a
+    backend that cannot initialise (a chip another process holds) or a
+    device model that cannot be imported must raise, never come back as
+    a one-lane CPU oracle serving unseen."""
     import builtins
 
     from lodestar_tpu.chain.bls.mesh import build_device_mesh
-    from lodestar_tpu.crypto.bls.api import verify_signature_sets
+    from lodestar_tpu.models import batch_verify as bv
+
+    def busy():
+        raise RuntimeError("Unable to initialize backend 'tpu': already in use")
+
+    monkeypatch.setattr(bv, "mesh_device_count", busy)
+    with pytest.raises(RuntimeError, match="already in use"):
+        build_device_mesh("on")
+    monkeypatch.undo()
 
     real_import = builtins.__import__
 
     def blocked(name, *a, **kw):
-        if "models.batch_verify" in name or name.endswith("batch_verify"):
+        if name == "lodestar_tpu.models" or name.endswith("batch_verify"):
             raise ImportError("no jax on this host")
         return real_import(name, *a, **kw)
 
     monkeypatch.setattr(builtins, "__import__", blocked)
-    mesh = build_device_mesh("auto")
-    assert len(mesh) == 1
-    assert mesh.lanes[0].verify_fn is verify_signature_sets
+    with pytest.raises(ImportError):
+        build_device_mesh("auto")
 
 
 def test_mesh_launch_reroutes_when_preferred_lane_already_wedged():

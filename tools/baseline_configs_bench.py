@@ -32,7 +32,7 @@ import numpy as np
 
 from lodestar_tpu.utils import enable_compile_cache
 
-enable_compile_cache(".")
+enable_compile_cache()
 
 QUICK = "--quick" in sys.argv
 REFERENCE_SIGS_PER_SEC_PER_CORE = 2200.0  # blst envelope (bench.py)

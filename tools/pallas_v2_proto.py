@@ -25,7 +25,7 @@ from jax.experimental import pallas as pl
 from lodestar_tpu.ops import fp
 from lodestar_tpu.utils import enable_compile_cache
 
-enable_compile_cache(".")
+enable_compile_cache()
 
 B = int(sys.argv[1]) if len(sys.argv) > 1 else 4096 * 54
 K = int(sys.argv[2]) if len(sys.argv) > 2 else 64
