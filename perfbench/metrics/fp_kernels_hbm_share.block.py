@@ -1,0 +1,3 @@
+"""Pallas Fp kernels: share of the HBM peak their calls reach, one block at a time."""
+
+from perfbench.readers import fp_kernels_hbm_share as read  # noqa: F401
