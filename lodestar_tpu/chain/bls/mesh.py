@@ -30,7 +30,6 @@ amortize the collective launch.
 
 from __future__ import annotations
 
-import time
 from typing import Callable, Sequence
 
 from lodestar_tpu import telemetry
@@ -262,34 +261,35 @@ def mesh_launch(
             # a mesh slot's launches name their chips. Size class is the
             # pow-2 bucket of the set count — the verify programs' own
             # compile-cache bucketing.
-            t0 = time.perf_counter() if telemetry.launch_telemetry_active() else 0.0
-            dispatched = True
-            with current.occupancy.launch():
-                use_staged = prepared is not None and prepared.error is None
-                if use_staged and prepared.inputs is None:
-                    ok = False  # prep rejected the batch: verdict final
-                    dispatched = False  # no backend call — not a launch
-                elif use_staged and current.verify_prepared_fn is not None:
-                    ok = bool(current.verify_prepared_fn(prepared.inputs))
-                elif (
-                    current.verify_single_fn is not None
-                    and _single_launch_active()
-                ):
-                    # lane-pinned single-launch road (one resident
-                    # program per batch); its single→split degradation
-                    # lives in the model layer, so an error here means
-                    # even the split schedule failed on this lane — the
-                    # same breaker/cross-lane semantics as verify_fn
-                    ok = bool(current.verify_single_fn(sets))
-                else:
-                    ok = bool(current.verify_fn(sets))
-            if t0 and dispatched:
-                telemetry.record_launch(
-                    "bls_lane_verify",
-                    telemetry.size_class_of(len(sets)),
-                    time.perf_counter() - t0,
-                    lane=current.label,
-                )
+            use_staged = prepared is not None and prepared.error is None
+            if use_staged and prepared.inputs is None:
+                # prep rejected the batch: verdict final, no backend
+                # call — not a launch
+                with current.occupancy.launch():
+                    ok = False
+            else:
+                with telemetry.launch(
+                    "bls_lane_verify", telemetry.size_class_of(len(sets)), lane=current.label
+                ) as tel, current.occupancy.launch():
+                    if use_staged and current.verify_prepared_fn is not None:
+                        info = prepared.info
+                        if info is not None:
+                            # staged on the prep thread: its parse seconds
+                            # cross threads with the inputs
+                            tel.add_phase("bls.parse", (info["end_ns"] - info["start_ns"]) / 1e9)
+                        ok = bool(current.verify_prepared_fn(prepared.inputs))
+                    elif (
+                        current.verify_single_fn is not None
+                        and _single_launch_active()
+                    ):
+                        # lane-pinned single-launch road (one resident
+                        # program per batch); its single→split degradation
+                        # lives in the model layer, so an error here means
+                        # even the split schedule failed on this lane — the
+                        # same breaker/cross-lane semantics as verify_fn
+                        ok = bool(current.verify_single_fn(sets))
+                    else:
+                        ok = bool(current.verify_fn(sets))
         except Exception:
             # an error on a staged-inputs attempt may be input-bound
             # (arrays committed to the sick die, a malformed staging) —

@@ -62,7 +62,7 @@ import threading
 import time
 from typing import Awaitable, Callable, Sequence
 
-from lodestar_tpu import slo, tracing
+from lodestar_tpu import slo, telemetry, tracing
 from lodestar_tpu.crypto.bls.api import SignatureSet
 from lodestar_tpu.logger import get_logger
 from lodestar_tpu.scheduler import (
@@ -652,18 +652,19 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         in-flight launch; everything available in FIFO mode (the
         pre-scheduler arm)."""
         job, cls, waited_ns = await self._jobs.get()
-        self._record_sched_dequeue(job, cls, waited_ns)
-        package = [job]
-        if not (self.scheduler_enabled and cls in BULK_CLASSES):
-            drain_cls = cls if self.scheduler_enabled else None
-            package_sets = len(job.sets)
-            while not self.scheduler_enabled or package_sets < MAX_PACKAGE_SETS:
-                nxt = self._jobs.get_nowait(drain_cls)
-                if nxt is None:
-                    break
-                self._record_sched_dequeue(*nxt)
-                package.append(nxt[0])
-                package_sets += len(nxt[0].sets)
+        with telemetry.phase("bls.next_package"):
+            self._record_sched_dequeue(job, cls, waited_ns)
+            package = [job]
+            if not (self.scheduler_enabled and cls in BULK_CLASSES):
+                drain_cls = cls if self.scheduler_enabled else None
+                package_sets = len(job.sets)
+                while not self.scheduler_enabled or package_sets < MAX_PACKAGE_SETS:
+                    nxt = self._jobs.get_nowait(drain_cls)
+                    if nxt is None:
+                        break
+                    self._record_sched_dequeue(*nxt)
+                    package.append(nxt[0])
+                    package_sets += len(nxt[0].sets)
         return package, cls
 
     async def _place_and_launch(self, package, cls, prepped=None) -> None:
@@ -686,20 +687,21 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                 await self._lane_free.wait()
                 if self._closed:
                     raise asyncio.CancelledError("bls pool closed")
-            mode, lanes = self._pick_placement(cls, package, free)
         except asyncio.CancelledError:
             err = asyncio.CancelledError("bls pool closed")
             for j in package:
                 if not j.future.done():
                     j.future.set_exception(err)
             raise
-        for lane in lanes:
-            lane.inflight += 1
-        task = asyncio.get_event_loop().create_task(
-            self._launch(package, mode, lanes, prepped=prepped)
-        )
-        self._launch_tasks.add(task)
-        task.add_done_callback(self._launch_tasks.discard)
+        with telemetry.phase("bls.place"):  # no await inside
+            mode, lanes = self._pick_placement(cls, package, free)
+            for lane in lanes:
+                lane.inflight += 1
+            task = asyncio.get_event_loop().create_task(
+                self._launch(package, mode, lanes, prepped=prepped)
+            )
+            self._launch_tasks.add(task)
+            task.add_done_callback(self._launch_tasks.discard)
 
     async def _run_jobs(self) -> None:
         while not self._closed:
@@ -1014,14 +1016,11 @@ class BlsDeviceVerifierPool(IBlsVerifier):
 
         # RLC-batch the batchable jobs in ≥16-set chunks; invalid batch →
         # retry each job individually (worker.ts:52-96)
-        from lodestar_tpu.utils.tracing import trace_region
-
         retries: list[_Job] = []
         for jobs, all_sets, staged in chunk_units:
             t0 = time.monotonic_ns() if traced else 0
             try:
-                with trace_region("bls_batch_verify"):
-                    ok, served = self._launch_sets(lane, all_sets, prepared=staged)
+                ok, served = self._launch_sets(lane, all_sets, prepared=staged)
             except Exception:
                 self.metrics["batch_retries"] += 1
                 if traced:
@@ -1248,7 +1247,8 @@ class BlsDeviceVerifierPool(IBlsVerifier):
 
     def _resolve(self, job: _Job, result: bool) -> None:
         if not job.future.done():
-            job.future.get_loop().call_soon_threadsafe(self._set_result, job, result)
+            with telemetry.phase("bls.resolve"):  # the hand-back to the loop thread
+                job.future.get_loop().call_soon_threadsafe(self._set_result, job, result)
 
     @staticmethod
     def _set_result(job: _Job, result: bool) -> None:

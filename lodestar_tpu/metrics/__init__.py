@@ -418,7 +418,7 @@ class DeviceLaunchMetrics:
     the hardware measurement campaign reads."""
 
     launch_seconds: Histogram  # dispatch wall time, labeled by program + size_class
-    compile_seconds: Counter  # wall time of first-call (trace+compile) dispatches
+    compile_seconds: Counter  # wall time of top-level first-call (trace+compile) dispatches
     compile_hits: Counter  # dispatches whose (program, size_class) was already compiled
     compile_misses: Counter  # first-call dispatches per (program, size_class) key
 
@@ -637,7 +637,7 @@ def create_metrics() -> BeaconMetrics:
         ),
         compile_seconds=c.counter(
             "lodestar_device_compile_seconds_total",
-            "Wall seconds spent in first-call-per-(program,size_class) "
+            "Wall seconds spent in top-level first-call-per-(program,size_class) "
             "dispatches — the trace+compile (or persistent-cache load) tax",
         ),
         compile_hits=c.counter(

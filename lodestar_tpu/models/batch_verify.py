@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from lodestar_tpu import telemetry
 from lodestar_tpu.crypto.bls import curve as C
 from lodestar_tpu.crypto.bls.api import SignatureSet
 from lodestar_tpu.crypto.bls.curve import G1_GEN
@@ -405,18 +406,19 @@ def prepare_sets_device(sets: list[SignatureSet], fused: bool = True):
 def _blind_and_aggregate_body(pk_x, pk_y, sig_x, sig_y, coeff_bits, mask):
     """Blinded scalar muls (r_i*PK_i in G1, r_i*S_i in G2), the masked G2
     fold to the aggregate signature, affine conversions."""
-    one1 = fp.one_mont()
-    one2 = tw.fp2_one()
-    rpk = cv.scalar_mul_var(cv.F1, (pk_x, pk_y), coeff_bits, one1)
-    rsig = cv.scalar_mul_var(cv.F2, (sig_x, sig_y), coeff_bits, one2)
-    # padded entries must not contribute to the signature aggregate:
-    # force their blinded sig to infinity before the fold
-    mcol = mask[:, None, None]
-    rsig = (rsig[0], rsig[1], jnp.where(mcol, rsig[2], jnp.zeros_like(rsig[2])))
-    s_agg = cv.fold_sum(cv.F2, rsig)
-    rpk_aff = cv.jac_to_affine_batch(cv.F1, rpk)
-    s_aff = cv.jac_to_affine_batch(cv.F2, tuple(c[None] for c in s_agg))
-    s_inf = cv.jac_is_inf(cv.F2, s_agg)
+    with jax.named_scope("bls.blind"):
+        one1 = fp.one_mont()
+        one2 = tw.fp2_one()
+        rpk = cv.scalar_mul_var(cv.F1, (pk_x, pk_y), coeff_bits, one1)
+        rsig = cv.scalar_mul_var(cv.F2, (sig_x, sig_y), coeff_bits, one2)
+        # padded entries must not contribute to the signature aggregate:
+        # force their blinded sig to infinity before the fold
+        mcol = mask[:, None, None]
+        rsig = (rsig[0], rsig[1], jnp.where(mcol, rsig[2], jnp.zeros_like(rsig[2])))
+        s_agg = cv.fold_sum(cv.F2, rsig)
+        rpk_aff = cv.jac_to_affine_batch(cv.F1, rpk)
+        s_aff = cv.jac_to_affine_batch(cv.F2, tuple(c[None] for c in s_agg))
+        s_inf = cv.jac_is_inf(cv.F2, s_agg)
     return rpk_aff, s_aff, s_inf
 
 
@@ -425,19 +427,20 @@ def _assemble_pairs(rpk_aff, s_aff, s_inf, h_x, h_y, mask):
     pair. Padded / infinite entries get the generator pair as a
     placeholder (any valid non-infinity point works; the mask drops
     their Miller value)."""
-    p_x = jnp.concatenate([rpk_aff[0], _NEG_G1_X[None].astype(jnp.int32)], axis=0)
-    p_y = jnp.concatenate([rpk_aff[1], _NEG_G1_Y[None].astype(jnp.int32)], axis=0)
-    q_x = jnp.concatenate([h_x, s_aff[0]], axis=0)
-    q_y = jnp.concatenate([h_y, s_aff[1]], axis=0)
-    pair_mask = jnp.concatenate([mask, ~s_inf[None]], axis=0)
-    gen_p = (jnp.asarray(_NEG_G1_X), jnp.asarray(_NEG_G1_Y))
-    gen_q_x = jnp.broadcast_to(h_x[0], q_x.shape[1:])
-    gen_q_y = jnp.broadcast_to(h_y[0], q_y.shape[1:])
-    mm = pair_mask[:, None, None]
-    p_x = jnp.where(mm[..., 0], p_x, gen_p[0])
-    p_y = jnp.where(mm[..., 0], p_y, gen_p[1])
-    q_x = jnp.where(mm, q_x, gen_q_x)
-    q_y = jnp.where(mm, q_y, gen_q_y)
+    with jax.named_scope("bls.assemble"):
+        p_x = jnp.concatenate([rpk_aff[0], _NEG_G1_X[None].astype(jnp.int32)], axis=0)
+        p_y = jnp.concatenate([rpk_aff[1], _NEG_G1_Y[None].astype(jnp.int32)], axis=0)
+        q_x = jnp.concatenate([h_x, s_aff[0]], axis=0)
+        q_y = jnp.concatenate([h_y, s_aff[1]], axis=0)
+        pair_mask = jnp.concatenate([mask, ~s_inf[None]], axis=0)
+        gen_p = (jnp.asarray(_NEG_G1_X), jnp.asarray(_NEG_G1_Y))
+        gen_q_x = jnp.broadcast_to(h_x[0], q_x.shape[1:])
+        gen_q_y = jnp.broadcast_to(h_y[0], q_y.shape[1:])
+        mm = pair_mask[:, None, None]
+        p_x = jnp.where(mm[..., 0], p_x, gen_p[0])
+        p_y = jnp.where(mm[..., 0], p_y, gen_p[1])
+        q_x = jnp.where(mm, q_x, gen_q_x)
+        q_y = jnp.where(mm, q_y, gen_q_y)
     return p_x, p_y, q_x, q_y, pair_mask
 
 
@@ -493,13 +496,16 @@ def _single_launch_verify(
     # (decompression chains + the shared Fp2 sqrt chain + SSWU +
     # 3-isogeny), subgroup ladders, hash finish (add + Budroni–Pintore
     # clearing + batch affine)
-    pk_x, pk_y, pk_curve, sig_x, sig_y, sig_curve, q0, q1 = dp._prep_field_stage(
-        pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi
-    )
-    pk_ok, sig_ok = dp._prep_subgroup_stage(
-        pk_x, pk_y, pk_curve, sig_x, sig_y, sig_curve
-    )
-    h_x, h_y = dp.hash_finish(q0, q1)
+    with jax.named_scope("bls.prep_field"):
+        pk_x, pk_y, pk_curve, sig_x, sig_y, sig_curve, q0, q1 = dp._prep_field_stage(
+            pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi
+        )
+    with jax.named_scope("bls.prep_subgroup"):
+        pk_ok, sig_ok = dp._prep_subgroup_stage(
+            pk_x, pk_y, pk_curve, sig_x, sig_y, sig_curve
+        )
+    with jax.named_scope("bls.hash_finish"):
+        h_x, h_y = dp.hash_finish(q0, q1)
 
     # RLC aggregation + Miller loop + final exponentiation. Invalid rows
     # carry in-contract relaxed limbs (the pow-chain outputs), so the
@@ -548,28 +554,21 @@ def device_batch_verify(pk, h, sig, coeff_bits, mask) -> jax.Array:
     (N, 64) int32 MSB-first; mask: (N,) bool — False entries are padding.
     Returns a scalar bool array.
     """
-    from lodestar_tpu import telemetry
     from lodestar_tpu.ops import fp_pallas
 
     staged = fp_pallas.use_pallas()
     # the verify core's jit-cache seam: one record per call (the staged
     # chain is one logical launch unit of 3 dispatches), size class =
     # the padded batch the executable was compiled for
-    t0 = time.perf_counter() if telemetry.launch_telemetry_active() else 0.0
-    if staged:
-        out = _device_batch_verify_staged(pk, h, sig, coeff_bits, mask)
-    else:
-        out = _device_batch_verify_impl(
+    with telemetry.launch(
+        "batch_verify_staged" if staged else "batch_verify", int(pk[0].shape[0])
+    ):
+        if staged:
+            return _device_batch_verify_staged(pk, h, sig, coeff_bits, mask)
+        return _device_batch_verify_impl(
             pk[0], pk[1], h[0], h[1], sig[0], sig[1],
             jnp.asarray(coeff_bits), jnp.asarray(mask),
         )
-    if t0:
-        telemetry.record_launch(
-            "batch_verify_staged" if staged else "batch_verify",
-            int(pk[0].shape[0]),
-            time.perf_counter() - t0,
-        )
-    return out
 
 
 _device_batch_verify_many_impl = jax.jit(jax.vmap(_device_batch_verify_impl))
@@ -669,21 +668,16 @@ def device_batch_verify_sharded(mesh, pk, h, sig, coeff_bits, mask) -> jax.Array
     points rides the ICI; every chip then finishes the fold + the single
     shared final exponentiation redundantly (SPMD-replicated scalar work).
     """
-    from lodestar_tpu import telemetry
-
-    t_tel = time.perf_counter() if telemetry.launch_telemetry_active() else 0.0
-    ok = _sharded_program(mesh)(
-        pk[0], pk[1], h[0], h[1], sig[0], sig[1],
-        jnp.asarray(coeff_bits), jnp.asarray(mask),
-    )
-    if t_tel:
-        # the sharded collective's jit-cache seam: only the first call
-        # per (mesh, batch) carries compile
-        telemetry.record_launch(
-            "batch_verify_sharded",
-            int(pk[0].shape[0]),
-            time.perf_counter() - t_tel,
-            lane=",".join(str(d.id) for d in mesh.devices.flat),
+    # the sharded collective's jit-cache seam: only the first call per
+    # (mesh, batch) carries compile
+    with telemetry.launch(
+        "batch_verify_sharded",
+        int(pk[0].shape[0]),
+        lane=",".join(str(d.id) for d in mesh.devices.flat),
+    ):
+        ok = _sharded_program(mesh)(
+            pk[0], pk[1], h[0], h[1], sig[0], sig[1],
+            jnp.asarray(coeff_bits), jnp.asarray(mask),
         )
     return ok.all()
 
@@ -748,33 +742,34 @@ def build_device_inputs(
     if size < n:
         raise ValueError("pad size smaller than batch")
 
-    if device_prep_active(prep):
+    with telemetry.phase("bls.parse"):
+        if device_prep_active(prep):
+            t0 = time.monotonic_ns()
+            try:
+                pk, h, sig, ok = _prepare_sets_device_arrays(sets, size)
+            except Exception as e:  # degrade to host prep, never resolve here
+                _note_prep_fallback(e)
+            else:
+                _note_prep("device", n, t0, rejected=not ok)
+                if not ok:
+                    return None
+                return _finish_inputs(pk, h, sig, n, size)
+
         t0 = time.monotonic_ns()
-        try:
-            pk, h, sig, ok = _prepare_sets_device_arrays(sets, size)
-        except Exception as e:  # degrade to host prep, never resolve here
-            _note_prep_fallback(e)
-        else:
-            _note_prep("device", n, t0, rejected=not ok)
-            if not ok:
-                return None
-            return _finish_inputs(pk, h, sig, n, size)
+        prepared = prepare_sets(sets)
+        _note_prep("host", n, t0, rejected=prepared is None)
+        if prepared is None:
+            return None
+        (pk_x, pk_y), (h_x, h_y), (sig_x, sig_y) = prepared
+        from lodestar_tpu.ops.prep import pad_rows
 
-    t0 = time.monotonic_ns()
-    prepared = prepare_sets(sets)
-    _note_prep("host", n, t0, rejected=prepared is None)
-    if prepared is None:
-        return None
-    (pk_x, pk_y), (h_x, h_y), (sig_x, sig_y) = prepared
-    from lodestar_tpu.ops.prep import pad_rows
-
-    return _finish_inputs(
-        (pad_rows(pk_x, size), pad_rows(pk_y, size)),
-        (pad_rows(h_x, size), pad_rows(h_y, size)),
-        (pad_rows(sig_x, size), pad_rows(sig_y, size)),
-        n,
-        size,
-    )
+        return _finish_inputs(
+            (pad_rows(pk_x, size), pad_rows(pk_y, size)),
+            (pad_rows(h_x, size), pad_rows(h_y, size)),
+            (pad_rows(sig_x, size), pad_rows(sig_y, size)),
+            n,
+            size,
+        )
 
 
 def make_synthetic_sets(n: int, seed: int = 1) -> list[SignatureSet]:
@@ -810,8 +805,17 @@ def _verify_sets_split(sets: list[SignatureSet]) -> bool:
     inputs = build_device_inputs(sets)
     if inputs is None:
         return False
+    return _verify_split_prepared(inputs)
+
+
+def _verify_split_prepared(inputs) -> bool:
+    """The split schedule's verify dispatch on `build_device_inputs`'
+    tuple, under the phase names the single launch uses."""
     pk, h, sig, bits, mask = inputs
-    return bool(np.asarray(device_batch_verify(pk, h, sig, bits, mask)))
+    with telemetry.phase("bls.dispatch"):
+        out = device_batch_verify(pk, h, sig, bits, mask)
+    with telemetry.phase("bls.wait"):
+        return bool(np.asarray(out))
 
 
 class SingleLaunchInputs:
@@ -841,19 +845,20 @@ def prepare_single_launch_inputs(sets: list[SignatureSet]):
     if not sets:
         return None
     n = len(sets)
-    t0 = time.monotonic_ns()
-    size = _pad_pow2(n)
-    parsed = _parse_host_arrays(sets, size)
-    if parsed is None:
-        _note_prep("single_launch", n, t0, rejected=True)
-        return None
-    pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi = parsed
-    struct = pk_struct & sig_struct
-    bits, mask = _blinding_and_mask(n, size)
-    _note_prep("single_launch", n, t0)
-    return SingleLaunchInputs(
-        list(sets), (pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi, struct), bits, mask, n
-    )
+    with telemetry.phase("bls.parse"):
+        t0 = time.monotonic_ns()
+        size = _pad_pow2(n)
+        parsed = _parse_host_arrays(sets, size)
+        if parsed is None:
+            _note_prep("single_launch", n, t0, rejected=True)
+            return None
+        pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi = parsed
+        struct = pk_struct & sig_struct
+        bits, mask = _blinding_and_mask(n, size)
+        _note_prep("single_launch", n, t0)
+        return SingleLaunchInputs(
+            list(sets), (pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi, struct), bits, mask, n
+        )
 
 
 def _verify_single_prepared(si: SingleLaunchInputs) -> bool:
@@ -864,14 +869,16 @@ def _verify_single_prepared(si: SingleLaunchInputs) -> bool:
     from lodestar_tpu.ops import prep as dp
 
     try:
-        verdict, batch_valid = dp._dispatch(
-            _single_launch_verify, *si.arrays, si.bits, si.mask
-        )
+        with telemetry.phase("bls.dispatch"):  # transfer and enqueue
+            verdict, batch_valid = dp._dispatch(
+                _single_launch_verify, *si.arrays, si.bits, si.mask
+            )
         # BOTH outputs are shape-checked inside the guarded region: a
         # miscompile returning a malformed batch_valid must degrade
         # like any other anomaly, not raise into the lane/breaker
-        v = np.asarray(verdict)
-        bvld = np.asarray(batch_valid)
+        with telemetry.phase("bls.wait"):  # blocks on the verdict
+            v = np.asarray(verdict)
+            bvld = np.asarray(batch_valid)
         for name, arr in (("verdict", v), ("batch_valid", bvld)):
             if arr.shape != () or arr.dtype != np.bool_:
                 raise RuntimeError(
@@ -920,8 +927,7 @@ def verify_prepared(inputs) -> bool:
     identical to `verify_signature_sets_device` on the same sets."""
     if isinstance(inputs, SingleLaunchInputs):
         return _verify_single_prepared(inputs)
-    pk, h, sig, bits, mask = inputs
-    return bool(np.asarray(device_batch_verify(pk, h, sig, bits, mask)))
+    return _verify_split_prepared(inputs)
 
 
 def prepare_inputs_for_lane(sets: list[SignatureSet], lane_index: int | None = None):

@@ -116,61 +116,62 @@ def miller_loop(p_aff, q_aff):
     denominators (2YZ^3 / Z*H per step), which vanish under
     `final_exponentiation`.
     """
-    xp, yp = p_aff
-    xq, yq = q_aff
-    one2 = tw.fp2_one(xq.shape[:-2])
+    with jax.named_scope("bls.miller"):
+        xp, yp = p_aff
+        xq, yq = q_aff
+        one2 = tw.fp2_one(xq.shape[:-2])
 
-    # T starts at Q (Jacobian, Z = 1 in Fp2)
-    T = (xq, yq, jnp.broadcast_to(one2, xq.shape))
-    f = tw.fp12_one(xp.shape[:-1])
+        # T starts at Q (Jacobian, Z = 1 in Fp2)
+        T = (xq, yq, jnp.broadcast_to(one2, xq.shape))
+        f = tw.fp12_one(xp.shape[:-1])
 
-    bits = jnp.asarray(_X_BITS)
+        bits = jnp.asarray(_X_BITS)
 
-    def dbl_line(T):
-        X, Y, Z = T
-        Z2 = tw.fp2_sq(Z)
-        Y2 = tw.fp2_sq(Y)
-        X2 = tw.fp2_sq(X)
-        YZ3 = tw.fp2_mul(Y, tw.fp2_mul(Z, Z2))
-        X3cube = tw.fp2_mul(X, X2)
-        # c0 = 2*Y*Z^3 * xi * yP ; c3 = 3X^3 - 2Y^2 ; c5 = -3X^2Z^2 * xP
-        c0 = tw.fp2_mul_fp(tw.fp2_mul_xi(tw.fp2_add(YZ3, YZ3)), yp)
-        c3 = tw.fp2_sub(_fp2_triple(X3cube), tw.fp2_add(Y2, Y2))
-        c5 = tw.fp2_neg(tw.fp2_mul_fp(_fp2_triple(tw.fp2_mul(X2, Z2)), xp))
-        return c0, c3, c5
+        def dbl_line(T):
+            X, Y, Z = T
+            Z2 = tw.fp2_sq(Z)
+            Y2 = tw.fp2_sq(Y)
+            X2 = tw.fp2_sq(X)
+            YZ3 = tw.fp2_mul(Y, tw.fp2_mul(Z, Z2))
+            X3cube = tw.fp2_mul(X, X2)
+            # c0 = 2*Y*Z^3 * xi * yP ; c3 = 3X^3 - 2Y^2 ; c5 = -3X^2Z^2 * xP
+            c0 = tw.fp2_mul_fp(tw.fp2_mul_xi(tw.fp2_add(YZ3, YZ3)), yp)
+            c3 = tw.fp2_sub(_fp2_triple(X3cube), tw.fp2_add(Y2, Y2))
+            c5 = tw.fp2_neg(tw.fp2_mul_fp(_fp2_triple(tw.fp2_mul(X2, Z2)), xp))
+            return c0, c3, c5
 
-    def add_line(T):
-        X, Y, Z = T
-        Z2 = tw.fp2_sq(Z)
-        Z3 = tw.fp2_mul(Z, Z2)
-        theta = tw.fp2_sub(Y, tw.fp2_mul(yq, Z3))  # Y - yQ Z^3
-        H = tw.fp2_sub(X, tw.fp2_mul(xq, Z2))  # X - xQ Z^2
-        ZH = tw.fp2_mul(Z, H)
-        c0 = tw.fp2_mul_fp(tw.fp2_mul_xi(ZH), yp)
-        c3 = tw.fp2_sub(tw.fp2_mul(theta, xq), tw.fp2_mul(ZH, yq))
-        c5 = tw.fp2_neg(tw.fp2_mul_fp(theta, xp))
-        return c0, c3, c5
+        def add_line(T):
+            X, Y, Z = T
+            Z2 = tw.fp2_sq(Z)
+            Z3 = tw.fp2_mul(Z, Z2)
+            theta = tw.fp2_sub(Y, tw.fp2_mul(yq, Z3))  # Y - yQ Z^3
+            H = tw.fp2_sub(X, tw.fp2_mul(xq, Z2))  # X - xQ Z^2
+            ZH = tw.fp2_mul(Z, H)
+            c0 = tw.fp2_mul_fp(tw.fp2_mul_xi(ZH), yp)
+            c3 = tw.fp2_sub(tw.fp2_mul(theta, xq), tw.fp2_mul(ZH, yq))
+            c5 = tw.fp2_neg(tw.fp2_mul_fp(theta, xp))
+            return c0, c3, c5
 
-    def body(carry, bit):
-        f, T = carry
-        # doubling step: f <- f^2 * l_{T,T}(P); T <- 2T
-        c0, c3, c5 = dbl_line(T)
-        f = _mul_by_line(tw.fp12_sq(f), c0, c3, c5)
-        T = cv.jac_double(cv.F2, T)
+        def body(carry, bit):
+            f, T = carry
+            # doubling step: f <- f^2 * l_{T,T}(P); T <- 2T
+            c0, c3, c5 = dbl_line(T)
+            f = _mul_by_line(tw.fp12_sq(f), c0, c3, c5)
+            T = cv.jac_double(cv.F2, T)
 
-        def add_step(args):
-            f, T = args
-            c0, c3, c5 = add_line(T)
-            f = _mul_by_line(f, c0, c3, c5)
-            T = cv.jac_add_mixed(cv.F2, T, (xq, yq), one2)
-            return f, T
+            def add_step(args):
+                f, T = args
+                c0, c3, c5 = add_line(T)
+                f = _mul_by_line(f, c0, c3, c5)
+                T = cv.jac_add_mixed(cv.F2, T, (xq, yq), one2)
+                return f, T
 
-        f, T = jax.lax.cond(bit != 0, add_step, lambda a: a, (f, T))
-        return (f, T), None
+            f, T = jax.lax.cond(bit != 0, add_step, lambda a: a, (f, T))
+            return (f, T), None
 
-    (f, _), _ = jax.lax.scan(body, (f, T), bits)
-    # negative parameter: conjugate
-    return tw.fp12_conj(f)
+        (f, _), _ = jax.lax.scan(body, (f, T), bits)
+        # negative parameter: conjugate
+        return tw.fp12_conj(f)
 
 
 # --- final exponentiation ----------------------------------------------------
@@ -202,19 +203,20 @@ def final_exponentiation(f):
     """f^(3*(p^12-1)/r) — byte-exact mirror of the oracle's HHT hard part
     (`crypto/bls/pairing.py:112`); the cube keeps pairing-product equality
     semantics unchanged (gcd(3, r) = 1)."""
-    # easy part: f^((p^6-1)(p^2+1))
-    f = tw.fp12_mul(tw.fp12_conj(f), tw.fp12_inv(f))
-    f = tw.fp12_mul(tw.fp12_frobenius(f, 2), f)
-    # hard part (cyclotomic: inverse == conjugate)
-    y = _pow_xm1(f)
-    y = _pow_xm1(y)
-    y = tw.fp12_mul(_pow_x(y), tw.fp12_frobenius(y, 1))
-    y = tw.fp12_mul(
-        tw.fp12_mul(_pow_x(_pow_x(y)), tw.fp12_frobenius(y, 2)),
-        tw.fp12_conj(y),
-    )
-    f3 = tw.fp12_mul(tw.fp12_mul(f, f), f)
-    return tw.fp12_mul(y, f3)
+    with jax.named_scope("bls.final_exp"):
+        with jax.named_scope("easy"):  # f^((p^6-1)(p^2+1))
+            f = tw.fp12_mul(tw.fp12_conj(f), tw.fp12_inv(f))
+            f = tw.fp12_mul(tw.fp12_frobenius(f, 2), f)
+        with jax.named_scope("hard"):  # cyclotomic: inverse == conjugate
+            y = _pow_xm1(f)
+            y = _pow_xm1(y)
+            y = tw.fp12_mul(_pow_x(y), tw.fp12_frobenius(y, 1))
+            y = tw.fp12_mul(
+                tw.fp12_mul(_pow_x(_pow_x(y)), tw.fp12_frobenius(y, 2)),
+                tw.fp12_conj(y),
+            )
+            f3 = tw.fp12_mul(tw.fp12_mul(f, f), f)
+            return tw.fp12_mul(y, f3)
 
 
 def pairing(p_aff, q_aff):
@@ -233,18 +235,19 @@ def fp12_product_fold(f, mask=None):
     replaced with one (the device analogue of the oracle's skip-infinity
     in `multi_pairing`). Returns (2, 3, 2, 33).
     """
-    if mask is not None:
-        ones = tw.fp12_one(f.shape[:1])
-        f = jnp.where(mask[..., None, None, None, None], f, ones)
-    b = f.shape[0]
-    size = 1 if b <= 1 else 1 << (b - 1).bit_length()
-    if size != b:
-        pad_ones = tw.fp12_one((size - b,))
-        f = jnp.concatenate([f, pad_ones], axis=0)
-    while f.shape[0] > 1:
-        half = f.shape[0] // 2
-        f = tw.fp12_mul(f[:half], f[half:])
-    return f[0]
+    with jax.named_scope("bls.fold"):
+        if mask is not None:
+            ones = tw.fp12_one(f.shape[:1])
+            f = jnp.where(mask[..., None, None, None, None], f, ones)
+        b = f.shape[0]
+        size = 1 if b <= 1 else 1 << (b - 1).bit_length()
+        if size != b:
+            pad_ones = tw.fp12_one((size - b,))
+            f = jnp.concatenate([f, pad_ones], axis=0)
+        while f.shape[0] > 1:
+            half = f.shape[0] // 2
+            f = tw.fp12_mul(f[:half], f[half:])
+        return f[0]
 
 
 @jax.jit

@@ -49,8 +49,6 @@ JAX backend (the r3 multichip-gate regression class).
 
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -197,15 +195,8 @@ def _dispatch(program, *args, **kwargs):
     # (the arrays arriving here are already size-class padded; kwargs
     # carry static_argnames-style knobs, not batch data, and stay out
     # of the size-class probe)
-    t0 = time.perf_counter() if telemetry.launch_telemetry_active() else 0.0
-    out = program(*args, **kwargs)
-    if t0:
-        telemetry.record_launch(
-            telemetry.program_name(program),
-            telemetry.launch_size_class(args),
-            time.perf_counter() - t0,
-        )
-    return out
+    with telemetry.launch(telemetry.program_name(program), telemetry.launch_size_class(args)):
+        return program(*args, **kwargs)
 
 
 def pad_pow2(n: int, floor: int = 8) -> int:

@@ -43,15 +43,19 @@ regression class; same doctrine as `ssz/hash.py`).
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Iterable
 
 import numpy as np
 
+from lodestar_tpu import telemetry
+
 from .hash import hash_nodes_cpu
 
 __all__ = [
     "HTR_MODES",
+    "FLUSH_STEPS",
     "configure_device_htr",
     "device_htr_active",
     "DirtyCollector",
@@ -83,6 +87,12 @@ _launch_count = 0  # guarded by: advisory-only (test/debug counter; metrics are 
 #: so the number of distinct compiled programs stays logarithmic in the
 #: largest level ever flushed (the ops/prep.py size-class doctrine).
 _MIN_PAIR_CLASS = 8
+
+#: what a collector flush spends its wall on, as `DirtyCollector.steps`
+#: and the profiler's host spans name it: index arithmetic over the
+#: dirty set, gathering pairs into launch layout, the device call and
+#: the wait for it, writing roots back, and levels hashed on the host
+FLUSH_STEPS = ("htr.index", "htr.gather", "htr.device", "htr.scatter", "htr.host_hash")
 
 #: below this many pairs a level stays on the host hasher even when the
 #: device backend is selected — a tiny level is far cheaper as a couple
@@ -147,38 +157,40 @@ def pad_pow2_pairs(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
-def _device_level(data: np.ndarray) -> np.ndarray:
+def _device_level(data: np.ndarray, steps: dict | None = None) -> np.ndarray:
     """One merkle level on the device: (2N, 32) uint8 -> (N, 32) uint8,
     padded to a power-of-two pair size class (pad pairs repeat pair 0 so
     padding never manufactures new compile shapes or NaN-style hazards —
-    their digests are computed and discarded)."""
+    their digests are computed and discarded). `steps` is the collector's
+    tally of a flush (see `DirtyCollector.steps`); without one the step
+    seconds go to the `merkle_level` ledger entry's `phases`."""
     global _launch_count
     from lodestar_tpu.ops import sha256 as ops
 
     n = data.shape[0] // 2
     size = pad_pow2_pairs(n)
     if size != n:
-        padded = np.empty((2 * size, 32), dtype=np.uint8)
-        padded[: 2 * n] = data
-        padded[2 * n :] = np.tile(data[:2], (size - n, 1))
-        data = padded
+        with telemetry.phase("htr.gather", into=steps):
+            padded = np.empty((2 * size, 32), dtype=np.uint8)
+            padded[: 2 * n] = data
+            padded[2 * n :] = np.tile(data[:2], (size - n, 1))
+            data = padded
     _launch_count += 1
     m = _htr_metrics
     if m is not None:
         # counted HERE so hash_level dispatches (batch_container_roots
         # levels) and collector flushes feed the same launches metric
         m.launches.inc()
-    from lodestar_tpu import telemetry
-
     # launch telemetry at the same dispatch site as the counter: one
     # record per padded merkle_level launch, size class = the padded
     # pair count (the compiled program's shape bucket)
-    t0 = time.perf_counter() if telemetry.launch_telemetry_active() else 0.0
-    words = ops.words_from_bytes(data.tobytes())
-    out = np.asarray(ops.merkle_level(words))
-    if t0:
-        telemetry.record_launch("merkle_level", size, time.perf_counter() - t0)
-    roots = np.frombuffer(ops.bytes_from_words(out), dtype=np.uint8).reshape(-1, 32)
+    with telemetry.launch("merkle_level", size):
+        with telemetry.phase("htr.gather", into=steps):
+            words = ops.words_from_bytes(data.tobytes())
+        with telemetry.phase("htr.device", into=steps):
+            out = np.asarray(ops.merkle_level(words))
+    with telemetry.phase("htr.scatter", into=steps):
+        roots = np.frombuffer(ops.bytes_from_words(out), dtype=np.uint8).reshape(-1, 32)
     return roots[:n]
 
 
@@ -263,11 +275,14 @@ class DirtyCollector:
         self.levels = 0  # guarded by: flush-thread (per-call instance, single owner)
         self.dirty_chunks = 0  # guarded by: flush-thread (per-call instance, single owner)
         self.backend = "cpu"  # guarded by: flush-thread (per-call instance, single owner)
+        # seconds by FLUSH_STEPS name while launch telemetry is active, else empty
+        self.steps: dict[str, float] = {}  # guarded by: flush-thread (per-call instance, single owner)
 
     # -- feeding ---------------------------------------------------------------
 
     def add_stack_job(self, levels: list[np.ndarray], dirty: Iterable[int]) -> None:
-        dirty = np.asarray(sorted(set(int(i) for i in dirty)), dtype=np.int64)
+        with telemetry.phase("htr.index", into=self.steps):
+            dirty = np.asarray(sorted(set(int(i) for i in dirty)), dtype=np.int64)
         if dirty.size == 0:
             return
         self.dirty_chunks += int(dirty.size)
@@ -305,71 +320,82 @@ class DirtyCollector:
         healthy tree-depth count."""
         max_level = self._max_level()
         self.levels = max_level
+        steps = self.steps
         # per stack job: dirty node indices at the current level
         frontiers = [j.dirty for j in self.stack_jobs]
         for lvl in range(1, max_level + 1):
-            chunks: list[np.ndarray] = []
             sinks: list[tuple] = []  # ("stack", job, parents) | ("node", nodes)
-            for ji, job in enumerate(self.stack_jobs):
-                if lvl >= len(job.levels) or frontiers[ji].size == 0:
+            with telemetry.phase("htr.index", into=steps):
+                picks: list[tuple[np.ndarray, np.ndarray]] = []  # (level below, its dirty pairs' rows)
+                for ji, job in enumerate(self.stack_jobs):
+                    if lvl >= len(job.levels) or frontiers[ji].size == 0:
+                        continue
+                    parents = np.unique(frontiers[ji] >> 1)
+                    pair_idx = np.empty(2 * parents.size, dtype=np.int64)
+                    pair_idx[0::2] = 2 * parents
+                    pair_idx[1::2] = 2 * parents + 1
+                    picks.append((job.levels[lvl - 1], pair_idx))
+                    sinks.append(("stack", ji, parents))
+                    frontiers[ji] = parents
+            with telemetry.phase("htr.gather", into=steps):
+                chunks = [below[pair_idx] for below, pair_idx in picks]
+                for job in self.node_jobs:
+                    nodes = job.groups.get(lvl)
+                    if not nodes:
+                        continue
+                    data = np.empty((2 * len(nodes), 32), dtype=np.uint8)
+                    for i, n in enumerate(nodes):
+                        data[2 * i] = np.frombuffer(n.left._root, dtype=np.uint8)
+                        data[2 * i + 1] = np.frombuffer(n.right._root, dtype=np.uint8)
+                    chunks.append(data)
+                    sinks.append(("node", nodes))
+                if not chunks:
                     continue
-                parents = np.unique(frontiers[ji] >> 1)
-                below = job.levels[lvl - 1]
-                pair_idx = np.empty(2 * parents.size, dtype=np.int64)
-                pair_idx[0::2] = 2 * parents
-                pair_idx[1::2] = 2 * parents + 1
-                chunks.append(below[pair_idx])
-                sinks.append(("stack", ji, parents))
-                frontiers[ji] = parents
-            for job in self.node_jobs:
-                nodes = job.groups.get(lvl)
-                if not nodes:
-                    continue
-                data = np.empty((2 * len(nodes), 32), dtype=np.uint8)
-                for i, n in enumerate(nodes):
-                    data[2 * i] = np.frombuffer(n.left._root, dtype=np.uint8)
-                    data[2 * i + 1] = np.frombuffer(n.right._root, dtype=np.uint8)
-                chunks.append(data)
-                sinks.append(("node", nodes))
-            if not chunks:
-                continue
-            data = chunks[0] if len(chunks) == 1 else np.concatenate(chunks, axis=0)
+                data = chunks[0] if len(chunks) == 1 else np.concatenate(chunks, axis=0)
             # the size asymmetry applies per level even on the device
             # pass: a sparse flush's 1-2-pair tail levels are far
             # cheaper as host digests than as padded dispatches (the
             # invariant is "at most one DEVICE launch per level", so
             # host-hashing a tiny level only tightens it)
-            if count_launches and data.shape[0] // 2 < _min_flush_pairs():
-                roots = hash_nodes_cpu(data)
+            if count_launches and data.shape[0] // 2 >= _min_flush_pairs():
+                roots = level_fn(data)  # `_device_level` names its own steps
+                self.launches += 1
             else:
-                roots = level_fn(data)
-                if count_launches:
-                    self.launches += 1
-            off = 0
-            for sink in sinks:
-                if sink[0] == "stack":
-                    _, ji, parents = sink
-                    self.stack_jobs[ji].levels[lvl][parents] = roots[off : off + parents.size]
-                    off += parents.size
-                else:
-                    _, nodes = sink
-                    for i, n in enumerate(nodes):
-                        n._root = roots[off + i].tobytes()
-                    off += len(nodes)
+                with telemetry.phase("htr.host_hash", into=steps):
+                    roots = hash_nodes_cpu(data) if count_launches else level_fn(data)
+            with telemetry.phase("htr.scatter", into=steps):
+                off = 0
+                for sink in sinks:
+                    if sink[0] == "stack":
+                        _, ji, parents = sink
+                        self.stack_jobs[ji].levels[lvl][parents] = roots[off : off + parents.size]
+                        off += parents.size
+                    else:
+                        _, nodes = sink
+                        for i, n in enumerate(nodes):
+                            n._root = roots[off + i].tobytes()
+                        off += len(nodes)
 
     def flush(self) -> dict:
         """One collector flush: at most one `hash_pairs` dispatch per
         tree level across EVERY job. Device errors degrade the whole
         flush to the CPU level hasher (recomputed from leaf inputs —
         partially-grafted device roots are overwritten, never trusted).
-        Returns the flush stats for span/metric attribution."""
+        Returns the flush stats for span/metric attribution; `steps` is
+        where `seconds` (and `add_stack_job` before it) went, by
+        FLUSH_STEPS name, and empty while launch telemetry is inactive."""
         t0 = time.monotonic()
         device = device_htr_active()
         self.launches = 0
+        if telemetry.launch_telemetry_active():
+            for name in FLUSH_STEPS:
+                self.steps.setdefault(name, 0.0)
         if device:
             self.backend = "device"
             try:
-                self._flush_with(_device_level, count_launches=True)
+                self._flush_with(
+                    functools.partial(_device_level, steps=self.steps), count_launches=True
+                )
             except Exception as e:
                 note_fallback(e)
                 self.backend = "cpu"
@@ -384,6 +410,7 @@ class DirtyCollector:
             "launches": self.launches,
             "dirty_chunks": self.dirty_chunks,
             "seconds": time.monotonic() - t0,
+            "steps": self.steps,
         }
         m = _htr_metrics
         if m is not None:

@@ -1,52 +1,38 @@
-"""Device launch telemetry: per-dispatch wall time, program identity,
-size class, lane/device, and first-call compile detection.
+"""Device launch telemetry: what every counted dispatch cost on the host's
+clock, what the wall was spent on, and the same names on the profiler's clock.
 
-PR 9 proved launch count is a first-order lever (5→3 prep launches =
-+23% replay throughput from overlap alone), and the "Enabling AI ASICs
-for ZKP" paper (PAPERS.md) makes launch/dispatch overhead the central
-argument — but until this module the system could only say *how many*
-device dispatches fired (`lodestar_bls_prep_launches_total`, the HTR
-dispatch counter, the per-lane launch counters), not where the wall
-time went: compile vs dispatch latency vs device execution, per program
-and size class. This module is the one seam every counted dispatch
-reports through:
+Two context managers are the one seam every counted dispatch reports through
+(`ops/prep.py:_dispatch`, `ssz/device_htr.py:_device_level`,
+`chain/bls/mesh.py:mesh_launch`, and the verify core and the sharded
+collective in `models/batch_verify.py`):
 
-* `ops/prep.py:_dispatch` — every prep program launch (fused stages,
-  per-leg reference schedule, hash-to-G2).
-* `ssz/device_htr.py:_device_level` — every batched SHA-256 merkle
-  level dispatch (collector flushes + shared-hook batch levels).
-* `chain/bls/mesh.py:mesh_launch` — every verify launch a mesh lane
-  serves (the whole bytes-in → verdict-out chain on that lane).
-* `models/batch_verify.py` — the RLC verify core and the sharded
-  collective (the jit-cache seams the compile detection rides).
+* `launch(program, size_class, lane=None)` — one **ledger entry** per
+  dispatch: wall seconds inside the `with` block (on an async backend:
+  dispatch plus whatever blocking transfer the block performs), the
+  program's name, the pow-2 size class its executable was compiled for,
+  the lane, first-call-per-(program, size class) **compile** detection,
+  the thread (`tid`), the launch that encloses it on that thread
+  (`parent`, its `seq`; None at top level) and `phases`. Launches nest
+  (`bls_lane_verify` holds `_single_launch_verify`), so sums are taken
+  over top-level entries.
+* `phase(name, into=None)` — elapsed seconds added to `phases[name]` of
+  the innermost open launch on the thread, or to `into` where a caller
+  keeps its own dict (the dirty collector's `steps`). A phase on a thread
+  with no open launch leaves no number behind.
 
-What gets recorded per dispatch:
-
-* **wall seconds** — host-observed time inside the dispatch call. On
-  synchronous backends (CPU XLA) this includes device execution; on
-  async backends it is dispatch + any blocking host transfer the
-  program performs. Honest name: *launch wall time at the seam*, not
-  "device execution time" (that is the XLA profiler's job,
-  `utils/tracing.py`).
-* **program** — the dispatched callable's name (`_prep_field_stage`,
-  `merkle_level`, `bls_lane_verify`, ...).
-* **size class** — the pow-2-padded batch size (the compile-cache
-  bucketing of `ops/prep.pad_pow2`), so per-class latency is readable
-  and label cardinality stays logarithmic.
-* **compile** — first-call-per-(program, size class) detection: the
-  jit caches compile one program per (callable, shape bucket), so the
-  first dispatch of a key in this process pays trace+compile (or the
-  persistent-cache load) and every later one is a cache hit. The
-  first-call flag separates the minutes-long compile outliers from the
-  steady-state dispatch latency on the same histogram.
-* **lane/device** — which chip served (mesh seam), when known.
+Both also open a `jax.profiler.TraceAnnotation` of the same name, so a
+profile of a node (XProf, Perfetto, or the benchmark's `--trace 1`, which
+puts every idle gap of the device under the innermost host span) shows
+them on the device trace's clock. Span names carry no digits and no
+metadata: sizes and levels go on the ledger entry. The vocabulary is in
+PERF.md §3.
 
 Sinks:
 
 * Prometheus (`DeviceLaunchMetrics`, installed by the node):
   `lodestar_device_launch_seconds{program,size_class}`,
-  `lodestar_device_compile_seconds_total`,
-  `lodestar_device_compile_{hits,misses}_total{program}`.
+  `lodestar_device_compile_seconds_total` (top-level first calls only, so
+  it can be summed), `lodestar_device_compile_{hits,misses}_total{program}`.
 * A bounded in-process **launch ledger** (deque, default 256 entries)
   surfaced by `GET /eth/v0/debug/launches` and folded into slow-slot
   dumps (`slow_slot_launches`) — a slow slot names its launches.
@@ -57,13 +43,15 @@ node) and stays off in bare library use; "on" records even without
 metrics (ledger + process-local counters — tests, benches); "off"
 disables everything, leaving the seams one flag-check from free.
 
-This module imports nothing heavy (stdlib only) and never touches a
-JAX backend — the r3 import-hygiene doctrine; the seams that import it
-are the ones that already own a device dispatch.
+This module imports the standard library only and never touches a JAX
+backend (`chain/bls/mesh.py` relies on it): the annotation class is taken
+from `sys.modules` at first use, and where JAX is not loaded there is no
+profiler to write to.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import deque
@@ -73,6 +61,8 @@ __all__ = [
     "DEFAULT_LEDGER_SIZE",
     "configure_launch_telemetry",
     "launch_telemetry_active",
+    "launch",
+    "phase",
     "record_launch",
     "launch_size_class",
     "size_class_of",
@@ -98,6 +88,7 @@ _ledger: deque = deque(maxlen=DEFAULT_LEDGER_SIZE)  # guarded by: _lock
 _seen_keys: set = set()  # guarded by: _lock — (program, size_class) compile-detection keys
 _seq = 0  # guarded by: _lock — monotonic dispatch sequence number
 _compiles = 0  # guarded by: _lock — first-call dispatches observed
+_tls = threading.local()  # .open: this thread's stack of open launches
 
 
 def configure_launch_telemetry(
@@ -167,6 +158,123 @@ def program_name(program) -> str:
     return type(program).__name__
 
 
+def _annotate(name: str):
+    """An entered `jax.profiler.TraceAnnotation(name)`, or None where JAX
+    is not loaded (no profiler to write to). Outside a profiler session
+    the annotation is one flag check inside TraceMe."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    note = jax.profiler.TraceAnnotation(name)
+    note.__enter__()
+    return note
+
+
+class _Off:
+    """What `launch` and `phase` return while telemetry is inactive."""
+
+    __slots__ = ()
+    entry = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def add_phase(self, name: str, seconds: float) -> None:
+        pass
+
+
+_OFF = _Off()
+
+
+class _Launch:
+    __slots__ = ("program", "size_class", "lane", "phases", "seq", "parent", "entry", "_t0", "_note")
+
+    def __init__(self, program: str, size_class: int, lane: str | None):
+        self.program, self.size_class, self.lane = program, size_class, lane
+        self.phases: dict[str, float] = {}
+        self.entry: dict | None = None
+
+    def add_phase(self, name: str, seconds: float) -> None:
+        """Seconds spent for this launch where no `phase` block on this
+        thread could see them (prep staged on another thread)."""
+        self.phases[name] = self.phases.get(name, 0.0) + seconds
+
+    def __enter__(self):
+        global _seq
+        stack = getattr(_tls, "open", None)
+        if stack is None:
+            stack = _tls.open = []
+        self.parent = stack[-1].seq if stack else None
+        with _lock:
+            _seq += 1
+            self.seq = _seq
+        stack.append(self)
+        self._note = _annotate(self.program)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        seconds = time.perf_counter() - self._t0
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        _tls.open.pop()
+        if exc_type is None:  # a dispatch that raised is the caller's fallback to count, not a launch
+            self.entry = _record(
+                self.program, self.size_class, seconds, self.lane,
+                seq=self.seq, parent=self.parent, phases=self.phases,
+            )
+        return False
+
+
+class _Phase:
+    __slots__ = ("name", "into", "_t0", "_note")
+
+    def __init__(self, name: str, into: dict | None):
+        self.name, self.into = name, into
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()  # the span's own cost counts as the phase's
+        self._note = _annotate(self.name)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+        seconds = time.perf_counter() - self._t0
+        into = self.into
+        if into is None:
+            stack = getattr(_tls, "open", None)
+            if not stack:
+                return False
+            into = stack[-1].phases
+        into[self.name] = into.get(self.name, 0.0) + seconds
+        return False
+
+
+def launch(program: str, size_class: int, lane: str | None = None):
+    """`with launch(...)` around one device dispatch: a ledger entry and
+    the metric observations for its extent (see `record_launch`), with
+    the phases opened inside it on this thread, under a profiler span
+    named `program`. `.entry` is the ledger entry after the block; a
+    block that raises writes none. Inactive: one flag check."""
+    if not launch_telemetry_active():
+        return _OFF
+    return _Launch(program, size_class, lane)
+
+
+def phase(name: str, into: dict | None = None):
+    """`with phase(name)` around a stretch of host work: a profiler span
+    named `name`, and its seconds added to `phases[name]` of the
+    innermost launch open on this thread (dropped where there is none),
+    or to `into[name]` where the caller keeps the dict."""
+    if not launch_telemetry_active():
+        return _OFF
+    return _Phase(name, into)
+
+
 def record_launch(
     program: str,
     size_class: int,
@@ -174,31 +282,42 @@ def record_launch(
     *,
     lane: str | None = None,
 ) -> dict | None:
-    """Record one device dispatch: ledger entry + metric observations.
+    """Record one top-level device dispatch whose wall the caller took
+    itself: ledger entry + metric observations. Returns the ledger entry
+    (tests), or None when inactive."""
+    if not launch_telemetry_active():
+        return None
+    return _record(program, size_class, seconds, lane)
 
-    Compile detection is first-call-per-(program, size_class): the jit
+
+def _record(program, size_class, seconds, lane, *, seq=None, parent=None, phases=None) -> dict:
+    """Compile detection is first-call-per-(program, size_class): the jit
     caches hold one executable per key, so the first dispatch of a key
     in this process carries trace+compile (or the persistent-cache
     load) and is counted as a miss; every later dispatch of the key is
-    a hit. Returns the ledger entry (tests), or None when inactive."""
-    if not launch_telemetry_active():
-        return None
+    a hit. First calls nest like their launches, so only a top-level
+    one adds its seconds to the compile counter."""
     global _seq, _compiles
     key = (program, size_class)
     with _lock:
-        _seq += 1
+        if seq is None:
+            _seq += 1
+            seq = _seq
         compile_ = key not in _seen_keys
         _seen_keys.add(key)
         if compile_:
             _compiles += 1
         entry = {
-            "seq": _seq,
+            "seq": seq,
             "program": program,
             "size_class": size_class,
             "seconds": seconds,
             "lane": lane,
             "compile": compile_,
             "t_mono_ns": time.monotonic_ns(),
+            "tid": threading.get_ident(),
+            "parent": parent,
+            "phases": phases if phases is not None else {},
         }
         _ledger.append(entry)
     m = _metrics
@@ -207,7 +326,8 @@ def record_launch(
             m.launch_seconds.labels(program, str(size_class)).observe(seconds)
             if compile_:
                 m.compile_misses.labels(program).inc()
-                m.compile_seconds.inc(seconds)
+                if parent is None:
+                    m.compile_seconds.inc(seconds)
             else:
                 m.compile_hits.labels(program).inc()
         except Exception:
@@ -217,33 +337,40 @@ def record_launch(
 
 def launch_ledger(n: int | None = None) -> list[dict]:
     """The most recent `n` ledger entries (all when None), oldest
-    first. Entries are copies — callers can't corrupt the ledger."""
+    first by the time they were written (a launch takes its `seq` when
+    it opens, so an enclosing launch follows the ones it holds).
+    Entries are copies — callers can't corrupt the ledger."""
     with _lock:
         entries = list(_ledger)
     if n is not None and n >= 0:
         entries = entries[-n:] if n else []
-    return [dict(e) for e in entries]
+    return [{**e, "phases": dict(e["phases"])} for e in entries]
 
 
 def launch_totals() -> dict:
-    """Cumulative view for the debug route: dispatch count, compile
-    count, distinct (program, size_class) keys, and per-program launch
-    counts over the CURRENT ledger window (the full-history numbers
-    are the Prometheus counters)."""
+    """Cumulative view for the debug route: launches opened (one that
+    raised took its number and wrote no entry), compile count, distinct
+    (program, size_class) keys, and per-program launch counts and
+    per-phase seconds over the CURRENT ledger window (the full-history
+    numbers are the Prometheus counters)."""
     with _lock:
         entries = list(_ledger)
         seq = _seq
         compiles = _compiles
         keys = len(_seen_keys)
     by_program: dict[str, int] = {}
+    by_phase: dict[str, float] = {}
     for e in entries:
         by_program[e["program"]] = by_program.get(e["program"], 0) + 1
+        for name, seconds in e["phases"].items():
+            by_phase[name] = by_phase.get(name, 0.0) + seconds
     return {
         "launches": seq,
         "compiles": compiles,
         "distinct_keys": keys,
         "ledger_entries": len(entries),
         "ledger_by_program": by_program,
+        "ledger_phase_seconds": by_phase,
     }
 
 

@@ -37,10 +37,11 @@ durations also feed the `lodestar_trace_*` Prometheus families so the
 "block pipeline trace" Grafana dashboard renders without scraping the
 debug API.
 
-This is the event-level layer `utils/tracing.py` (env-gated XLA
-profiler capture of device internals) composes with: XLA traces show
-what the chip did inside one launch; these spans show where a slot's
-wall-clock went across the host pipeline.
+These spans show where a slot's wall-clock went across the host
+pipeline. What the chip did inside one launch, and what the host did
+between launches, is on the profiler's clock instead: the launch spans,
+phases and stage scopes of `lodestar_tpu/telemetry.py` (PERF.md §3),
+read by `python3 perfbench/run.py ... --trace 1` or any XProf capture.
 """
 
 from __future__ import annotations

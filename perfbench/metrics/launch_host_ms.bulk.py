@@ -1,0 +1,3 @@
+"""Pool: median host work of a `bls_lane_verify` launch before the device can start (`bls.parse` + `bls.dispatch`), bulk traffic."""
+
+from perfbench.phase_readers import launch_host_ms as read  # noqa: F401

@@ -158,7 +158,7 @@ def test_differential_fuzz_across_forks(fork, minimal_preset, device_on, monkeyp
 
     real_device_level = dh._device_level
 
-    def boom(data):
+    def boom(data, steps=None):
         raise RuntimeError("injected: force the CPU incremental path")
 
     for round_ in range(5):
@@ -219,7 +219,7 @@ def test_device_error_falls_back_with_identical_root(
         state = _mk_state(p, "altair")
         expect = state.type.hash_tree_root(state)
 
-        def boom(data):
+        def boom(data, steps=None):
             raise RuntimeError("injected device fault")
 
         monkeypatch.setattr(dh, "_device_level", boom)
