@@ -16,6 +16,10 @@ CPU reference (the pure-Python BLS oracle, hashlib):
    one tampered job and one malformed job through `BlsOffloadClient`;
 4. the node's own `BlsDeviceVerifierPool` takes 1,024 sets at
    gossip-attestation priority, so packages reach the 512-set cap;
+   the multi-job launch at (2 slots, 128 rows) gives each job the
+   oracle's verdict with a cancelling pair in the first job only and
+   an off-subgroup key in the last only, and the pool answers a
+   131-set block with ONE such launch;
 5. `merkle_root_device` at 2^20 chunks (the 1M-validator shape) and
    `DirtyCollector` flushes of a 2^20-leaf stack with 512 and 2^17 dirty
    leaves must give hashlib's roots with `backend == "device"`;
@@ -111,6 +115,23 @@ def make_batches(seed: int) -> dict:
             pubkey=off_subgroup_pk, message=valid[j].message, signature=valid[j].signature
         )
         batches[size] = {"valid": valid, "tampered": tampered, "invalid": invalid}
+    # the two jobs of a 131-set block (66 and 65 sets), and each with a
+    # fault of its own: a pair of signatures shifted by +D and -D, which
+    # only distinct blinding coefficients catch, and the off-subgroup key
+    first, last = base[:66], base[63:128]
+    d = curve.g2_mul(curve.G2_GEN, rng.randrange(1, fields.R))
+    cancelling = list(first)
+    for i, shift in zip(rng.sample(range(len(first)), 2), (d, curve.g2_neg(d))):
+        sig = curve.g2_add(serdes.g2_from_bytes(first[i].signature), shift)
+        cancelling[i] = SignatureSet(
+            pubkey=first[i].pubkey, message=first[i].message, signature=serdes.g2_to_bytes(sig)
+        )
+    off_key = list(last)
+    i = rng.randrange(len(last))
+    off_key[i] = SignatureSet(
+        pubkey=off_subgroup_pk, message=last[i].message, signature=last[i].signature
+    )
+    batches["jobs"] = {"first": first, "last": last, "cancelling": cancelling, "off_key": off_key}
     malformed = list(base)
     malformed[rng.randrange(len(base))] = SignatureSet(
         pubkey=b"\x00" * 48, message=base[0].message, signature=b"\xff" * 96
@@ -124,6 +145,8 @@ def oracle_verdicts(batches: dict) -> dict:
     from lodestar_tpu.crypto.bls.api import verify_signature_sets
 
     out = {"malformed": verify_signature_sets(batches["malformed"])}
+    for name, sets in batches["jobs"].items():
+        out[("job", name)] = verify_signature_sets(sets)
     for size in SIZE_CLASSES:
         for kind, sets in batches[size].items():
             out[(size, kind)] = verify_signature_sets(sets)
@@ -244,6 +267,49 @@ async def phase_node_pool(node, batches: dict, oracle) -> dict:
         check(m[key] == 0, f"pool {key} = {m[key]}")
     log(f"node pool: {len(sets)} sets -> {got}; {m}")
     return {"verdict": bool(got), "metrics": m, "lanes": pool.mesh.lane_states()}
+
+
+GROUPED_CASES = (("first", "last"), ("cancelling", "last"), ("first", "off_key"))
+
+
+async def phase_grouped(node, batches: dict, oracle) -> dict:
+    """The multi-job launch at (2, 128): a verdict a job, each the
+    oracle's for that job alone; then the pool's road to it."""
+    from lodestar_tpu.chain.bls import VerifySignatureOpts
+    from lodestar_tpu.models import batch_verify as bv
+    from lodestar_tpu.scheduler import PriorityClass
+
+    jobs = batches["jobs"]
+    out = {}
+    for names in GROUPED_CASES:
+        t0 = time.monotonic()
+        got = bv.verify_sets_grouped_launch([jobs[n] for n in names])
+        want = [oracle()[("job", n)] for n in names]
+        log(f"grouped launch {names}: {got} (oracle {want}) {time.monotonic() - t0:.1f}s")
+        check(got == want, f"grouped launch on {names}: {got}, oracle {want}")
+        out["/".join(names)] = got
+    check(out["cancelling/last"] == [False, True] and out["first/off_key"] == [True, False],
+          f"the planted faults did not fail their own jobs only: {out}")
+    # a 131-set block through the pool: jobs of 66 and 65, one launch
+    pool = node.bls
+    block = jobs["first"] + jobs["last"]
+    check(len(block) == 131, f"block of {len(block)} sets")
+    lane_launches = lambda: sum(lane.launches for lane in pool.mesh.lanes)  # noqa: E731
+    before, launches = dict(pool.metrics), lane_launches()
+    got = await pool.verify_signature_sets(
+        block, VerifySignatureOpts(priority=PriorityClass.GOSSIP_BLOCK)
+    )
+    check(got is True, f"pool on an honest 131-set block: {got}")
+    check(pool.metrics["jobs_started"] - before["jobs_started"] == 2, f"pool jobs {pool.metrics}")
+    took = lane_launches() - launches
+    check(took == 1, f"a block took {took} launches")
+    tampered = jobs["cancelling"] + jobs["last"]
+    check(await pool.verify_signature_sets(
+        tampered, VerifySignatureOpts(priority=PriorityClass.GOSSIP_BLOCK)) is False,
+        "pool passed a block with a cancelling pair")
+    log(f"pool: 131-set block in {took} launch")
+    out["pool_block_launches"] = took
+    return out
 
 
 def phase_state_root(seed: int) -> dict:
@@ -378,6 +444,7 @@ async def run(seed: int, device: dict, compile_stats: dict) -> dict:
             backend = build_backend()  # default flags, as server.main() does
             report["served"] = await phase_served(backend, batches, oracle)
             report["node_pool"] = await phase_node_pool(node, batches, oracle)
+            report["grouped"] = await phase_grouped(node, batches, oracle)
         report["oracle"] = {str(k): v for k, v in oracle().items()}
         report["state_root"] = phase_state_root(seed)
         if device["count"] > 1:
@@ -398,6 +465,8 @@ async def run(seed: int, device: dict, compile_stats: dict) -> dict:
         for program in ("_single_launch_verify", "_prep_field_stage", "_prep_subgroup_stage",
                         "hash_finish", "batch_verify_staged", "bls_lane_verify"):
             check(f"{program}/{size}" in ran, f"ledger has no {program}/{size}: {sorted(ran)}")
+    for program in ("_grouped_launch_verify", "bls_lane_verify"):
+        check(f"{program}/256" in ran, f"ledger has no {program}/256: {sorted(ran)}")
     check(f"_merkle_root_fixed/{1 << TREE_DEPTH}" in ran, f"ledger has no merkle root: {sorted(ran)}")
     check(any(k.startswith("merkle_level/") for k in ran), "ledger has no merkle_level launch")
     report["compile"] = {k: round(v, 1) for k, v in compile_stats.items()}

@@ -105,7 +105,11 @@ class MeshLane:
     (optional) is the lane-pinned single-launch entry
     (models `make_lane_verify_single_fn`): `mesh_launch` prefers it for
     unstaged work while `--bls-single-launch` resolves active, so a
-    whole batch is one resident program on this lane's die."""
+    whole batch is one resident program on this lane's die.
+    `verify_grouped_fn` (optional) is the multi-job entry (models
+    `make_lane_verify_grouped_fn`): a list of jobs' sets in, ONE launch,
+    a list of verdicts out; the pool forms multi-job units only where
+    every lane has one."""
 
     def __init__(
         self,
@@ -117,6 +121,7 @@ class MeshLane:
         wedge_reset_s: float = LANE_WEDGE_RESET_S,
         verify_prepared_fn: Callable | None = None,
         verify_single_fn: Callable | None = None,
+        verify_grouped_fn: Callable | None = None,
     ) -> None:
         from lodestar_tpu.offload.resilience import CircuitBreaker
 
@@ -125,6 +130,7 @@ class MeshLane:
         self.verify_fn = verify_fn
         self.verify_prepared_fn = verify_prepared_fn
         self.verify_single_fn = verify_single_fn
+        self.verify_grouped_fn = verify_grouped_fn
         self.occupancy = OccupancyTracker()
         self.breaker = CircuitBreaker(
             failure_threshold=wedge_threshold,
@@ -188,6 +194,16 @@ class VerifierMesh:
     def sharding_available(self) -> bool:
         return self.sharded_fn is not None and not self.sharded_breaker.is_open
 
+    def grouping_available(self) -> bool:
+        """Whether a multi-job unit is ONE launch on whichever lane
+        serves it: every lane has the grouped entry and
+        `--bls-single-launch` resolves active (the grouped program is
+        the single-launch program with a slot a job)."""
+        return (
+            all(lane.verify_grouped_fn is not None for lane in self.lanes)
+            and _single_launch_active()
+        )
+
     def occupancy(self) -> float:
         lanes = self.available() or self.lanes
         return sum(lane.occupancy.occupancy() for lane in lanes) / len(lanes)
@@ -224,7 +240,8 @@ def mesh_launch(
     on_launch: Callable | None = None,
     on_wedge: Callable | None = None,
     prepared: "PreparedSets | None" = None,
-) -> tuple[bool, MeshLane]:
+    grouped: bool = False,
+) -> tuple["bool | list[bool]", MeshLane]:
     """One verify launch with per-lane wedge accounting and cross-lane
     error retry — the single-launch core shared by the pool's executor
     path and the standalone offload host's backend.
@@ -243,7 +260,20 @@ def mesh_launch(
     staged inputs go through the lane's `verify_prepared_fn`; a staged
     prep ERROR — or a lane without a prepared callable — re-preps
     through the plain `verify_fn`, so the fail-closed degradation chain
-    is byte-for-byte the pre-pipeline one."""
+    is byte-for-byte the pre-pipeline one.
+
+    `grouped` makes it the multi-job launch: `sets` is then a list of
+    jobs' sets, the lane's `verify_grouped_fn` (or `verify_prepared_fn`
+    on staged inputs) serves them in ONE launch, and `ok` is a list of
+    verdicts, one a job. Breaker accounting, cross-lane retry and the
+    one `bls_lane_verify` ledger entry are the same; its size class is
+    the launch's rows (slots times a slot's size class)."""
+    if grouped:
+        size_class = telemetry.size_class_of(len(sets), floor=2) * max(
+            telemetry.size_class_of(len(job)) for job in sets
+        )
+    else:
+        size_class = telemetry.size_class_of(len(sets))
     if prefer is None or (prefer.wedged and mesh.available()):
         # no preference, or the preferred lane wedged since dispatch
         # (mid-package: chunk N trips the breaker, chunk N+1 must not
@@ -266,10 +296,10 @@ def mesh_launch(
                 # prep rejected the batch: verdict final, no backend
                 # call — not a launch
                 with current.occupancy.launch():
-                    ok = False
+                    ok = [False] * len(sets) if grouped else False
             else:
                 with telemetry.launch(
-                    "bls_lane_verify", telemetry.size_class_of(len(sets)), lane=current.label
+                    "bls_lane_verify", size_class, lane=current.label
                 ) as tel, current.occupancy.launch():
                     if use_staged and current.verify_prepared_fn is not None:
                         info = prepared.info
@@ -277,7 +307,9 @@ def mesh_launch(
                             # staged on the prep thread: its parse seconds
                             # cross threads with the inputs
                             tel.add_phase("bls.parse", (info["end_ns"] - info["start_ns"]) / 1e9)
-                        ok = bool(current.verify_prepared_fn(prepared.inputs))
+                        ok = current.verify_prepared_fn(prepared.inputs)
+                    elif grouped:
+                        ok = current.verify_grouped_fn(sets)
                     elif (
                         current.verify_single_fn is not None
                         and _single_launch_active()
@@ -290,6 +322,7 @@ def mesh_launch(
                         ok = bool(current.verify_single_fn(sets))
                     else:
                         ok = bool(current.verify_fn(sets))
+                    ok = [bool(v) for v in ok] if grouped else bool(ok)
         except Exception:
             # an error on a staged-inputs attempt may be input-bound
             # (arrays committed to the sick die, a malformed staging) —
@@ -324,6 +357,7 @@ def single_lane_mesh(
     wedge_threshold: int = LANE_WEDGE_THRESHOLD,
     verify_prepared_fn: Callable | None = None,
     verify_single_fn: Callable | None = None,
+    verify_grouped_fn: Callable | None = None,
 ) -> VerifierMesh:
     """The pre-mesh shape: one lane, no sharded collective."""
     return VerifierMesh(
@@ -334,6 +368,7 @@ def single_lane_mesh(
                 wedge_threshold=wedge_threshold,
                 verify_prepared_fn=verify_prepared_fn,
                 verify_single_fn=verify_single_fn,
+                verify_grouped_fn=verify_grouped_fn,
             )
         ]
     )
@@ -367,6 +402,7 @@ def build_device_mesh(
             wedge_threshold=wedge_threshold,
             verify_prepared_fn=bv.verify_prepared,
             verify_single_fn=bv.verify_sets_single_launch,
+            verify_grouped_fn=bv.verify_sets_grouped_launch,
         )
 
     if mode == "off":
@@ -386,6 +422,7 @@ def build_device_mesh(
             wedge_threshold=wedge_threshold,
             verify_prepared_fn=bv.make_lane_verify_prepared_fn(i),
             verify_single_fn=bv.make_lane_verify_single_fn(i),
+            verify_grouped_fn=bv.make_lane_verify_grouped_fn(i),
         )
         for i in range(n)
     ]

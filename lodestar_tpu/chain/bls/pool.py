@@ -18,6 +18,15 @@ N-worker thread pool replaced by one device pipeline:
 * **Fail-closed** (`index.ts:386-393` analogue): any backend error rejects
   the job with the error — it never resolves True. Callers treat rejection
   as invalid-block/peer-downscore, exactly like the reference.
+* **Multi-job launches** (`_launch_units`): a package's non-batchable
+  jobs of the 128 size class (more than 64 sets: the two halves of a
+  block's 131 sets, a sync committee's four jobs) ride ONE launch, up
+  to MAX_PACKAGE_SETS // MAX_SIGNATURE_SETS_PER_JOB = 4 a launch, a slot
+  of the program's rows and a verdict each — on one chip the reason
+  for the reference's cut (a job a core) is gone, and what a launch
+  costs before its first row (a dispatch, a round trip, the serial
+  depth of its chains) is paid once. Smaller jobs and batchable jobs
+  keep their own launches.
 * **Mesh lanes** (`chain/bls/mesh.py`): the pool serves a `VerifierMesh`
   of per-device launch lanes. One dispatcher waits for a free lane,
   dequeues through the shared priority queue, and places the package:
@@ -40,8 +49,8 @@ N-worker thread pool replaced by one device pipeline:
   priority-class queue (gossip block > gossip attestation > API >
   range sync > backfill; stride-weighted-fair + starvation aging)
   instead of FIFO, so a slot-deadline block never queues behind a
-  backfill batch. Bulk-class jobs run one per package — the bound on
-  how long they can head-of-line-block an arriving urgent job. Device
+  backfill batch. A bulk-class package is ONE launch — the bound on
+  how long it can head-of-line-block an arriving urgent job. Device
   launches feed per-lane EWMA occupancy trackers whose mesh aggregate
   backs a graded ACCEPT/SHED_BULK/REJECT admission view the offload
   server ships to clients. `scheduler_enabled=False` restores arrival
@@ -122,6 +131,8 @@ DEVICE_WEDGE_THRESHOLD = LANE_WEDGE_THRESHOLD
 # flood must not coalesce into one giant package that head-of-line
 # blocks an arriving gossip block for its whole duration
 MAX_PACKAGE_SETS = 4 * MAX_SIGNATURE_SETS_PER_JOB
+# jobs of the largest size class that share one launch, a slot each
+MAX_GROUP_JOBS = MAX_PACKAGE_SETS // MAX_SIGNATURE_SETS_PER_JOB
 
 
 def chunkify_maximize_chunk_size(arr: Sequence, max_len: int) -> list[list]:
@@ -163,6 +174,47 @@ class _Job:
         # slot-deadline slack ledger (None when the SLO layer is off —
         # the unconfigured path pays one None check per lifecycle edge)
         self.slo = slo.job_begin(priority, slot)
+
+
+def _groupable(job: _Job) -> bool:
+    """A job that may share a launch: non-batchable (it needs a verdict
+    of its own) and of the largest size class, so its slot is one the
+    job would fill alone; a smaller job keeps its smaller program."""
+    return (
+        not job.batchable
+        and telemetry.size_class_of(len(job.sets)) == MAX_SIGNATURE_SETS_PER_JOB
+    )
+
+
+def _launch_units(package: list[_Job], grouping: bool) -> tuple[list[list[_Job]], list[list[_Job]]]:
+    """THE unit boundaries of a package, for the staged prep and the
+    launches alike: (chunks, units). `chunks` are the RLC chunks of the
+    batchable jobs. `units` cover the non-batchable jobs in queue order:
+    one job (its own launch), or — where `grouping` — up to
+    MAX_GROUP_JOBS groupable jobs that ride one multi-job launch (a unit
+    stands where its first job stood)."""
+    chunks = chunkify_maximize_chunk_size(
+        [j for j in package if j.batchable], BATCHABLE_MIN_PER_CHUNK
+    )
+    units: list[list[_Job]] = []
+    filling: list[_Job] | None = None
+    for j in package:
+        if j.batchable:
+            continue
+        if not (grouping and _groupable(j)):
+            units.append([j])
+        elif filling is None or len(filling) == MAX_GROUP_JOBS:
+            filling = [j]
+            units.append(filling)
+        else:
+            filling.append(j)
+    return chunks, units
+
+
+def _unit_sets(unit: list[_Job]) -> list:
+    """What a unit's launch is handed: its job's sets, or for a
+    multi-job unit the list of its jobs' sets."""
+    return [j.sets for j in unit] if len(unit) > 1 else unit[0].sets
 
 
 class _OverlapTracker:
@@ -223,8 +275,9 @@ class _OverlapTracker:
 
 
 class _PrepUnit:
-    """One staged launch unit: the jobs it covers, their flattened sets,
-    and the prep outcome (PreparedSets: inputs / reject / error)."""
+    """One staged launch unit: the jobs it covers, their flattened sets
+    (a multi-job unit: the list of its jobs' sets), and the prep outcome
+    (PreparedSets: inputs / reject / error)."""
 
     __slots__ = ("jobs", "sets", "prepared")
 
@@ -238,11 +291,11 @@ class _PreppedPackage:
     """Staged launch units for one package (the package itself and its
     class ride the _Staged entry — this is just the prep output)."""
 
-    __slots__ = ("chunks", "singles")
+    __slots__ = ("chunks", "units")
 
-    def __init__(self, chunks, singles):
+    def __init__(self, chunks, units):
         self.chunks = chunks  # batchable RLC chunks, prep staged
-        self.singles = singles  # non-batchable jobs, prep staged
+        self.units = units  # non-batchable jobs, one or a group a unit, prep staged
 
 
 class _Staged:
@@ -300,6 +353,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         else:
             prepared_fn = None
             single_fn = None
+            grouped_fn = None
             if not explicit_fn:
                 # the default backend can verify staged inputs directly
                 # and serve the single-launch road; an injected mock
@@ -307,16 +361,19 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                 # and mesh_launch re-preps inline through the mock
                 from lodestar_tpu.models.batch_verify import (
                     verify_prepared,
+                    verify_sets_grouped_launch,
                     verify_sets_single_launch,
                 )
 
                 prepared_fn = verify_prepared
                 single_fn = verify_sets_single_launch
+                grouped_fn = verify_sets_grouped_launch
             self.mesh = single_lane_mesh(
                 verify_fn,
                 wedge_threshold=DEVICE_WEDGE_THRESHOLD,
                 verify_prepared_fn=prepared_fn,
                 verify_single_fn=single_fn,
+                verify_grouped_fn=grouped_fn,
             )
 
         # prep→verify double buffering: stage prep of package k+1 while
@@ -647,15 +704,33 @@ class BlsDeviceVerifierPool(IBlsVerifier):
     async def _next_package(self) -> tuple[list[_Job], PriorityClass]:
         """Dequeue one job and drain immediately-available work into the
         package: same class only under the scheduler, capped at
-        MAX_PACKAGE_SETS (and bulk runs ONE job per package) — both
+        MAX_PACKAGE_SETS (and a bulk package is ONE launch) — both
         bound how long an arriving gossip block can wait behind the
         in-flight launch; everything available in FIFO mode (the
-        pre-scheduler arm)."""
+        pre-scheduler arm). What one bulk launch can carry: one job, or,
+        from a groupable head job on, further jobs of its class while
+        each is groupable too and a slot is left — so a queue of small
+        backfill jobs never becomes a package of many launches. (Where
+        the mesh can shard, bulk stays one job a package: the
+        collective's units are that road's own.)"""
         job, cls, waited_ns = await self._jobs.get()
         with telemetry.phase("bls.next_package"):
             self._record_sched_dequeue(job, cls, waited_ns)
             package = [job]
-            if not (self.scheduler_enabled and cls in BULK_CLASSES):
+            if self.scheduler_enabled and cls in BULK_CLASSES:
+                if (
+                    _groupable(job)
+                    and not self.mesh.sharding_available()
+                    and self.mesh.grouping_available()
+                ):
+                    while len(package) < MAX_GROUP_JOBS:
+                        head = self._jobs.peek(cls)
+                        if head is None or not _groupable(head):
+                            break
+                        nxt = self._jobs.get_nowait(cls)
+                        self._record_sched_dequeue(*nxt)
+                        package.append(nxt[0])
+            else:
                 drain_cls = cls if self.scheduler_enabled else None
                 package_sets = len(job.sets)
                 while not self.scheduler_enabled or package_sets < MAX_PACKAGE_SETS:
@@ -807,20 +882,28 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             return None
         return min(free, key=lambda l: l.occupancy.occupancy()).index
 
-    def _prep_unit(self, jobs: list[_Job], sets: list) -> _PrepUnit:
+    def _prep_unit(self, jobs: list[_Job], sets: list, grouped: bool = False) -> _PrepUnit:
         """Stage prep for one launch unit (prep executor thread). Errors
         are CAPTURED, not raised: the launch re-preps through the plain
         verify path so a prep fault takes the exact pre-pipeline
         degradation road (device→host inside build_device_inputs;
-        anything worse raises at launch time and fails closed)."""
-        from lodestar_tpu.models.batch_verify import consume_prep_info
+        anything worse raises at launch time and fails closed). A
+        multi-job unit (`sets`: its jobs' sets) stages the host parse
+        of every slot; its launch is the single-launch program's."""
+        from lodestar_tpu.models.batch_verify import (
+            consume_prep_info,
+            prepare_grouped_launch_inputs,
+        )
 
         t0_ns = time.monotonic_ns()
         inputs = None
         error: Exception | None = None
         with self._overlap.prep():
             try:
-                inputs = self._prep_fn(sets, self._prep_lane_hint())
+                if grouped:
+                    inputs = prepare_grouped_launch_inputs(sets)
+                else:
+                    inputs = self._prep_fn(sets, self._prep_lane_hint())
             except Exception as e:
                 error = e
         info = consume_prep_info()
@@ -829,19 +912,15 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         return _PrepUnit(jobs, sets, PreparedSets(inputs, error, info))
 
     def _prep_package(self, package: list[_Job]) -> _PreppedPackage:
-        """Prep every launch unit the verify stage will dispatch: the
-        RLC chunks of the batchable jobs plus each non-batchable job —
-        the same unit boundaries `_verify_package` launches, so the
-        launch schedule is unchanged."""
+        """Prep every launch unit the verify stage will dispatch:
+        `_launch_units`' boundaries, the ones `_verify_package` launches
+        unstaged, so the launch schedule is unchanged."""
         self._staged_packages += 1
-        batchable = [j for j in package if j.batchable]
-        individual = [j for j in package if not j.batchable]
-        chunks = [
-            self._prep_unit(chunk, [s for j in chunk for s in j.sets])
-            for chunk in chunkify_maximize_chunk_size(batchable, BATCHABLE_MIN_PER_CHUNK)
-        ]
-        singles = [self._prep_unit([j], j.sets) for j in individual]
-        return _PreppedPackage(chunks, singles)
+        chunks, units = _launch_units(package, self.mesh.grouping_available())
+        return _PreppedPackage(
+            [self._prep_unit(chunk, [s for j in chunk for s in j.sets]) for chunk in chunks],
+            [self._prep_unit(unit, _unit_sets(unit), grouped=len(unit) > 1) for unit in units],
+        )
 
     def pipeline_stats(self) -> dict:
         """Pipeline wall-clock accounting: prep/verify busy time, their
@@ -941,16 +1020,18 @@ class BlsDeviceVerifierPool(IBlsVerifier):
     def _launch_sets(
         self,
         lane: MeshLane,
-        sets: list[SignatureSet],
+        sets: list,
         prepared: PreparedSets | None = None,
+        grouped: bool = False,
     ):
         """One verify launch, preferring `lane` (mesh_launch: breaker
         accounting + cross-lane error retry — a sick chip degrades its
         work onto the rest of the mesh with the verdict unchanged;
         raises only when every candidate lane errored, which with one
         lane is exactly the pre-mesh fail-closed behavior). `prepared`
-        carries staged pipeline inputs (see mesh_launch). Returns
-        (ok, lane_that_served)."""
+        carries staged pipeline inputs (see mesh_launch). `grouped`: the
+        multi-job launch (`sets` a list of jobs' sets, `ok` a verdict a
+        job). Returns (ok, lane_that_served)."""
         from .mesh import mesh_launch
 
         return mesh_launch(
@@ -958,7 +1039,8 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             sets,
             prefer=lane,
             prepared=prepared,
-            on_launch=lambda l: self._count_lane_launch(l, "single"),
+            grouped=grouped,
+            on_launch=lambda l: self._count_lane_launch(l, "grouped" if grouped else "single"),
             on_wedge=self._on_lane_wedge,
         )
 
@@ -1000,19 +1082,15 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                         {"sets": len(j.sets)},
                     )
 
-        batchable = [j for j in package if j.batchable]
-        individual = [j for j in package if not j.batchable]
         if prepped is None:
+            chunks, units = _launch_units(package, self.mesh.grouping_available())
             chunk_units = [
-                (chunk, [s for j in chunk for s in j.sets], None)
-                for chunk in chunkify_maximize_chunk_size(
-                    batchable, BATCHABLE_MIN_PER_CHUNK
-                )
+                (chunk, [s for j in chunk for s in j.sets], None) for chunk in chunks
             ]
-            single_units = [([j], j.sets, None) for j in individual]
+            job_units = [(unit, _unit_sets(unit), None) for unit in units]
         else:
             chunk_units = [(u.jobs, u.sets, u.prepared) for u in prepped.chunks]
-            single_units = [(u.jobs, u.sets, u.prepared) for u in prepped.singles]
+            job_units = [(u.jobs, u.sets, u.prepared) for u in prepped.units]
 
         # RLC-batch the batchable jobs in ≥16-set chunks; invalid batch →
         # retry each job individually (worker.ts:52-96)
@@ -1045,7 +1123,10 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                 self.metrics["batch_retries"] += 1
                 retries.extend(jobs)
 
-        for jobs, sets_, staged in single_units + [([j], j.sets, None) for j in retries]:
+        for jobs, sets_, staged in job_units + [([j], j.sets, None) for j in retries]:
+            if len(jobs) > 1:
+                self._verify_grouped_unit(jobs, sets_, staged, lane, traced)
+                continue
             j = jobs[0]
             t0 = time.monotonic_ns() if traced else 0
             try:
@@ -1066,6 +1147,38 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                     )
                 if not j.future.done():
                     j.future.get_loop().call_soon_threadsafe(self._reject, j, e)
+
+    def _verify_grouped_unit(
+        self, jobs: list[_Job], job_sets: list, staged, lane: MeshLane, traced: bool
+    ) -> None:
+        """One multi-job launch: each job's future gets its own slot's
+        verdict. An error (every candidate lane failed) fails the
+        unit's jobs closed, as a single launch's does its one."""
+        t0 = time.monotonic_ns() if traced else 0
+        n_sets = sum(len(j.sets) for j in jobs)
+        try:
+            verdicts, served = self._launch_sets(lane, job_sets, prepared=staged, grouped=True)
+            if len(verdicts) != len(jobs):
+                raise RuntimeError(
+                    f"multi-job launch answered {len(verdicts)} verdicts for {len(jobs)} jobs"
+                )
+        except Exception as e:
+            if traced:
+                self._trace_unit_prep(jobs, staged, t0)
+                self._trace_launch(
+                    jobs, t0, n_sets, "grouped_error", lane.label, lane=str(lane.index)
+                )
+            for j in jobs:
+                if not j.future.done():
+                    j.future.get_loop().call_soon_threadsafe(self._reject, j, e)
+            return
+        if traced:
+            self._trace_unit_prep(jobs, staged, t0)
+            self._trace_launch(
+                jobs, t0, n_sets, "grouped", served.label, lane=str(served.index)
+            )
+        for j, ok in zip(jobs, verdicts):
+            self._resolve(j, ok)
 
     def _trace_unit_prep(self, jobs: list[_Job], staged, t0: int) -> None:
         """`bls_prep` span for one launch unit: from the thread-local

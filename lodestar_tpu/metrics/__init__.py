@@ -281,7 +281,7 @@ class SchedulerMetrics:
     admission_state: Gauge  # 0 accept / 1 shed_bulk / 2 reject
     shed_total: Counter  # labeled by launch class
     lane_occupancy: Gauge  # per-device EWMA occupancy, labeled by device
-    lane_launches: Counter  # device launches, labeled by device + mode (single/sharded)
+    lane_launches: Counter  # device launches, labeled by device + mode (single/grouped/sharded)
     lane_wedge_trips: Counter  # per-chip wedge-breaker trips, labeled by device
     mesh_lanes: Gauge  # non-wedged lanes currently serving
 
@@ -1127,7 +1127,7 @@ def create_metrics() -> BeaconMetrics:
         ),
         lane_launches=c.counter(
             "lodestar_sched_lane_launches_total",
-            "Device launches per mesh lane (mode: single or sharded collective)",
+            "Device launches per mesh lane (mode: single, grouped multi-job, or sharded collective)",
             ["device", "mode"],
         ),
         lane_wedge_trips=c.counter(

@@ -52,6 +52,7 @@ __all__ = [
     "COEFF_BITS",
     "SINGLE_LAUNCH_MODES",
     "SingleLaunchInputs",
+    "GroupedLaunchInputs",
     "configure_device_prep",
     "configure_single_launch",
     "consume_prep_info",
@@ -60,6 +61,7 @@ __all__ = [
     "prepare_sets",
     "prepare_sets_device",
     "prepare_single_launch_inputs",
+    "prepare_grouped_launch_inputs",
     "build_device_inputs",
     "device_batch_verify",
     "device_batch_verify_many",
@@ -67,6 +69,7 @@ __all__ = [
     "make_synthetic_sets",
     "verify_signature_sets_device",
     "verify_sets_single_launch",
+    "verify_sets_grouped_launch",
     "verify_prepared",
     "prepare_inputs_for_lane",
     "verify_signature_sets_sharded",
@@ -74,6 +77,7 @@ __all__ = [
     "make_lane_verify_fn",
     "make_lane_verify_prepared_fn",
     "make_lane_verify_single_fn",
+    "make_lane_verify_grouped_fn",
     "make_mesh_sharded_fn",
 ]
 
@@ -319,6 +323,10 @@ def prepare_sets(sets: list[SignatureSet]):
     )
 
 
+def _encodings_have_their_lengths(sets: list[SignatureSet]) -> bool:
+    return all(len(bytes(s.pubkey)) == 48 and len(bytes(s.signature)) == 96 for s in sets)
+
+
 def _parse_host_arrays(sets: list[SignatureSet], size: int):
     """Host byte stage shared by the split and single-launch schedules:
     wrong-length structural check, compressed-flag/limb parsing on
@@ -332,7 +340,7 @@ def _parse_host_arrays(sets: list[SignatureSet], size: int):
     from lodestar_tpu.ops import prep as dp
 
     n = len(sets)
-    if any(len(bytes(s.pubkey)) != 48 or len(bytes(s.signature)) != 96 for s in sets):
+    if not _encodings_have_their_lengths(sets):
         return None
     pk_raw = np.frombuffer(
         b"".join(bytes(s.pubkey) for s in sets), dtype=np.uint8
@@ -403,9 +411,34 @@ def prepare_sets_device(sets: list[SignatureSet], fused: bool = True):
     )
 
 
-def _blind_and_aggregate_body(pk_x, pk_y, sig_x, sig_y, coeff_bits, mask):
+def _slot_major(a, groups: int):
+    """(groups * slot, ...) rows -> (slot, groups, ...): a slot's rows
+    down axis 0 and the slots beside each other. The ops' tree folds
+    reduce axis 0 and carry every other axis as batch, so on this layout
+    they fold all slots at once, each exactly as they fold it alone."""
+    return jnp.swapaxes(a.reshape((groups, -1) + a.shape[1:]), 0, 1)
+
+
+def _fold_sum_slots(F, pts, groups: int):
+    """`cv.fold_sum` per slot: (X, Y, Z) each (groups, ...)."""
+    return cv.fold_sum(F, tuple(_slot_major(c, groups) for c in pts))
+
+
+def _fp12_product_fold_slots(fs, mask, groups: int):
+    """`prg.fp12_product_fold` per slot, masked rows replaced with one:
+    (groups, 2, 3, 2, 33). A slot is a power of two long (the size
+    classes are), so the fold pads nothing."""
+    with jax.named_scope("bls.fold"):
+        ones = tw.fp12_one(fs.shape[:1])
+        fs = _slot_major(jnp.where(mask[:, None, None, None, None], fs, ones), groups)
+    return prg.fp12_product_fold(fs)
+
+
+def _blind_and_aggregate_body(pk_x, pk_y, sig_x, sig_y, coeff_bits, mask, groups: int = 1):
     """Blinded scalar muls (r_i*PK_i in G1, r_i*S_i in G2), the masked G2
-    fold to the aggregate signature, affine conversions."""
+    fold to each slot's aggregate signature, affine conversions. The
+    rows are `groups` slots of equal length, one RLC batch a slot; the
+    aggregates come back with the slots down axis 0."""
     with jax.named_scope("bls.blind"):
         one1 = fp.one_mont()
         one2 = tw.fp2_one()
@@ -415,25 +448,26 @@ def _blind_and_aggregate_body(pk_x, pk_y, sig_x, sig_y, coeff_bits, mask):
         # force their blinded sig to infinity before the fold
         mcol = mask[:, None, None]
         rsig = (rsig[0], rsig[1], jnp.where(mcol, rsig[2], jnp.zeros_like(rsig[2])))
-        s_agg = cv.fold_sum(cv.F2, rsig)
+        s_agg = _fold_sum_slots(cv.F2, rsig, groups)
         rpk_aff = cv.jac_to_affine_batch(cv.F1, rpk)
-        s_aff = cv.jac_to_affine_batch(cv.F2, tuple(c[None] for c in s_agg))
+        s_aff = cv.jac_to_affine_batch(cv.F2, s_agg)
         s_inf = cv.jac_is_inf(cv.F2, s_agg)
     return rpk_aff, s_aff, s_inf
 
 
 def _assemble_pairs(rpk_aff, s_aff, s_inf, h_x, h_y, mask):
-    """Miller batch: N blinded-pubkey/message pairs + the (-g1, S_agg)
-    pair. Padded / infinite entries get the generator pair as a
-    placeholder (any valid non-infinity point works; the mask drops
-    their Miller value)."""
+    """Miller batch: N blinded-pubkey/message pairs, then one
+    (-g1, S_agg) pair a slot. Padded / infinite entries get the
+    generator pair as a placeholder (any valid non-infinity point works;
+    the mask drops their Miller value)."""
     with jax.named_scope("bls.assemble"):
-        p_x = jnp.concatenate([rpk_aff[0], _NEG_G1_X[None].astype(jnp.int32)], axis=0)
-        p_y = jnp.concatenate([rpk_aff[1], _NEG_G1_Y[None].astype(jnp.int32)], axis=0)
+        gen_p = (jnp.asarray(_NEG_G1_X), jnp.asarray(_NEG_G1_Y))
+        agg_p = [jnp.broadcast_to(c, s_inf.shape + c.shape) for c in gen_p]  # -g1, a row a slot
+        p_x = jnp.concatenate([rpk_aff[0], agg_p[0]], axis=0)
+        p_y = jnp.concatenate([rpk_aff[1], agg_p[1]], axis=0)
         q_x = jnp.concatenate([h_x, s_aff[0]], axis=0)
         q_y = jnp.concatenate([h_y, s_aff[1]], axis=0)
-        pair_mask = jnp.concatenate([mask, ~s_inf[None]], axis=0)
-        gen_p = (jnp.asarray(_NEG_G1_X), jnp.asarray(_NEG_G1_Y))
+        pair_mask = jnp.concatenate([mask, ~s_inf], axis=0)
         gen_q_x = jnp.broadcast_to(h_x[0], q_x.shape[1:])
         gen_q_y = jnp.broadcast_to(h_y[0], q_y.shape[1:])
         mm = pair_mask[:, None, None]
@@ -444,9 +478,21 @@ def _assemble_pairs(rpk_aff, s_aff, s_inf, h_x, h_y, mask):
     return p_x, p_y, q_x, q_y, pair_mask
 
 
-def _fold_verdict_body(fs, pair_mask):
-    f = prg.fp12_product_fold(fs, mask=pair_mask)
+def _fold_verdict_body(fs, pair_mask, groups: int = 1):
+    """Each slot's Fp12 product (its rows' Miller values times its
+    aggregate pair's), one final exponentiation batched over the slots,
+    the ==1 predicate: (groups,) bools."""
+    rows = fs.shape[0] - groups
+    f = _fp12_product_fold_slots(fs[:rows], pair_mask[:rows], groups)
+    with jax.named_scope("bls.fold"):
+        agg = jnp.where(pair_mask[rows:, None, None, None, None], fs[rows:], tw.fp12_one((groups,)))
+        f = tw.fp12_mul(f, agg)
     return tw.fp12_eq_one(prg.final_exponentiation(f))
+
+
+def _fold_verdict_one(fs, pair_mask):
+    """`_fold_verdict_body` of one batch: a scalar bool."""
+    return _fold_verdict_body(fs, pair_mask)[0]
 
 
 @jax.jit
@@ -459,22 +505,20 @@ def _device_batch_verify_impl(pk_x, pk_y, h_x, h_y, sig_x, sig_y, coeff_bits, ma
         rpk_aff, s_aff, s_inf, h_x, h_y, mask
     )
     fs = prg.miller_loop((p_x, p_y), (q_x, q_y))
-    return _fold_verdict_body(fs, pair_mask)
+    return _fold_verdict_one(fs, pair_mask)
 
 
 _stage_blind_and_aggregate = jax.jit(_blind_and_aggregate_body)
 _stage_miller = jax.jit(lambda p_x, p_y, q_x, q_y: prg.miller_loop((p_x, p_y), (q_x, q_y)))
-_stage_fold_verdict = jax.jit(_fold_verdict_body)
+_stage_fold_verdict = jax.jit(_fold_verdict_one)
 
 
-@jax.jit
-def _single_launch_verify(
-    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask
+def _single_launch_body(
+    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, groups: int
 ):
-    """THE single-launch program: compressed-point limbs + hash-to-field
-    halves in, scalar verdict out — one resident device program per
-    pow-2 size class (`ops.prep.SINGLE_LAUNCH_BUDGET` dispatches per
-    batch, counted at ops/prep.py's `_dispatch` seam).
+    """The single-launch chain over `groups` slots of equal length, one
+    RLC batch a slot: compressed-point limbs + hash-to-field halves in,
+    a verdict a slot out.
 
     Composed by CALLING the fused schedule's three staged legs
     (ops/prep.py `_prep_field_stage` / `_prep_subgroup_stage` /
@@ -482,14 +526,19 @@ def _single_launch_verify(
     single program and the 3-launch reference share one source of truth
     per leg) plus the RLC/pairing bodies of this module; the G2 ladder
     tables and hot curve constants are closed over as jit constants, so
-    they stay pinned in device memory across batches. Structurally
-    invalid rows (host parse flags in `struct_ok`, on-curve/subgroup
-    flags decided here) fold into the verdict on device: any invalid
-    unmasked row makes the batch False, exactly the fail-fast the split
-    schedule applies before its verify dispatch. Returns
-    (verdict, batch_valid) scalar bools — the second distinguishes a
-    structural reject from an invalid signature for the prep-rejection
-    metric only (both are final False verdicts)."""
+    they stay pinned in device memory across batches. Everything
+    row-wise (the prep legs, the blinding ladders, the Miller loop) runs
+    flat over all rows; what is per batch (the signature aggregate, its
+    (-g1, S_agg) pair, the Fp12 product, the final exponentiation, the
+    structural veto) is per slot, so a slot's verdict is what the
+    program returns for that slot's rows alone. Structurally invalid
+    rows (host parse flags in `struct_ok`, on-curve/subgroup flags
+    decided here) fold into the verdict on device: any invalid unmasked
+    row makes its slot False, exactly the fail-fast the split schedule
+    applies before its verify dispatch. Returns (verdict, batch_valid),
+    (groups,) bools each — the second distinguishes a structural reject
+    from an invalid signature for the prep-rejection metric only (both
+    are final False verdicts)."""
     from lodestar_tpu.ops import prep as dp
 
     # the fused schedule's three legs, one trace: field stage
@@ -512,17 +561,45 @@ def _single_launch_verify(
     # group ops below stay well-defined on them; their garbage pairing
     # values are irrelevant because `batch_valid` vetoes the verdict.
     rpk_aff, s_aff, s_inf = _blind_and_aggregate_body(
-        pk_x, pk_y, sig_x, sig_y, coeff_bits, mask
+        pk_x, pk_y, sig_x, sig_y, coeff_bits, mask, groups
     )
     p_x, p_y, q_x, q_y, pair_mask = _assemble_pairs(
         rpk_aff, s_aff, s_inf, h_x, h_y, mask
     )
     fs = prg.miller_loop((p_x, p_y), (q_x, q_y))
-    rlc_ok = _fold_verdict_body(fs, pair_mask)
+    rlc_ok = _fold_verdict_body(fs, pair_mask, groups)
 
     valid = struct_ok & pk_ok & sig_ok
-    batch_valid = jnp.all(valid | ~mask)
+    batch_valid = jnp.all((valid | ~mask).reshape(groups, -1), axis=1)
     return batch_valid & rlc_ok, batch_valid
+
+
+@jax.jit
+def _single_launch_verify(
+    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask
+):
+    """THE single-launch program: one batch, scalar verdict out — one
+    resident device program per pow-2 size class
+    (`ops.prep.SINGLE_LAUNCH_BUDGET` dispatches per batch, counted at
+    ops/prep.py's `_dispatch` seam). `_single_launch_body` with one
+    slot."""
+    verdict, batch_valid = _single_launch_body(
+        pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, 1
+    )
+    return verdict[0], batch_valid[0]
+
+
+@functools.partial(jax.jit, static_argnames="groups")
+def _grouped_launch_verify(
+    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, *, groups
+):
+    """The multi-job launch: `groups` jobs ride one program, a slot of
+    rows each, and each gets the verdict `_single_launch_verify` gives
+    it alone. One resident program per (rows, groups); the pool forms
+    (256, 2) and (512, 4)."""
+    return _single_launch_body(
+        pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, groups
+    )
 
 
 def _device_batch_verify_staged(pk, h, sig, coeff_bits, mask):
@@ -691,7 +768,7 @@ def _pad_pow2(n: int, floor: int = 8) -> int:
 def _random_coeffs(n: int) -> np.ndarray:
     """[1, r_1, ..., r_{n-1}] nonzero 64-bit blinding scalars."""
     out = np.empty(n, dtype=np.uint64)
-    out[0] = 1
+    out[:1] = 1  # none for an empty slot of a multi-job launch
     for i in range(1, n):
         k = 0
         while k == 0:
@@ -861,29 +938,34 @@ def prepare_single_launch_inputs(sets: list[SignatureSet]):
         )
 
 
+def _dispatch_launch(program, what: str, shape: tuple, *args, **static):
+    """ONE counted dispatch of a single-launch program and the wait for
+    its two outputs, BOTH shape-checked: a miscompile returning a
+    malformed batch_valid must raise here, inside the caller's guarded
+    region, and degrade like any other anomaly, not reach the
+    lane/breaker. Returns (verdict, batch_valid) as numpy bools."""
+    from lodestar_tpu.ops import prep as dp
+
+    with telemetry.phase("bls.dispatch"):  # transfer and enqueue
+        verdict, batch_valid = dp._dispatch(program, *args, **static)
+    with telemetry.phase("bls.wait"):  # blocks on the verdict
+        v = np.asarray(verdict)
+        bvld = np.asarray(batch_valid)
+    for name, arr in (("verdict", v), ("batch_valid", bvld)):
+        if arr.shape != shape or arr.dtype != np.bool_:
+            raise RuntimeError(f"{what} {name} shape anomaly: {arr.shape}/{arr.dtype}")
+    return v, bvld
+
+
 def _verify_single_prepared(si: SingleLaunchInputs) -> bool:
     """Dispatch ONE single-launch program on host-staged inputs. A
     device error or a verdict-shape anomaly degrades the batch to the
     split schedule (counted + warned) — which itself degrades device
     prep to host prep, the full staged-jit miscompile chain."""
-    from lodestar_tpu.ops import prep as dp
-
     try:
-        with telemetry.phase("bls.dispatch"):  # transfer and enqueue
-            verdict, batch_valid = dp._dispatch(
-                _single_launch_verify, *si.arrays, si.bits, si.mask
-            )
-        # BOTH outputs are shape-checked inside the guarded region: a
-        # miscompile returning a malformed batch_valid must degrade
-        # like any other anomaly, not raise into the lane/breaker
-        with telemetry.phase("bls.wait"):  # blocks on the verdict
-            v = np.asarray(verdict)
-            bvld = np.asarray(batch_valid)
-        for name, arr in (("verdict", v), ("batch_valid", bvld)):
-            if arr.shape != () or arr.dtype != np.bool_:
-                raise RuntimeError(
-                    f"single-launch {name} shape anomaly: {arr.shape}/{arr.dtype}"
-                )
+        v, bvld = _dispatch_launch(
+            _single_launch_verify, "single-launch", (), *si.arrays, si.bits, si.mask
+        )
     except Exception as e:  # degrade to the split schedule, never resolve here
         _note_single_launch_fallback(e)
         return _verify_sets_split(si.sets)
@@ -915,16 +997,119 @@ def verify_sets_single_launch(sets: list[SignatureSet]) -> bool:
     return _verify_single_prepared(si)
 
 
-def verify_prepared(inputs) -> bool:
+class GroupedLaunchInputs:
+    """Host-staged inputs for one multi-job launch: the jobs as they
+    came, the parsed arrays of `groups` slots of `slot` rows, blinding
+    bits and mask a slot, and `riding`, the indices of the jobs that got
+    a slot in slot order (a job with a wrong-length encoding is False at
+    parse time and gets none)."""
+
+    __slots__ = ("jobs", "arrays", "bits", "mask", "groups", "riding")
+
+    def __init__(self, jobs, arrays, bits, mask, groups, riding):
+        self.jobs = jobs
+        self.arrays = arrays  # as SingleLaunchInputs.arrays, groups * slot rows
+        self.bits = bits
+        self.mask = mask
+        self.groups = groups
+        self.riding = riding
+
+
+def grouped_launch_groups(n_jobs: int) -> int:
+    """Slots of the launch that carries `n_jobs` jobs: 2, or 4 for three
+    and four (three leave a slot empty, fully masked and ignored)."""
+    return 2 if n_jobs <= 2 else 4
+
+
+def prepare_grouped_launch_inputs(jobs: list[list[SignatureSet]]) -> GroupedLaunchInputs:
+    """Host byte stage of the multi-job launch: each job parsed into its
+    own slot of the launch's rows, with its own blinding and mask — what
+    `prepare_single_launch_inputs` makes of the job alone, side by side.
+    A slot is as long as the largest job's size class; zero device
+    dispatches."""
+    with telemetry.phase("bls.parse"):
+        t0 = time.monotonic_ns()
+        riding = [i for i, job in enumerate(jobs) if job and _encodings_have_their_lengths(job)]
+        n = sum(len(jobs[i]) for i in riding)
+        rejected = len(riding) < len(jobs)
+        if not riding:
+            _note_prep("single_launch", sum(len(j) for j in jobs), t0, rejected=True)
+            return GroupedLaunchInputs(jobs, None, None, None, 0, riding)
+        groups = grouped_launch_groups(len(riding))
+        slot = max(_pad_pow2(len(jobs[i])) for i in riding)
+        # padding rows repeat a real row and are masked by every
+        # consumer, an empty slot's rows too
+        filler = jobs[riding[0]][0]
+        rows, bits, mask = [], [], []
+        for g in range(groups):
+            job = jobs[riding[g]] if g < len(riding) else []
+            rows += list(job) + [job[0] if job else filler] * (slot - len(job))
+            job_bits, job_mask = _blinding_and_mask(len(job), slot)
+            bits.append(job_bits)
+            mask.append(job_mask)
+        pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi = (
+            _parse_host_arrays(rows, groups * slot)
+        )
+        _note_prep("single_launch", n, t0, rejected=rejected)
+        return GroupedLaunchInputs(
+            jobs,
+            (pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi, pk_struct & sig_struct),
+            np.concatenate(bits),
+            np.concatenate(mask),
+            groups,
+            riding,
+        )
+
+
+def _verify_grouped_prepared(gi: GroupedLaunchInputs) -> list[bool]:
+    """Dispatch ONE multi-job program on host-staged inputs: a verdict a
+    job. A device error or a shape anomaly of either output degrades the
+    unit to one `verify_sets_single_launch` a job (counted + warned)."""
+    verdicts = [False] * len(gi.jobs)
+    if not gi.riding:
+        return verdicts
+    try:
+        v, bvld = _dispatch_launch(
+            _grouped_launch_verify, "grouped-launch", (gi.groups,),
+            *gi.arrays, gi.bits, gi.mask, groups=gi.groups,
+        )
+    except Exception as e:  # degrade to one launch a job, never resolve here
+        _note_single_launch_fallback(e)
+        return [verify_sets_single_launch(job) for job in gi.jobs]
+    m = _prep_metrics
+    for g, i in enumerate(gi.riding):
+        verdicts[i] = bool(v[g])
+        if m is not None and not bool(bvld[g]):
+            m.rejected.inc()
+    return verdicts
+
+
+def verify_sets_grouped_launch(jobs: list[list[SignatureSet]]) -> list[bool]:
+    """Up to four jobs, ONE counted device dispatch, a verdict a job —
+    each identical to `verify_sets_single_launch` on that job alone. A
+    host-parse ERROR degrades to that road, a job at a time."""
+    try:
+        gi = prepare_grouped_launch_inputs(jobs)
+    except Exception as e:
+        _note_single_launch_fallback(e)
+        return [verify_sets_single_launch(job) for job in jobs]
+    return _verify_grouped_prepared(gi)
+
+
+def verify_prepared(inputs) -> bool | list[bool]:
     """Verify a batch whose inputs were already staged by the pipeline's
     prep stage (chain/bls/pool.py double-buffers prep of batch k+1
-    against this call on batch k). Two staged shapes: the split
+    against this call on batch k). Three staged shapes: the split
     schedule's `build_device_inputs` tuple (device arrays; blinding
-    sampled at prep time; one RLC verify dispatch here), or a
+    sampled at prep time; one RLC verify dispatch here), a
     `SingleLaunchInputs` (host byte-parse only; the ONE single-launch
     program dispatches here, so the whole device chain of batch k
-    overlaps the host parse of batch k+1). Either way the verdict is
-    identical to `verify_signature_sets_device` on the same sets."""
+    overlaps the host parse of batch k+1), or a `GroupedLaunchInputs`
+    (the same for a multi-job launch: a list of verdicts, one a job).
+    Either way a verdict is identical to `verify_signature_sets_device`
+    on the same sets."""
+    if isinstance(inputs, GroupedLaunchInputs):
+        return _verify_grouped_prepared(inputs)
     if isinstance(inputs, SingleLaunchInputs):
         return _verify_single_prepared(inputs)
     return _verify_split_prepared(inputs)
@@ -1027,6 +1212,19 @@ def make_lane_verify_single_fn(device_index: int):
 
     lane_verify_single.__name__ = f"lane_verify_single_dev{device_index}"
     return lane_verify_single
+
+
+def make_lane_verify_grouped_fn(device_index: int):
+    """Multi-job twin of `make_lane_verify_single_fn`, pinned to one
+    chip: a list of jobs in, one launch, a list of verdicts out."""
+
+    def lane_verify_grouped(jobs: list[list[SignatureSet]]) -> list[bool]:
+        dev = jax.devices()[device_index]
+        with jax.default_device(dev):
+            return verify_sets_grouped_launch(jobs)
+
+    lane_verify_grouped.__name__ = f"lane_verify_grouped_dev{device_index}"
+    return lane_verify_grouped
 
 
 def make_mesh_sharded_fn():

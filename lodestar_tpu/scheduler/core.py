@@ -176,6 +176,12 @@ class PriorityWorkQueue:
             self.metrics.jobs_dequeued.labels(cls.label).inc()
         return item, cls, waited_ns
 
+    def peek(self, cls: PriorityClass):
+        """The item `get_nowait(cls)` would pop, left where it is; None
+        when the class is empty. Touches no accounting."""
+        q = self._queues[PriorityClass(cls)]
+        return q[0][0] if q else None
+
     async def get(self) -> tuple[object, PriorityClass, int]:
         while True:
             out = self.get_nowait()

@@ -187,6 +187,61 @@ class TestSingleLaunchVerdicts:
         assert _all_paths_agree(offsub, oracle=verify_signature_sets(offsub)) is False
 
 
+def _cancelling_pair(sets, a: int, b: int):
+    """Sets `a` and `b` with their signatures shifted by +D and -D: the
+    plain sum of the batch's signatures is unchanged, so only distinct
+    blinding coefficients tell the batch from an honest one."""
+    from lodestar_tpu.crypto.bls import curve as C
+
+    d = C.g2_mul(C.G2_GEN, 0xD15C0)
+    out = list(sets)
+    for i, shift in ((a, d), (b, C.g2_neg(d))):
+        sig = C.g2_add(serdes.g2_from_bytes(sets[i].signature), shift)
+        out[i] = SignatureSet(
+            pubkey=sets[i].pubkey, message=sets[i].message, signature=serdes.g2_to_bytes(sig)
+        )
+    return out
+
+
+@pytest.mark.slow
+class TestGroupedLaunchVerdicts:
+    """The multi-job program at (G = 2, slot = 8) against the oracle:
+    each slot's verdict is its own job's, whatever rides beside it."""
+
+    def test_a_fault_fails_its_own_job_only(self, single_on):
+        r = rng(229)
+        first = bv.make_synthetic_sets(5, seed=173)
+        last = bv.make_synthetic_sets(4, seed=179)
+        cancelling = _cancelling_pair(first, 1, 3)
+        off_key = list(last)
+        off_key[2] = SignatureSet(
+            pubkey=serdes.g1_to_bytes(_g1_offsubgroup_point(r)),
+            message=last[2].message,
+            signature=last[2].signature,
+        )
+        for jobs in (
+            [first, last],
+            [cancelling, last],  # a cancelling pair in slot 0 only
+            [first, off_key],  # an off-subgroup key in slot 1 only
+            [cancelling, off_key],
+        ):
+            want = [verify_signature_sets(job) for job in jobs]
+            base = dp.prep_launches_total()
+            got = bv.verify_sets_grouped_launch(jobs)
+            assert dp.prep_launches_total() - base == dp.SINGLE_LAUNCH_BUDGET
+            assert got == want
+        assert [verify_signature_sets(j) for j in (first, last, cancelling, off_key)] == [
+            True, True, False, False
+        ]
+
+    def test_three_jobs_and_an_empty_slot(self, single_on):
+        """G = 4 at slot 8, the 32-row program: the fourth slot is empty,
+        fully masked, and resolves nothing."""
+        jobs = [bv.make_synthetic_sets(n, seed=181 + n) for n in (3, 2, 4)]
+        jobs[1] = _cancelling_pair(jobs[1], 0, 1)
+        assert bv.verify_sets_grouped_launch(jobs) == [True, False, True]
+
+
 class TestSingleLaunchDegradation:
     def test_device_fault_degrades_to_split_then_host(self, single_on, monkeypatch):
         """Injected single-launch fault → split schedule; with device

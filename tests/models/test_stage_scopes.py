@@ -68,14 +68,16 @@ def test_a_stage_body_traces_under_its_scope(fn, args, want):
 
 def test_assemble_names_its_scope():
     aff1, aff2 = (G1, G1), (shape(1, 2, fp.LIMBS),) * 2
-    got = stage_scopes(bv._assemble_pairs, aff1, aff2, shape(dtype=jnp.bool_), G2, G2, MASK)
+    got = stage_scopes(bv._assemble_pairs, aff1, aff2, shape(1, dtype=jnp.bool_), G2, G2, MASK)  # one slot
     assert got == {"bls.assemble"}
 
 
-def test_the_single_launch_program_carries_all_ten(monkeypatch):
+@pytest.mark.parametrize("groups", [1, 2, 4])
+def test_the_single_launch_program_carries_all_ten(monkeypatch, groups):
     """The three prep legs are scoped where the single launch calls
     them; stand-ins of the legs' shapes keep the trace short (the real
-    legs are the split schedule's programs and are traced under `slow`)."""
+    legs are the split schedule's programs and are traced under `slow`).
+    The multi-job program is the same body over `groups` slots."""
 
     def field_stage(pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi):
         g1 = pk_x_std[:, : fp.LIMBS] + 1
@@ -95,7 +97,11 @@ def test_the_single_launch_program_carries_all_ten(monkeypatch):
     args = (shape(size, fp.LIMBS), shape(size, dtype=jnp.bool_), shape(size, 2, fp.LIMBS),
             shape(size, dtype=jnp.bool_), shape(size, 2, fp.LIMBS), shape(size, 2, fp.LIMBS),
             shape(size, dtype=jnp.bool_), shape(size, bv.COEFF_BITS), shape(size, dtype=jnp.bool_))
-    program = bv._single_launch_verify.__wrapped__  # the body: a jit would cache the stand-ins' trace
+    # the bodies: a jit would cache the stand-ins' trace
+    if groups == 1:
+        program = bv._single_launch_verify.__wrapped__
+    else:
+        program = lambda *a: bv._grouped_launch_verify.__wrapped__(*a, groups=groups)
     assert stage_scopes(program, *args) == set(STAGES)
 
 

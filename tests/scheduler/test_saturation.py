@@ -84,6 +84,55 @@ def test_scheduler_bounds_gossip_block_wait_under_backfill_load():
     asyncio.run(go())
 
 
+def test_gossip_block_waits_out_one_multi_job_bulk_launch():
+    """The same case with 66-set backfill jobs on a lane that takes a
+    package of them in ONE launch: four jobs ride the in-flight launch,
+    and the block is served next, ahead of the queued rest."""
+    from lodestar_tpu.chain.bls.mesh import MeshLane, VerifierMesh
+    from lodestar_tpu.models import batch_verify as bv
+
+    launches: list[list[int]] = []
+
+    def slow_single(sets):
+        launches.append([len(sets)])
+        time.sleep(SLOW_CALL_S)
+        return True
+
+    def slow_grouped(jobs):
+        launches.append([len(j) for j in jobs])
+        time.sleep(SLOW_CALL_S)
+        return [True] * len(jobs)
+
+    done: list[str] = []
+
+    async def submit(pool, name, n_sets, priority):
+        assert await pool.verify_signature_sets(
+            _sets(n_sets, tag=hash(name) % 250), VerifySignatureOpts(priority=priority)
+        )
+        done.append(name)
+
+    async def go():
+        mesh = VerifierMesh([MeshLane(0, slow_single, verify_grouped_fn=slow_grouped)])
+        pool = BlsDeviceVerifierPool(mesh=mesh, scheduler_enabled=True)
+        bulk = [
+            asyncio.ensure_future(submit(pool, f"backfill{i}", 66, PriorityClass.BACKFILL))
+            for i in range(N_BULK)
+        ]
+        await asyncio.sleep(SLOW_CALL_S / 2)
+        block = asyncio.ensure_future(submit(pool, "block", 1, PriorityClass.GOSSIP_BLOCK))
+        await asyncio.gather(*bulk, block)
+        await pool.close()
+
+    prev = bv.configure_single_launch(mode="on")
+    try:
+        asyncio.run(go())
+    finally:
+        bv.configure_single_launch(mode=prev)
+    # one bulk launch in flight when the block arrives, the block's next
+    assert launches == [[66] * 4, [1], [66] * 2], launches
+    assert done.index("block") == 4, done
+
+
 def test_fifo_control_arm_shows_the_inversion():
     async def go():
         pool = BlsDeviceVerifierPool(SlowBackend(), scheduler_enabled=False)

@@ -208,3 +208,20 @@ def test_bulk_classes_cover_sync_paths():
         < PriorityClass.RANGE_SYNC
         < PriorityClass.BACKFILL
     )
+
+
+def test_peek_shows_the_class_head_and_moves_nothing():
+    """`peek(cls)` is the item `get_nowait(cls)` would pop: no pop, no
+    fairness accounting, no metric."""
+    q = PriorityWorkQueue()
+    assert q.peek(PriorityClass.BACKFILL) is None
+    q.put_nowait("b0", PriorityClass.BACKFILL)
+    q.put_nowait("b1", PriorityClass.BACKFILL)
+    q.put_nowait("g0", PriorityClass.GOSSIP_BLOCK)
+    before = q.stats()
+    assert q.peek(PriorityClass.BACKFILL) == "b0"
+    assert q.peek(PriorityClass.GOSSIP_BLOCK) == "g0"
+    assert q.peek(PriorityClass.API) is None
+    assert q.stats() == before and len(q) == 3
+    assert q.get_nowait(PriorityClass.BACKFILL)[0] == "b0"
+    assert q.peek(PriorityClass.BACKFILL) == "b1"
