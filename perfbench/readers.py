@@ -11,7 +11,8 @@ observed (see `run.run_cell`):
                        ends of the window (`pool.<key>`: the pool's own tallies)
     monitor            JAX's compile and persistent-cache events
     trace              `perfbench.trace.Reduced`, or None without a chip
-    trace_span         (start, end) of the traced part of the window, seconds
+    trace_span         (start, end) of the traced part of the window, seconds, on
+                       the harness's clock (the trace's own events are on the profiler's)
     peaks              this device's row of `perfbench/peaks.json`
     workload           the kind's workload object
 
@@ -25,6 +26,8 @@ import statistics
 from perfbench.trace import hlo_io_bytes
 
 FP_KERNELS = ("mul_acc", "sq_acc", "redc", "mont_mul", "mont_sq")
+VERIFY_LAUNCH = "bls_lane_verify"  # the launch's ledger entry, and its host span in a trace
+STAGE_PREFIX = "bls."  # of the `jax.named_scope` names round the verify program's stages
 SHA_PAIR_BYTES = 96  # one pair-hash reads two 32-byte nodes and writes one
 
 
@@ -70,3 +73,48 @@ def fp_kernels_hbm_share(ctx):
         return None
     moved = sum(n * hlo_io_bytes(text) for text, n, _ in calls)
     return 100.0 * moved / ctx["peaks"]["hbm_bytes_per_s"] / seconds
+
+
+def launch_scopes(ctx):
+    """The device time of the verify launches of the traced span by stage
+    scope (`Reduced.scope_seconds`), over those whose host span the trace
+    holds whole, which leaves out the launch the profiler started or
+    stopped in, and whose operations it kept: every launch counted
+    brings all of its operations, or a row that is better lower would
+    read a dropped event as a gain. An operation counts for the launch
+    whose span it starts in. Nothing without a trace, such a launch or a
+    scope."""
+    if ctx["trace"] is None:
+        return None
+    found = ctx["trace"].scope_seconds(VERIFY_LAUNCH, STAGE_PREFIX)
+    return found if found.spans and any(found.seconds) else None
+
+
+def stage_device_ms(ctx, stage: str):
+    """Device ms of a verify launch under one of the program's stage
+    scopes (`bls.miller`); None where no operation carries it. The time
+    in which only a container runs (`while`, `conditional`: 3.3% of a
+    (256, 2) launch, my chip runs, PR 28) is under no stage:
+    `containers_device_ms`."""
+    found = launch_scopes(ctx)
+    return 1000.0 * found.seconds[stage] / found.spans if found and stage in found.seconds else None
+
+
+def containers_device_ms(ctx):
+    """Device ms of a verify launch in which a container runs and no
+    operation inside it: the loops' own time, which no stage row holds.
+    With it the rows, the stages without a row and the unscoped
+    operations add up to the launch's busy time."""
+    found = launch_scopes(ctx)
+    return 1000.0 * found.between_s / found.spans if found and found.between_s else None
+
+
+def stage_scoped_share(ctx):
+    """Share of a verify launch's busy device time in which an operation
+    under a stage scope runs: a refactoring that drops a scope shows
+    here. The containers' own time is busy and under no stage."""
+    found = launch_scopes(ctx)
+    if not found:
+        return None
+    scoped = sum(s for scope, s in found.seconds.items() if scope)
+    return 100.0 * scoped / (sum(found.seconds.values()) + found.between_s)

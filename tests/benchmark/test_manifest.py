@@ -1,5 +1,8 @@
 """BENCHMARK.json against the builder's contract, and every name in it
-resolving to a file of its own."""
+resolving to a file of its own. Each check takes the manifest it reads
+(`MANIFEST_CHECKS`, `check_a_cell_resolves`), so that
+`test_phase_readers.py` can hold a grown copy to the same checks; the
+files are looked for where `perfbench.manifest` says the tree is."""
 
 from __future__ import annotations
 
@@ -17,21 +20,21 @@ M = manifest.load_manifest()
 CELLS = [w["name"] for w in M["workloads"]]
 
 
-def test_top_level_keys_are_exactly_the_contracts():
+def check_top_level_keys_are_exactly_the_contracts(M):
     assert set(M) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
     assert M["command"] == ["python3", "perfbench/run.py"]
     assert 1 <= M["run_seconds"] <= 51 and isinstance(M["run_seconds"], int)
     assert os.path.getsize(manifest.MANIFEST_PATH) <= 64 * 1024
 
 
-def test_paths_hold_the_benchmark_and_the_command_lies_inside():
+def check_paths_hold_the_benchmark_and_the_command_lies_inside(M):
     assert 1 <= len(M["paths"]) <= 16
     for p in M["paths"]:
         assert os.path.isdir(os.path.join(manifest.ROOT, p))
     assert any(M["command"][1].startswith(p + "/") for p in M["paths"])
 
 
-def test_files_under_paths_are_named_from_name_characters():
+def check_files_under_paths_are_named_from_name_characters(M):
     ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
     for p in M["paths"]:
         for folder, dirs, files in os.walk(os.path.join(manifest.ROOT, p)):
@@ -41,15 +44,18 @@ def test_files_under_paths_are_named_from_name_characters():
                 assert ok.match(rel), rel
 
 
-@pytest.mark.parametrize("section", ["configs", "workloads", "end_to_end", "per_layer"])
-def test_names_are_unique_and_well_formed(section):
-    names = [row["name"] for row in M[section]]
-    assert len(set(names)) == len(names)
-    for n in names:
-        assert NAME.match(n), n
+SECTIONS = ["configs", "workloads", "end_to_end", "per_layer"]
 
 
-def test_configs_have_their_files_and_are_all_used():
+def check_names_are_unique_and_well_formed(M, sections=SECTIONS):
+    for section in sections:
+        names = [row["name"] for row in M[section]]
+        assert len(set(names)) == len(names)
+        for n in names:
+            assert NAME.match(n), n
+
+
+def check_configs_have_their_files_and_are_all_used(M):
     used = {w["config"] for w in M["workloads"]}
     files = [c["file"] for c in M["configs"]]
     assert len(set(files)) == len(files)
@@ -65,7 +71,7 @@ def test_configs_have_their_files_and_are_all_used():
             assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
 
 
-def test_workloads_are_one_chip_pairs_that_appear_once():
+def check_workloads_are_one_chip_pairs_that_appear_once(M):
     pairs = [(w["config"], w["traffic"]) for w in M["workloads"]]
     assert len(set(pairs)) == len(pairs)
     four = sum(1 for w in M["workloads"] if w["chips"] == 4)
@@ -77,7 +83,8 @@ def test_workloads_are_one_chip_pairs_that_appear_once():
         assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
 
 
-def test_end_to_end_metrics_have_bounds_and_setup_is_among_them():
+def check_end_to_end_metrics_have_bounds_and_setup_is_among_them(M):
+    cells = [w["name"] for w in M["workloads"]]
     names = [m["name"] for m in M["end_to_end"]]
     assert "setup_s" in names
     for m in M["end_to_end"]:
@@ -86,10 +93,11 @@ def test_end_to_end_metrics_have_bounds_and_setup_is_among_them():
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.25
         for w in m.get("workloads", []):
-            assert w in CELLS
+            assert w in cells
 
 
-def test_per_layer_metrics_name_a_layer_and_an_end_to_end_metric():
+def check_per_layer_metrics_name_a_layer_and_an_end_to_end_metric(M):
+    cells = [w["name"] for w in M["workloads"]]
     e2e = {m["name"]: m for m in M["end_to_end"]}
     for m in M["per_layer"]:
         assert set(m) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
@@ -99,13 +107,12 @@ def test_per_layer_metrics_name_a_layer_and_an_end_to_end_metric():
         assert 1 <= len(m["layer"]) <= 200 and "\n" not in m["layer"]
         moved = e2e[m["moves"]]
         for w in m["workloads"]:
-            assert w in CELLS
+            assert w in cells
             assert "workloads" not in moved or w in moved["workloads"], (m["name"], w)
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_every_name_of_a_cell_resolves_to_a_file_of_its_own(name):
-    cell = manifest.load_cell(name)
+def check_a_cell_resolves(M, name):
+    cell = manifest.load_cell(name, M)
     generator.validate(cell.traffic)
     assert {"warm_calls", "trace_seconds", "correct"} <= set(cell.spec)
     kind = cell.kind()
@@ -118,6 +125,56 @@ def test_every_name_of_a_cell_resolves_to_a_file_of_its_own(name):
     # every cell reports setup_s, one other end-to-end metric and a per-layer metric
     assert len(cell.end_to_end) >= 2 and cell.per_layer
     assert "setup_s" in [m["name"] for m in cell.end_to_end]
+
+
+MANIFEST_CHECKS = [
+    check_top_level_keys_are_exactly_the_contracts,
+    check_paths_hold_the_benchmark_and_the_command_lies_inside,
+    check_files_under_paths_are_named_from_name_characters,
+    check_names_are_unique_and_well_formed,
+    check_configs_have_their_files_and_are_all_used,
+    check_workloads_are_one_chip_pairs_that_appear_once,
+    check_end_to_end_metrics_have_bounds_and_setup_is_among_them,
+    check_per_layer_metrics_name_a_layer_and_an_end_to_end_metric,
+]
+
+
+def test_top_level_keys_are_exactly_the_contracts():
+    check_top_level_keys_are_exactly_the_contracts(M)
+
+
+def test_paths_hold_the_benchmark_and_the_command_lies_inside():
+    check_paths_hold_the_benchmark_and_the_command_lies_inside(M)
+
+
+def test_files_under_paths_are_named_from_name_characters():
+    check_files_under_paths_are_named_from_name_characters(M)
+
+
+@pytest.mark.parametrize("section", SECTIONS)
+def test_names_are_unique_and_well_formed(section):
+    check_names_are_unique_and_well_formed(M, [section])
+
+
+def test_configs_have_their_files_and_are_all_used():
+    check_configs_have_their_files_and_are_all_used(M)
+
+
+def test_workloads_are_one_chip_pairs_that_appear_once():
+    check_workloads_are_one_chip_pairs_that_appear_once(M)
+
+
+def test_end_to_end_metrics_have_bounds_and_setup_is_among_them():
+    check_end_to_end_metrics_have_bounds_and_setup_is_among_them(M)
+
+
+def test_per_layer_metrics_name_a_layer_and_an_end_to_end_metric():
+    check_per_layer_metrics_name_a_layer_and_an_end_to_end_metric(M)
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_every_name_of_a_cell_resolves_to_a_file_of_its_own(name):
+    check_a_cell_resolves(M, name)
 
 
 def test_an_unknown_workload_or_metric_is_an_error():

@@ -1,14 +1,22 @@
 """The per-layer metrics that read the program's own names: each reader
 on a context made by hand (a value, and nothing where the program
-records no phases or steps), the manifest's rows for them, and the
-trace reduction putting an idle gap under a program span on the
+records no phases or steps), the manifest's rows for them, which a
+manifest may grow around (a row appended, a cell listed under them), and
+the trace reduction putting an idle gap under a program span on the
 profiler's clock instead of the harness's outer one."""
 
 from __future__ import annotations
 
+import copy
+import json
+import os
+import shutil
+
 import pytest
 
 from perfbench import generator, manifest, phase_readers, trace
+
+from . import test_manifest, test_stage_readers
 
 M = manifest.load_manifest()
 NEW = {
@@ -40,14 +48,71 @@ def flush_record(detail: dict, error=None):
     return rec
 
 
+def check_the_manifest_lists_the_metric(m: dict, name: str) -> None:
+    """The row is there once, as PR 26 added it, its cells among the
+    row's, and its layer is one that another row names. Where it stands
+    among the rows, and which cells joined it since, is free."""
+    unit, source, layer, moves, cells = NEW[name]
+    (row,) = [r for r in m["per_layer"] if r["name"] == name]
+    assert {k: v for k, v in row.items() if k != "workloads"} == {
+        "name": name, "unit": unit, "better": "lower", "source": source, "layer": layer, "moves": moves}
+    assert set(cells) <= set(row["workloads"])
+    assert layer in {r["layer"] for r in m["per_layer"] if r["name"] not in NEW}
+
+
 @pytest.mark.parametrize("name", sorted(NEW))
 def test_the_manifest_lists_the_metric_after_the_accepted_ones(name):
-    unit, source, layer, moves, cells = NEW[name]
-    rows = [m for m in M["per_layer"] if m["name"] == name]
-    assert rows == [{"name": name, "unit": unit, "better": "lower", "source": source, "layer": layer,
-                     "moves": moves, "workloads": cells}]
-    assert [m["name"] for m in M["per_layer"]][-len(NEW):] == list(NEW)
-    assert layer in {m["layer"] for m in M["per_layer"][: -len(NEW)]}  # a layer the benchmark already names
+    check_the_manifest_lists_the_metric(M, name)
+
+
+def grow_a_tree(tmp_path) -> None:
+    """A copy of the benchmark in which a later PR has added a cell and a
+    per-layer metric: files and manifest entries only, every file that
+    was there left as it was."""
+    shutil.copytree(manifest.BENCH_DIR, tmp_path / "perfbench",
+                    ignore=shutil.ignore_patterns("__pycache__", "data", "reference"))
+    os.makedirs(tmp_path / "tests" / "benchmark")
+    bench = tmp_path / "perfbench"
+    shutil.copy(bench / "traffic" / "range-sync-segments.json", bench / "traffic" / "later-traffic.json")
+    shutil.copy(bench / "cells" / "node-range-sync.json", bench / "cells" / "later-cell.json")
+    (bench / "metrics" / "later_metric.py").write_text("def read(ctx):\n    return None\n")
+    grown = copy.deepcopy(M)
+    grown["workloads"].append({"name": "later-cell", "config": "mainnet-solo-node", "traffic": "later-traffic",
+                               "chips": 1, "why": "a cell that a later PR adds"})
+    grown["per_layer"].append({"name": "later_metric", "unit": "ms", "better": "lower",
+                               "source": "program_span", "layer": "pool", "moves": "sigs_per_s",
+                               "workloads": ["later-cell"]})
+    for section, name in (("end_to_end", "sigs_per_s"), ("per_layer", "first_call_s"),
+                          ("per_layer", "launch_host_ms.bulk"),
+                          ("end_to_end", "verdict_p50_ms"), ("per_layer", "jobs_per_launch"),
+                          ("per_layer", "stage_device_ms.miller")):  # rows of PR 28, and what they move
+        (row,) = [r for r in grown[section] if r["name"] == name]
+        row["workloads"].append("later-cell")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(grown, indent=1) + "\n")
+
+
+def test_a_manifest_grown_by_a_row_and_a_cell_still_passes_every_check(tmp_path, monkeypatch):
+    """What `perfbench/README.md` promises a later PR: it appends a row,
+    adds a cell and lists the cell under the metrics it reports, and no
+    test pins the table's tail or a row's cells."""
+    grow_a_tree(tmp_path)
+    monkeypatch.setattr(manifest, "ROOT", str(tmp_path))
+    monkeypatch.setattr(manifest, "BENCH_DIR", str(tmp_path / "perfbench"))
+    monkeypatch.setattr(manifest, "MANIFEST_PATH", str(tmp_path / "BENCHMARK.json"))
+    grown = manifest.load_manifest(manifest.MANIFEST_PATH)
+    assert [r["name"] for r in grown["per_layer"]][-1] == "later_metric"
+    for check in test_manifest.MANIFEST_CHECKS:
+        check(grown)
+    for w in grown["workloads"]:
+        test_manifest.check_a_cell_resolves(grown, w["name"])
+    later = manifest.load_cell("later-cell", grown)
+    assert {m["name"] for m in later.end_to_end} == {"sigs_per_s", "verdict_p50_ms", "setup_s"}
+    assert {m["name"] for m in later.per_layer} == {"first_call_s", "launch_host_ms.bulk", "jobs_per_launch",
+                                                    "stage_device_ms.miller", "later_metric"}
+    for name in sorted(NEW):
+        check_the_manifest_lists_the_metric(grown, name)
+    for name in sorted(test_stage_readers.ROWS):
+        test_stage_readers.check_the_manifest_lists_the_row(grown, name)
 
 
 @pytest.mark.parametrize("name", ["launch_host_ms.bulk", "launch_host_ms.block"])
