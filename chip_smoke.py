@@ -11,9 +11,13 @@ CPU reference (the pure-Python BLS oracle, hashlib):
    launch, and the staged three-jit schedule over fused device prep)
    must agree with the oracle on a valid, a tampered and a structurally
    invalid batch at both size classes the run dispatches (128, 512);
-3. the offload server as `offload.server.main()` builds its backend,
-   behind real gRPC on localhost, answers eight concurrent 128-set jobs,
-   one tampered job and one malformed job through `BlsOffloadClient`;
+3. the offload host as `offload.server.main()` builds it
+   (`offload.server.boot_host`: the verifier pool behind the wire, the
+   port bound only after the flat 128-row program and the multi-job
+   programs at (256, 2) and (512, 4) have each answered a valid and a
+   tampered known batch), behind real gRPC on localhost, answers eight
+   concurrent 128-set jobs, one tampered job and one malformed job
+   through `BlsOffloadClient`;
 4. the node's own `BlsDeviceVerifierPool` takes 1,024 sets at
    gossip-attestation priority, so packages reach the 512-set cap;
    the multi-job launch at (2 slots, 128 rows) gives each job the
@@ -214,18 +218,17 @@ def phase_programs(batches: dict, oracle) -> dict:
     return out
 
 
-async def phase_served(backend, batches: dict, oracle) -> dict:
-    """The offload host's default backend behind real gRPC."""
+async def phase_served(host, batches: dict, oracle) -> dict:
+    """The offload host as the command builds it, behind real gRPC."""
     from lodestar_tpu.offload.client import BlsOffloadClient
-    from lodestar_tpu.offload.server import BlsOffloadServer
 
+    backend = host.backend
     check(backend.description["verifier"] == "device",
           f"offload server resolved {backend.description}")
-    server = BlsOffloadServer(
-        backend.verify, port=0, chip_status_fn=backend.chip_status_fn
-    )
-    server.start()
-    client = BlsOffloadClient(f"127.0.0.1:{server.port}")
+    check(host.pool is not None, "the offload host serves no verifier pool")
+    check([(w["rows"], w["jobs"]) for w in host.warmed] == [(128, 1), (256, 2), (512, 4)],
+          f"warm start answered {host.warmed}")
+    client = BlsOffloadClient(f"127.0.0.1:{host.port}")
     size = SIZE_CLASSES[0]
     jobs = [("valid", batches[size]["valid"])] * 8 + [
         ("tampered", batches[size]["tampered"]),
@@ -235,13 +238,12 @@ async def phase_served(backend, batches: dict, oracle) -> dict:
         got = await asyncio.gather(*(client.verify_signature_sets(s) for _, s in jobs))
     finally:
         await client.close()
-        server.stop()
     for (kind, _), verdict in zip(jobs, got):
         want = oracle()["malformed" if kind == "malformed" else (size, kind)]
         check(verdict == want, f"served {kind} job: {verdict}, oracle {want}")
     log(f"served path: {len(jobs)} jobs, verdicts {[bool(v) for v in got]}")
     return {"description": backend.description, "verdicts": [bool(v) for v in got],
-            "lanes": backend.mesh.lane_states()}
+            "warmed": host.warmed, "lanes": backend.mesh.lane_states()}
 
 
 async def phase_node_pool(node, batches: dict, oracle) -> dict:
@@ -396,13 +398,16 @@ def ledger_summary() -> dict:
     return out
 
 
-def counter_totals(registry) -> dict:
-    """Each watched counter summed over its label sets."""
+def counter_totals(*registries) -> dict:
+    """Each watched counter summed over its label sets and the registries
+    (the node's, and the offload host's: the process-global device seams
+    report to whichever of the two was booted last)."""
     totals = dict.fromkeys(ZERO_COUNTERS, 0.0)
-    for family in registry.collect():
-        for sample in family.samples:
-            if sample.name in totals:
-                totals[sample.name] += sample.value
+    for registry in registries:
+        for family in registry.collect():
+            for sample in family.samples:
+                if sample.name in totals:
+                    totals[sample.name] += sample.value
     return totals
 
 
@@ -412,7 +417,7 @@ def counter_totals(registry) -> dict:
 async def run(seed: int, device: dict, compile_stats: dict) -> dict:
     from lodestar_tpu import native, telemetry
     from lodestar_tpu.native import bls as native_bls
-    from lodestar_tpu.offload.server import build_backend
+    from lodestar_tpu.offload.server import boot_host
     from lodestar_tpu.ops import fp_pallas
 
     check("LODESTAR_FP_PALLAS" not in os.environ, "LODESTAR_FP_PALLAS is set")
@@ -441,16 +446,20 @@ async def run(seed: int, device: dict, compile_stats: dict) -> dict:
         with concurrent.futures.ThreadPoolExecutor(1) as ex:
             oracle = ex.submit(oracle_verdicts, batches).result
             report["programs"] = phase_programs(batches, oracle)
-            backend = build_backend()  # default flags, as server.main() does
-            report["served"] = await phase_served(backend, batches, oracle)
+            # default flags, as server.main() does; a port of the system's choosing
+            host = await asyncio.get_event_loop().run_in_executor(None, lambda: boot_host(port=0))
+            try:
+                report["served"] = await phase_served(host, batches, oracle)
+            finally:
+                host.stop()
             report["node_pool"] = await phase_node_pool(node, batches, oracle)
             report["grouped"] = await phase_grouped(node, batches, oracle)
         report["oracle"] = {str(k): v for k, v in oracle().items()}
         report["state_root"] = phase_state_root(seed)
         if device["count"] > 1:
-            report["lanes"] = phase_lanes(backend.mesh, node.bls.mesh, batches)
+            report["lanes"] = phase_lanes(host.backend.mesh, node.bls.mesh, batches)
 
-        report["counters"] = counter_totals(node.metrics.creator.registry)
+        report["counters"] = counter_totals(node.metrics.creator.registry, host.creator.registry)
         for name, value in report["counters"].items():
             check(value == 0, f"{name} = {value}")
         for lanes in (report["served"]["lanes"], report["node_pool"]["lanes"]):

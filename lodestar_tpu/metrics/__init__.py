@@ -38,6 +38,10 @@ __all__ = [
     "AuditMetrics",
     "TenantMetrics",
     "create_tenant_metrics",
+    "create_bls_prep_metrics",
+    "create_bls_pipeline_metrics",
+    "create_device_launch_metrics",
+    "create_sched_metrics",
     "create_metrics",
     "MetricsServer",
     "ValidatorMonitor",
@@ -534,6 +538,151 @@ _SEC_SLACK = (
 )
 
 
+def create_bls_prep_metrics(c: "RegistryMetricCreator") -> BlsPrepMetrics:
+    """`lodestar_bls_prep_*` on `creator`'s registry (a node's, or an offload host's)."""
+    return BlsPrepMetrics(
+        sets=c.counter(
+            "lodestar_bls_prep_sets_total",
+            "Signature sets prepared (decompress + subgroup + hash-to-G2), by layer",
+            ["layer"],
+        ),
+        seconds=c.histogram(
+            "lodestar_bls_prep_seconds",
+            "Input-prep wall time per batch, by layer (device/host)",
+            _SEC_SMALL,
+            ["layer"],
+        ),
+        fallbacks=c.counter(
+            "lodestar_bls_prep_fallback_total",
+            "Device input-prep errors degraded to the host prep path",
+        ),
+        single_launch_fallbacks=c.counter(
+            "lodestar_bls_single_launch_fallback_total",
+            "Single-launch verify errors (device fault or verdict-shape "
+            "anomaly) degraded to the split prep-then-verify schedule",
+        ),
+        rejected=c.counter(
+            "lodestar_bls_prep_rejected_total",
+            "Prep calls that rejected a structurally invalid batch",
+        ),
+        launches=c.counter(
+            "lodestar_bls_prep_launches_total",
+            "Device program dispatches at the ops/prep.py launch seam "
+            "(plain dispatch counter: fused-stage, per-leg, hash-to-G2 "
+            "AND single-launch verify dispatches all count — per-schedule "
+            "rates come from lodestar_device_launch_seconds{program}; the "
+            "per-batch budget invariant is asserted in tests against the "
+            "same seam)",
+        ),
+    )
+
+
+def create_bls_pipeline_metrics(c: "RegistryMetricCreator") -> BlsPipelineMetrics:
+    """`lodestar_bls_pipeline_*` on `creator`'s registry."""
+    return BlsPipelineMetrics(
+        overlap_occupancy_pct=c.gauge(
+            "lodestar_bls_pipeline_overlap_occupancy_pct",
+            "Percent of verify-stage busy time with a prep stage in flight "
+            "(the pool's pipeline_stats overlap accounting, scrape-time)",
+        ),
+        staged_packages=c.gauge(
+            "lodestar_bls_pipeline_staged_packages",
+            "Packages staged through the prep→verify double buffer "
+            "(cumulative; 0 = the pipeline never engaged)",
+        ),
+        prep_seconds=c.gauge(
+            "lodestar_bls_pipeline_prep_seconds_total",
+            "Cumulative wall seconds some prep stage was in flight",
+        ),
+        verify_seconds=c.gauge(
+            "lodestar_bls_pipeline_verify_seconds_total",
+            "Cumulative wall seconds some verify stage was in flight",
+        ),
+    )
+
+
+def create_device_launch_metrics(c: "RegistryMetricCreator") -> DeviceLaunchMetrics:
+    """`lodestar_device_launch_*` / `lodestar_device_compile_*` on `creator`'s registry."""
+    return DeviceLaunchMetrics(
+        launch_seconds=c.histogram(
+            "lodestar_device_launch_seconds",
+            "Device dispatch wall time at the counted launch seams, by "
+            "program and pow-2 size class (host-observed: includes device "
+            "execution on synchronous backends and trace+compile on the "
+            "first call per class)",
+            _SEC_LAUNCH,
+            ["program", "size_class"],
+        ),
+        compile_seconds=c.counter(
+            "lodestar_device_compile_seconds_total",
+            "Wall seconds spent in top-level first-call-per-(program,size_class) "
+            "dispatches — the trace+compile (or persistent-cache load) tax",
+        ),
+        compile_hits=c.counter(
+            "lodestar_device_compile_hits_total",
+            "Dispatches whose (program, size_class) executable was already "
+            "compiled in this process",
+            ["program"],
+        ),
+        compile_misses=c.counter(
+            "lodestar_device_compile_misses_total",
+            "First-call dispatches per (program, size_class) — each paid "
+            "trace+compile or a persistent-cache load",
+            ["program"],
+        ),
+    )
+
+
+def create_sched_metrics(c: "RegistryMetricCreator") -> SchedulerMetrics:
+    """`lodestar_sched_*` on `creator`'s registry."""
+    return SchedulerMetrics(
+        queue_depth=c.gauge(
+            "lodestar_sched_queue_depth", "Device scheduler queue depth", ["class"]
+        ),
+        queue_wait=c.histogram(
+            "lodestar_sched_queue_wait_seconds",
+            "Launch-queue wait (enqueue to dequeue) by class",
+            _SEC_SMALL,
+            ["class"],
+        ),
+        jobs_dequeued=c.counter(
+            "lodestar_sched_jobs_dequeued_total", "Jobs dequeued for launch", ["class"]
+        ),
+        starvation_promotions=c.counter(
+            "lodestar_sched_starvation_promotions_total",
+            "Jobs served by aging ahead of the fair order",
+        ),
+        occupancy_permille=c.gauge(
+            "lodestar_sched_occupancy_permille", "EWMA device busy-ns per wall-ns (0-1000)"
+        ),
+        admission_state=c.gauge(
+            "lodestar_sched_admission_state", "0 accept / 1 shed bulk / 2 reject"
+        ),
+        shed_total=c.counter(
+            "lodestar_sched_shed_total", "Work deferred by backpressure/admission", ["class"]
+        ),
+        lane_occupancy=c.gauge(
+            "lodestar_sched_lane_occupancy_permille",
+            "Per-chip EWMA busy-ns per wall-ns (0-1000)",
+            ["device"],
+        ),
+        lane_launches=c.counter(
+            "lodestar_sched_lane_launches_total",
+            "Device launches per mesh lane (mode: single, grouped multi-job, or sharded collective)",
+            ["device", "mode"],
+        ),
+        lane_wedge_trips=c.counter(
+            "lodestar_sched_lane_wedge_trips_total",
+            "Per-chip wedge-breaker trips (lane degraded out of the mesh)",
+            ["device"],
+        ),
+        mesh_lanes=c.gauge(
+            "lodestar_sched_mesh_lanes_available",
+            "Mesh lanes currently serving (non-wedged)",
+        ),
+    )
+
+
 def create_metrics() -> BeaconMetrics:
     """Reference `createMetrics` (`metrics/metrics.ts:14`)."""
     c = RegistryMetricCreator()
@@ -570,89 +719,9 @@ def create_metrics() -> BeaconMetrics:
             "lodestar_bls_thread_pool_latency_from_worker", "Result latency", _SEC_TINY,
         ),
     )
-    bls_prep = BlsPrepMetrics(
-        sets=c.counter(
-            "lodestar_bls_prep_sets_total",
-            "Signature sets prepared (decompress + subgroup + hash-to-G2), by layer",
-            ["layer"],
-        ),
-        seconds=c.histogram(
-            "lodestar_bls_prep_seconds",
-            "Input-prep wall time per batch, by layer (device/host)",
-            _SEC_SMALL,
-            ["layer"],
-        ),
-        fallbacks=c.counter(
-            "lodestar_bls_prep_fallback_total",
-            "Device input-prep errors degraded to the host prep path",
-        ),
-        single_launch_fallbacks=c.counter(
-            "lodestar_bls_single_launch_fallback_total",
-            "Single-launch verify errors (device fault or verdict-shape "
-            "anomaly) degraded to the split prep-then-verify schedule",
-        ),
-        rejected=c.counter(
-            "lodestar_bls_prep_rejected_total",
-            "Prep calls that rejected a structurally invalid batch",
-        ),
-        launches=c.counter(
-            "lodestar_bls_prep_launches_total",
-            "Device program dispatches at the ops/prep.py launch seam "
-            "(plain dispatch counter: fused-stage, per-leg, hash-to-G2 "
-            "AND single-launch verify dispatches all count — per-schedule "
-            "rates come from lodestar_device_launch_seconds{program}; the "
-            "per-batch budget invariant is asserted in tests against the "
-            "same seam)",
-        ),
-    )
-    bls_pipeline = BlsPipelineMetrics(
-        overlap_occupancy_pct=c.gauge(
-            "lodestar_bls_pipeline_overlap_occupancy_pct",
-            "Percent of verify-stage busy time with a prep stage in flight "
-            "(the pool's pipeline_stats overlap accounting, scrape-time)",
-        ),
-        staged_packages=c.gauge(
-            "lodestar_bls_pipeline_staged_packages",
-            "Packages staged through the prep→verify double buffer "
-            "(cumulative; 0 = the pipeline never engaged)",
-        ),
-        prep_seconds=c.gauge(
-            "lodestar_bls_pipeline_prep_seconds_total",
-            "Cumulative wall seconds some prep stage was in flight",
-        ),
-        verify_seconds=c.gauge(
-            "lodestar_bls_pipeline_verify_seconds_total",
-            "Cumulative wall seconds some verify stage was in flight",
-        ),
-    )
-    device_launch = DeviceLaunchMetrics(
-        launch_seconds=c.histogram(
-            "lodestar_device_launch_seconds",
-            "Device dispatch wall time at the counted launch seams, by "
-            "program and pow-2 size class (host-observed: includes device "
-            "execution on synchronous backends and trace+compile on the "
-            "first call per class)",
-            _SEC_LAUNCH,
-            ["program", "size_class"],
-        ),
-        compile_seconds=c.counter(
-            "lodestar_device_compile_seconds_total",
-            "Wall seconds spent in top-level first-call-per-(program,size_class) "
-            "dispatches — the trace+compile (or persistent-cache load) tax",
-        ),
-        compile_hits=c.counter(
-            "lodestar_device_compile_hits_total",
-            "Dispatches whose (program, size_class) executable was already "
-            "compiled in this process",
-            ["program"],
-        ),
-        compile_misses=c.counter(
-            "lodestar_device_compile_misses_total",
-            "First-call dispatches per (program, size_class) — each paid "
-            "trace+compile or a persistent-cache load",
-            ["program"],
-        ),
-    )
+    bls_prep = create_bls_prep_metrics(c)
+    bls_pipeline = create_bls_pipeline_metrics(c)
+    device_launch = create_device_launch_metrics(c)
     ssz_htr = SszHtrMetrics(
         flushes=c.counter(
             "lodestar_ssz_htr_flushes_total",
@@ -1094,52 +1163,7 @@ def create_metrics() -> BeaconMetrics:
             ["class"],
         ),
     )
-    sched = SchedulerMetrics(
-        queue_depth=c.gauge(
-            "lodestar_sched_queue_depth", "Device scheduler queue depth", ["class"]
-        ),
-        queue_wait=c.histogram(
-            "lodestar_sched_queue_wait_seconds",
-            "Launch-queue wait (enqueue to dequeue) by class",
-            _SEC_SMALL,
-            ["class"],
-        ),
-        jobs_dequeued=c.counter(
-            "lodestar_sched_jobs_dequeued_total", "Jobs dequeued for launch", ["class"]
-        ),
-        starvation_promotions=c.counter(
-            "lodestar_sched_starvation_promotions_total",
-            "Jobs served by aging ahead of the fair order",
-        ),
-        occupancy_permille=c.gauge(
-            "lodestar_sched_occupancy_permille", "EWMA device busy-ns per wall-ns (0-1000)"
-        ),
-        admission_state=c.gauge(
-            "lodestar_sched_admission_state", "0 accept / 1 shed bulk / 2 reject"
-        ),
-        shed_total=c.counter(
-            "lodestar_sched_shed_total", "Work deferred by backpressure/admission", ["class"]
-        ),
-        lane_occupancy=c.gauge(
-            "lodestar_sched_lane_occupancy_permille",
-            "Per-chip EWMA busy-ns per wall-ns (0-1000)",
-            ["device"],
-        ),
-        lane_launches=c.counter(
-            "lodestar_sched_lane_launches_total",
-            "Device launches per mesh lane (mode: single, grouped multi-job, or sharded collective)",
-            ["device", "mode"],
-        ),
-        lane_wedge_trips=c.counter(
-            "lodestar_sched_lane_wedge_trips_total",
-            "Per-chip wedge-breaker trips (lane degraded out of the mesh)",
-            ["device"],
-        ),
-        mesh_lanes=c.gauge(
-            "lodestar_sched_mesh_lanes_available",
-            "Mesh lanes currently serving (non-wedged)",
-        ),
-    )
+    sched = create_sched_metrics(c)
     return BeaconMetrics(
         creator=c,
         bls_pool=bls,

@@ -263,6 +263,101 @@ def _device_pool(opts: BeaconNodeOptions, metrics: BeaconMetrics):
     )
 
 
+def _offload_verifier(opts: BeaconNodeOptions, metrics: BeaconMetrics) -> IBlsVerifier:
+    """The verifier of a node with `--bls-offload` endpoints, as its
+    options shape it: the breaker-guarded client with its Byzantine
+    auditor and persisted quarantines, then the verified degradation
+    chain (every layer re-verifies; errors degrade, verdicts are
+    final)."""
+    from lodestar_tpu.offload.client import BlsOffloadClient
+
+    # Byzantine audit: seeded sampler + background
+    # re-verification. Forensics + quarantine persistence:
+    # prefer the tracing export dir (next to the slow-slot
+    # dumps), else a subdirectory of the data dir — only a
+    # fully in-memory node runs without persistence
+    audit_dir = opts.tracing_export_dir
+    if audit_dir is None and opts.db_path:
+        import os as _os
+
+        # db_path is the WAL *file* (cli passes <dir>/wal.log):
+        # persist beside it, inside the data directory
+        audit_dir = _os.path.join(
+            _os.path.dirname(_os.path.abspath(opts.db_path)), "offload-audit"
+        )
+    from lodestar_tpu.offload.audit import AuditSampler, OffloadAuditor
+
+    # ALWAYS constructed: with --offload-audit-rate 0 it is
+    # passive (no sampling thread) but still owns quarantine
+    # persistence, gauges and rehabilitation — a standing
+    # Byzantine verdict keeps its lifecycle regardless of the
+    # sampling knob
+    auditor = OffloadAuditor(
+        sampler=AuditSampler(
+            opts.offload_audit_rate, seed=opts.offload_audit_seed
+        ),
+        budget=opts.offload_audit_budget,
+        dump_dir=audit_dir,
+        quarantine_cooloff_s=opts.offload_quarantine_cooloff_s or None,
+        metrics=metrics.audit,
+        start=opts.offload_audit_rate > 0,
+    )
+    client = BlsOffloadClient(
+        opts.offload_endpoints,
+        breaker_threshold=opts.offload_breaker_threshold,
+        breaker_reset_s=opts.offload_breaker_reset_s,
+        hedge_delay_ms=opts.offload_hedge_delay_ms,
+        metrics=metrics.resilience,
+        auditor=auditor,
+        quarantine_cooloff_s=opts.offload_quarantine_cooloff_s or None,
+        tenant=opts.offload_tenant,
+    )
+    if opts.offload_audit_via == "helper" and len(opts.offload_endpoints) > 1:
+        from lodestar_tpu.offload.audit import cross_helper_reference
+
+        auditor.set_reference(cross_helper_reference(client))
+    # operator lifts first, then re-apply persisted Byzantine
+    # quarantines — a restart must not silently re-trust a caught
+    # liar, and that holds even at --offload-audit-rate 0 (the
+    # passive auditor still reads/writes the quarantine file)
+    persisted_before = set(auditor.load_quarantined())
+    for target in opts.offload_unquarantine:
+        if target not in opts.offload_endpoints and target not in persisted_before:
+            # a typo'd lift silently no-opping would leave the
+            # operator believing the quarantine was cleared
+            client.log.warn(
+                "--offload-unquarantine target matches no configured "
+                "endpoint and no persisted quarantine record",
+                {"target": target},
+            )
+            continue
+        # clears breaker state AND (via the bound auditor) the
+        # persisted record — the lift logic lives in one place
+        client.unquarantine_endpoint(target)
+    import time as _time
+
+    from lodestar_tpu.offload.audit import remaining_cooloff
+
+    cool = opts.offload_quarantine_cooloff_s or None
+    now = _time.time()
+    for target, rec in auditor.load_quarantined().items():
+        if target in opts.offload_endpoints:
+            client.quarantine_endpoint(
+                target,
+                cooloff_s=remaining_cooloff(rec, cool, now),
+                reason="persisted_byzantine",
+            )
+    if opts.offload_fallback == "none":
+        return client
+    from lodestar_tpu.chain.bls import DegradingBlsVerifier
+
+    layers: list = [("offload", client)]
+    if opts.offload_fallback == "device":
+        layers.append(("device_pool", _device_pool(opts, metrics)))
+    layers.append(("cpu", BlsSingleThreadVerifier()))
+    return DegradingBlsVerifier(layers, metrics=metrics.resilience)
+
+
 def configure_device_runtime(opts: BeaconNodeOptions, metrics: BeaconMetrics) -> dict:
     """Observe the backend once and configure the process-global device
     seams from it (they live in the model/ssz/ops layers, below any
@@ -422,99 +517,11 @@ class BeaconNode:
             # the slow-slot dump hook makes a slow slot name its launches
             _tracing.configure(launches_supplier=_telemetry.slow_slot_launches)
 
-        # 3. bls verifier — offload endpoints get the resilience stack:
-        # breaker-guarded client, then the verified degradation chain
-        # (every layer re-verifies; errors degrade, verdicts are final)
+        # 3. bls verifier — offload endpoints get the resilience stack
+        # (`_offload_verifier`)
         bls: IBlsVerifier
         if opts.offload_endpoints:
-            from lodestar_tpu.offload.client import BlsOffloadClient
-
-            # 3a. Byzantine audit: seeded sampler + background
-            # re-verification. Forensics + quarantine persistence:
-            # prefer the tracing export dir (next to the slow-slot
-            # dumps), else a subdirectory of the data dir — only a
-            # fully in-memory node runs without persistence
-            audit_dir = opts.tracing_export_dir
-            if audit_dir is None and opts.db_path:
-                import os as _os
-
-                # db_path is the WAL *file* (cli passes <dir>/wal.log):
-                # persist beside it, inside the data directory
-                audit_dir = _os.path.join(
-                    _os.path.dirname(_os.path.abspath(opts.db_path)), "offload-audit"
-                )
-            from lodestar_tpu.offload.audit import AuditSampler, OffloadAuditor
-
-            # ALWAYS constructed: with --offload-audit-rate 0 it is
-            # passive (no sampling thread) but still owns quarantine
-            # persistence, gauges and rehabilitation — a standing
-            # Byzantine verdict keeps its lifecycle regardless of the
-            # sampling knob
-            auditor = OffloadAuditor(
-                sampler=AuditSampler(
-                    opts.offload_audit_rate, seed=opts.offload_audit_seed
-                ),
-                budget=opts.offload_audit_budget,
-                dump_dir=audit_dir,
-                quarantine_cooloff_s=opts.offload_quarantine_cooloff_s or None,
-                metrics=metrics.audit,
-                start=opts.offload_audit_rate > 0,
-            )
-            client = BlsOffloadClient(
-                opts.offload_endpoints,
-                breaker_threshold=opts.offload_breaker_threshold,
-                breaker_reset_s=opts.offload_breaker_reset_s,
-                hedge_delay_ms=opts.offload_hedge_delay_ms,
-                metrics=metrics.resilience,
-                auditor=auditor,
-                quarantine_cooloff_s=opts.offload_quarantine_cooloff_s or None,
-                tenant=opts.offload_tenant,
-            )
-            if opts.offload_audit_via == "helper" and len(opts.offload_endpoints) > 1:
-                from lodestar_tpu.offload.audit import cross_helper_reference
-
-                auditor.set_reference(cross_helper_reference(client))
-            # operator lifts first, then re-apply persisted Byzantine
-            # quarantines — a restart must not silently re-trust a caught
-            # liar, and that holds even at --offload-audit-rate 0 (the
-            # passive auditor still reads/writes the quarantine file)
-            persisted_before = set(auditor.load_quarantined())
-            for target in opts.offload_unquarantine:
-                if target not in opts.offload_endpoints and target not in persisted_before:
-                    # a typo'd lift silently no-opping would leave the
-                    # operator believing the quarantine was cleared
-                    client.log.warn(
-                        "--offload-unquarantine target matches no configured "
-                        "endpoint and no persisted quarantine record",
-                        {"target": target},
-                    )
-                    continue
-                # clears breaker state AND (via the bound auditor) the
-                # persisted record — the lift logic lives in one place
-                client.unquarantine_endpoint(target)
-            import time as _time
-
-            from lodestar_tpu.offload.audit import remaining_cooloff
-
-            cool = opts.offload_quarantine_cooloff_s or None
-            now = _time.time()
-            for target, rec in auditor.load_quarantined().items():
-                if target in opts.offload_endpoints:
-                    client.quarantine_endpoint(
-                        target,
-                        cooloff_s=remaining_cooloff(rec, cool, now),
-                        reason="persisted_byzantine",
-                    )
-            if opts.offload_fallback == "none":
-                bls = client
-            else:
-                from lodestar_tpu.chain.bls import DegradingBlsVerifier
-
-                layers: list = [("offload", client)]
-                if opts.offload_fallback == "device":
-                    layers.append(("device_pool", _device_pool(opts, metrics)))
-                layers.append(("cpu", BlsSingleThreadVerifier()))
-                bls = DegradingBlsVerifier(layers, metrics=metrics.resilience)
+            bls = _offload_verifier(opts, metrics)
         elif device_runtime["verifier"] == "device":
             bls = _device_pool(opts, metrics)
         else:

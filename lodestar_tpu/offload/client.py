@@ -76,7 +76,7 @@ import time
 
 import grpc
 
-from lodestar_tpu import tracing
+from lodestar_tpu import telemetry, tracing
 from lodestar_tpu.chain.bls.interface import IBlsVerifier, VerifySignatureOpts
 from lodestar_tpu.crypto.bls.api import SignatureSet
 from lodestar_tpu.logger import get_logger
@@ -633,16 +633,21 @@ class BlsOffloadClient(IBlsVerifier):
             if opts is not None and opts.priority is not None
             else PriorityClass.API
         )
-        frame = encode_sets(list(sets))
-        # tenant-stamped variant for capable endpoints: the trailer is
-        # a pure suffix, so the set bytes are serialized once (a hedge
-        # pair may legitimately send different framings; each attempt
-        # digest-checks against the exact bytes it sent)
-        frame_tenant = (
-            frame + encode_tenant_trailer(self.tenant, priority)
-            if self.tenant is not None
-            else None
-        )
+        encoded: dict[str, float] = {}
+        with telemetry.phase("offload.encode", into=encoded):
+            frame = encode_sets(list(sets))
+            # tenant-stamped variant for capable endpoints: the trailer is
+            # a pure suffix, so the set bytes are serialized once (a hedge
+            # pair may legitimately send different framings; each attempt
+            # digest-checks against the exact bytes it sent)
+            frame_tenant = (
+                frame + encode_tenant_trailer(self.tenant, priority)
+                if self.tenant is not None
+                else None
+            )
+        # the call's first RPC carries the encode's seconds on its ledger
+        # entry: the frame is built once, here on the loop thread
+        encode_s = encoded.get("offload.encode", 0.0)
         deadline = self._deadline_for(priority)
         # trace context rides the call's metadata so server-side device
         # spans come home in trailing metadata and stitch under this RPC;
@@ -665,7 +670,7 @@ class BlsOffloadClient(IBlsVerifier):
             # true hedging: concurrent second attempt after the delay,
             # first answer wins, full budget per attempt (no splitting)
             return await self._verify_hedged(
-                frame, frame_tenant, n_sets, priority, deadline, trace_hdr, trace_parent
+                frame, frame_tenant, n_sets, priority, deadline, trace_hdr, trace_parent, encode_s
             )
         max_attempts = 2 if priority in self._hedge_classes and usable > 1 else 1
         tried: tuple[_Endpoint, ...] = ()
@@ -718,6 +723,7 @@ class BlsOffloadClient(IBlsVerifier):
                     None,
                     self._call_endpoint,
                     ep, token, use_frame, n_sets, priority, attempt_deadline, trace_hdr, trace_parent,
+                    0.0 if len(tried) > 1 else encode_s,
                 )
                 if attempt > 0 and m is not None:
                     m.hedge_wins.labels(priority.label).inc()
@@ -757,6 +763,7 @@ class BlsOffloadClient(IBlsVerifier):
         attempt_deadline: float,
         trace_hdr,
         trace_parent,
+        encode_s: float = 0.0,
     ):
         """Launch one verify attempt on the executor WITHOUT awaiting it
         (the hedged path races these). Outstanding counters settle in a
@@ -779,6 +786,7 @@ class BlsOffloadClient(IBlsVerifier):
             None,
             self._call_endpoint,
             ep, token, use_frame, n_sets, priority, attempt_deadline, trace_hdr, trace_parent,
+            encode_s,
         )
 
         def _settle(f, ep=ep):
@@ -800,6 +808,7 @@ class BlsOffloadClient(IBlsVerifier):
         deadline: float,
         trace_hdr,
         trace_parent,
+        encode_s: float = 0.0,
     ) -> bool:
         """True hedged request: the primary attempt gets the FULL class
         budget; if it is still in flight past the hedge delay, a second
@@ -834,6 +843,7 @@ class BlsOffloadClient(IBlsVerifier):
             fut = self._launch_attempt(
                 loop, ep, token, frame, frame_tenant, n_sets,
                 priority, remaining, trace_hdr, trace_parent,
+                0.0 if len(tried) > 1 else encode_s,
             )
             ep_of[fut] = ep
             pending.add(fut)
@@ -933,30 +943,44 @@ class BlsOffloadClient(IBlsVerifier):
         deadline: float,
         trace_hdr,
         trace_parent,
+        encode_s: float = 0.0,
     ) -> bool:
         """One verify RPC on `ep` (runs on an executor thread). Breaker
         outcome and endpoint health are recorded on every exit path,
         token-matched to the attempt that acquired admission — a stale
-        pre-open RPC resolving late cannot perturb a half-open trial."""
+        pre-open RPC resolving late cannot perturb a half-open trial.
+
+        An RPC that brings a frame home is one `offload_rpc` entry of
+        the launch ledger (`telemetry.launch`), its wall this thread's,
+        with the phases `offload.call` (the stub call: the wire both
+        ways and the host's `offload_serve` between) and `offload.check`
+        (the digest-checked decode), and `offload.encode` on a call's
+        first RPC (`encode_s`, spent on the loop thread before this
+        thread was handed the frame)."""
         # clock reads only on the traced path: untraced RPCs pay just
         # the trace_hdr None-checks
         t0 = time.monotonic_ns() if trace_hdr is not None else 0
         grpc_call = None
         err: str | None = None
         try:
-            if trace_hdr is not None:
-                resp, grpc_call = ep.verify.with_call(
-                    frame,
-                    timeout=deadline,
-                    metadata=((tracing.TRACE_CONTEXT_KEY, trace_hdr),),
-                )
-            else:
-                resp = ep.verify(frame, timeout=deadline)
-            # may raise OffloadError: server error frame, malformed frame,
-            # or a digest that doesn't bind this request to this verdict —
-            # trailing spans still came home and must be grafted below
-            # lint: allow(lock-discipline) — executor-thread read of a one-way sticky flag: a stale False only re-admits legacy framing for an RPC already in flight
-            verdict = decode_verdict(resp, request=frame, require_digest=ep.digest_seen)
+            with telemetry.launch("offload_rpc", telemetry.size_class_of(n_sets)) as tel:
+                if encode_s:
+                    tel.add_phase("offload.encode", encode_s)
+                with telemetry.phase("offload.call"):
+                    if trace_hdr is not None:
+                        resp, grpc_call = ep.verify.with_call(
+                            frame,
+                            timeout=deadline,
+                            metadata=((tracing.TRACE_CONTEXT_KEY, trace_hdr),),
+                        )
+                    else:
+                        resp = ep.verify(frame, timeout=deadline)
+                # may raise OffloadError: server error frame, malformed frame,
+                # or a digest that doesn't bind this request to this verdict —
+                # trailing spans still came home and must be grafted below
+                with telemetry.phase("offload.check"):
+                    # lint: allow(lock-discipline) — executor-thread read of a one-way sticky flag: a stale False only re-admits legacy framing for an RPC already in flight
+                    verdict = decode_verdict(resp, request=frame, require_digest=ep.digest_seen)
             ep.breaker.record_success(token)
             with self._lock:
                 ep.healthy = True

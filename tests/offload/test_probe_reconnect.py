@@ -63,8 +63,13 @@ def test_no_reconnect_while_verifications_outstanding():
         client = BlsOffloadClient(DEAD_TARGET, probe_interval_s=0.05)
         with client._lock:
             client._endpoints[0].outstanding = 1  # simulate an in-flight RPC
-        time.sleep(0.6)
         ep = client._endpoints[0]
+        # two failed probes are ~0.5 s apart on an idle machine (the first
+        # backoff step) and later on a loaded one: wait for them, not for a
+        # fixed time
+        deadline = time.monotonic() + 10.0
+        while time.monotonic() < deadline and ep.consecutive_failures < 2:
+            time.sleep(0.02)
         assert ep.consecutive_failures >= 2  # probing continued
         assert reconnects == []  # but no teardown under outstanding work
         with client._lock:
