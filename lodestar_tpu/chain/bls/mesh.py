@@ -204,6 +204,15 @@ class VerifierMesh:
             and _single_launch_active()
         )
 
+    def staged_prep_is_host_only(self) -> bool:
+        """Whether prep staged ahead of a launch touches no device: every
+        lane takes staged inputs and `--bls-single-launch` resolves
+        active, so what is staged is the host byte parse."""
+        return (
+            all(lane.verify_prepared_fn is not None for lane in self.lanes)
+            and _single_launch_active()
+        )
+
     def occupancy(self) -> float:
         lanes = self.available() or self.lanes
         return sum(lane.occupancy.occupancy() for lane in lanes) / len(lanes)

@@ -35,6 +35,13 @@ N-worker thread pool replaced by one device pipeline:
   data-parallel (`verify_signature_sets_sharded`) across the idle chips.
   With a single visible device the mesh is one lane and the launch
   schedule is bit-identical to the pre-mesh pool (regression-tested).
+* **Staged prep** (`PIPELINE_MODES`): where it can be hidden, a
+  package's prep — under the single launch the host byte parse — runs
+  on another thread, launch unit by launch unit, while the device runs
+  the unit before. The dispatcher takes the next package while the
+  lanes are busy only once the queue holds all of it (nothing that
+  arrives later could join it, so no launch's composition changes);
+  an urgent arrival still overtakes the package taken ahead.
 * **Wedge detection** (`offload/resilience.CircuitBreaker`): each lane
   carries its OWN wedge breaker — consecutive launch errors on a chip
   open it, the dispatcher stops placing work there, and in-flight work
@@ -66,7 +73,9 @@ enumerated per device). Tests inject multi-lane topologies via `mesh=`.
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
 import contextlib
+import itertools
 import threading
 import time
 from typing import Awaitable, Callable, Sequence
@@ -104,16 +113,21 @@ __all__ = [
     "PIPELINE_MODES",
 ]
 
-#: prep→verify pipeline modes (--bls-pipeline): "auto" double-buffers
-#: only when the mesh has a sibling lane to stage prep on (a 1-lane /
-#: no-mesh pool keeps the exact pre-pipeline launch schedule), "on"
-#: forces the overlap even on one chip (prep of batch k+1 interleaves
-#: with the verify of batch k on the same die — the host byte work and
-#: the prep launches slot into the verify program's gaps), "off" keeps
-#: prep inline with the launch. Under --bls-single-launch the staged
-#: prep is host byte-parse only (the whole device chain is batch k's
-#: one launch), so the overlap is host parse of k+1 vs the single
-#: launch of k.
+#: prep→verify pipeline modes (--bls-pipeline): while a lane runs launch
+#: unit k, the prep of the unit it runs next (the package's next unit,
+#: else the next package's first) is under way on another thread.
+#: "auto" stages where that buys something: the mesh has a sibling lane
+#: to stage prep on, or — one lane — the staged prep is host-only, i.e.
+#: the lanes take staged inputs and --bls-single-launch resolves active
+#: (byte parse, xmd and blinding of k+1 against the ONE launch of k).
+#: One lane under the split schedule keeps the exact pre-pipeline launch
+#: schedule: staged prep there is device launches on the die that
+#: verifies. A package that finds a lane free and is one launch unit
+#: has no launch to hide its prep behind and takes the inline road.
+#: "on" stages every package, on one chip under either schedule; "off"
+#: keeps prep inline with the launch. Wherever packages are staged, one
+#: is taken ahead of a free lane only when the queue already holds all
+#: of it: an open one stays queued, where arrivals still join it.
 PIPELINE_MODES = ("auto", "on", "off")
 
 # tuning constants — same values/rationale as the reference (index.ts:30-62)
@@ -275,40 +289,32 @@ class _OverlapTracker:
 
 
 class _PrepUnit:
-    """One staged launch unit: the jobs it covers, their flattened sets
-    (a multi-job unit: the list of its jobs' sets), and the prep outcome
-    (PreparedSets: inputs / reject / error)."""
+    """One launch unit of a staged package: the jobs it covers, their
+    flattened sets (a multi-job unit: the list of its jobs' sets), and
+    `prepared`, which the prep thread resolves to the unit's prep
+    outcome (PreparedSets: inputs / reject / error) when it gets there."""
 
-    __slots__ = ("jobs", "sets", "prepared")
+    __slots__ = ("jobs", "sets", "grouped", "prepared")
 
-    def __init__(self, jobs: list[_Job], sets: list, prepared: PreparedSets):
+    def __init__(self, jobs: list[_Job], sets: list, grouped: bool = False):
         self.jobs = jobs
         self.sets = sets
-        self.prepared = prepared
+        self.grouped = grouped  # a multi-job launch: `sets` is a list of jobs' sets
+        self.prepared: concurrent.futures.Future = concurrent.futures.Future()
+        # the prep thread always delivers: a dispatcher cancelled while
+        # it waits for the first unit must not cancel the hand-over
+        self.prepared.set_running_or_notify_cancel()
 
 
 class _PreppedPackage:
-    """Staged launch units for one package (the package itself and its
-    class ride the _Staged entry — this is just the prep output)."""
+    """A staged package's launch units, in the order the verify stage
+    launches them; each is handed over as its prep is ready."""
 
     __slots__ = ("chunks", "units")
 
-    def __init__(self, chunks, units):
-        self.chunks = chunks  # batchable RLC chunks, prep staged
-        self.units = units  # non-batchable jobs, one or a group a unit, prep staged
-
-
-class _Staged:
-    """Staging-queue entry: the dequeued package plus the (possibly
-    still-running) prep future; `prep` is None for bulk packages, which
-    keep the inline-prep sharded road."""
-
-    __slots__ = ("package", "cls", "prep")
-
-    def __init__(self, package, cls, prep):
-        self.package = package
-        self.cls = cls
-        self.prep = prep
+    def __init__(self, chunks: list[_PrepUnit], units: list[_PrepUnit]):
+        self.chunks = chunks  # batchable RLC chunks
+        self.units = units  # non-batchable jobs, one or a group a unit
 
 
 class BlsDeviceVerifierPool(IBlsVerifier):
@@ -376,34 +382,28 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                 verify_grouped_fn=grouped_fn,
             )
 
-        # prep→verify double buffering: stage prep of package k+1 while
-        # the lanes verify package k. "auto" engages only with a sibling
-        # lane to stage on — the 1-lane default keeps the pre-pipeline
-        # launch schedule exactly (regression-tested). Staging also
-        # requires lanes that can CONSUME staged inputs (or an injected
-        # prep_fn): a mesh of plain verify callables would pay real prep
-        # for inputs nobody uses — and a prep-stage structural reject
-        # would overrule a backend that never saw the sets
+        # prep→verify double buffering (PIPELINE_MODES). Staging requires
+        # lanes that can CONSUME staged inputs (or an injected prep_fn):
+        # a mesh of plain verify callables would pay real prep for
+        # inputs nobody uses — and a prep-stage structural reject would
+        # overrule a backend that never saw the sets. Whether "auto"
+        # engages on one lane follows the schedule, which is resolved
+        # where packages are staged (`_staging`): a pool may be built
+        # before, and tests flip the mode around calls
         if pipeline not in PIPELINE_MODES:
             raise ValueError(
                 f"bls_pipeline must be one of {PIPELINE_MODES}, got {pipeline!r}"
             )
         self.pipeline_mode = pipeline
-        stageable = prep_fn is not None or all(
+        self._stageable = prep_fn is not None or all(
             lane.verify_prepared_fn is not None for lane in self.mesh.lanes
         )
-        if pipeline == "on" and not stageable:
+        if pipeline == "on" and not self._stageable:
             self._log.warn(
                 "bls pipeline forced on but no lane can verify staged inputs; "
                 "running unpipelined"
             )
-        self._pipeline_enabled = stageable and (
-            pipeline == "on" or (pipeline == "auto" and len(self.mesh) > 1)
-        )
         self._prep_fn = prep_fn if prep_fn is not None else self._default_prep_fn
-        self._staged_q: asyncio.Queue | None = None  # guarded by: event-loop (built by _ensure_runner)
-        self._stage_slot: asyncio.Semaphore | None = None  # guarded by: event-loop (built by _ensure_runner)
-        self._verify_runner: asyncio.Task | None = None  # guarded by: event-loop (single-threaded)
         self._overlap = _OverlapTracker()
         self._staged_packages = 0  # guarded by: advisory-only (monotonic count, prep threads under the GIL)
         if pipeline_metrics is not None:
@@ -460,8 +460,10 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         self._closed = False  # guarded by: event-loop (one-way flag; executor readers see it at worst one package late)
         self._runner: asyncio.Task | None = None  # guarded by: event-loop (single-threaded)
         self._launch_tasks: set[asyncio.Task] = set()  # guarded by: event-loop (single-threaded)
-        self._lane_free = asyncio.Event()  # guarded by: event-loop (single-threaded)
-        self._lane_free.set()
+        # what the parked dispatcher re-reads its state for: a lane
+        # freed, a job reached the queue, the pool closed
+        self._wake = asyncio.Event()  # guarded by: event-loop (single-threaded)
+        self._wake.set()
 
         # metric counters (reference blsThreadPool.* taxonomy)
         self.metrics = {  # guarded by: advisory-only (incremented from executor threads under the GIL; scrapers read stale-by-one)
@@ -472,6 +474,11 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             "errors": 0,
             "sharded_launches": 0,
             "sharded_fallbacks": 0,
+            # staged prep: its wall time, and the part of it during which
+            # a launch was in flight (`_OverlapTracker`, written as each
+            # unit's prep ends); inline prep is in neither
+            "parse_ns": 0,
+            "parse_hidden_ns": 0,
         }
 
     @property
@@ -530,7 +537,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         for job, _cls, _waited in self._jobs.drain():
             if not job.future.done():
                 job.future.set_exception(err)
-        self._lane_free.set()  # unblock a dispatcher parked on a busy mesh
+        self._wake.set()  # unblock a dispatcher parked on a busy mesh
         if self._runner is not None:
             self._runner.cancel()
             try:
@@ -538,31 +545,6 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             except asyncio.CancelledError:
                 pass
             self._runner = None
-        if self._verify_runner is not None:
-            self._verify_runner.cancel()
-            try:
-                await self._verify_runner
-            except asyncio.CancelledError:
-                pass
-            self._verify_runner = None
-        # drain the staging queue: a package parked between the prep and
-        # verify stages has no other owner left to fail its futures (and
-        # its still-running prep future nobody left to await — consume
-        # the eventual outcome so a late prep error isn't logged as an
-        # unretrieved exception at shutdown)
-        if self._staged_q is not None:
-            while True:
-                try:
-                    staged = self._staged_q.get_nowait()
-                except asyncio.QueueEmpty:
-                    break
-                if staged.prep is not None:
-                    staged.prep.add_done_callback(
-                        lambda f: f.cancelled() or f.exception()
-                    )
-                for job in staged.package:
-                    if not job.future.done():
-                        job.future.set_exception(err)
         # in-flight launches: cancel the awaiting tasks (the executor
         # threads run to completion and resolve futures thread-safe,
         # exactly like the pre-mesh abandoned run_in_executor)
@@ -575,22 +557,8 @@ class BlsDeviceVerifierPool(IBlsVerifier):
     # -- queueing -------------------------------------------------------------
 
     def _ensure_runner(self) -> None:
-        loop = asyncio.get_event_loop()
-        if self._pipeline_enabled:
-            # BOTH stage tasks self-heal independently: a dead dispatch
-            # stage with a live staging stage would otherwise fill the
-            # 1-deep queue and hang every later verify with no restart
-            if self._staged_q is None:
-                # depth 1 IS the double buffer: one package staged
-                # (prep in flight) beyond whatever is launching
-                self._staged_q = asyncio.Queue(maxsize=1)
-                self._stage_slot = asyncio.Semaphore(1)
-            if self._runner is None or self._runner.done():
-                self._runner = loop.create_task(self._stage_jobs())
-            if self._verify_runner is None or self._verify_runner.done():
-                self._verify_runner = loop.create_task(self._dispatch_staged())
-        elif self._runner is None or self._runner.done():
-            self._runner = loop.create_task(self._run_jobs())
+        if self._runner is None or self._runner.done():
+            self._runner = asyncio.get_event_loop().create_task(self._run_jobs())
 
     def _enqueue(self, job: _Job) -> _Job:
         self._outstanding += 1
@@ -607,6 +575,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                 )
         else:
             self._jobs.put_nowait(job, job.priority)
+            self._wake.set()
         return job
 
     def _dec_outstanding(self) -> None:
@@ -631,6 +600,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         for job in jobs:
             slo.job_flushed(job.slo)
             self._jobs.put_nowait(job, job.priority)
+        self._wake.set()
 
     # -- execution ------------------------------------------------------------
 
@@ -666,14 +636,10 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         return [lane for lane in self.mesh.lanes if lane.inflight == 0]
 
     async def _wait_free_lane(self) -> None:
-        """Park the dispatcher until some lane can take a package. The
-        wait happens BEFORE the dequeue, so jobs stay in the priority
-        queue (and keep reordering under arriving urgent work) until
-        the mesh actually has capacity — with one lane this is exactly
-        the pre-mesh serialized schedule."""
+        """Park the dispatcher until some lane can take a package."""
         while not self._free_lanes():
-            self._lane_free.clear()
-            await self._lane_free.wait()
+            self._wake.clear()
+            await self._wake.wait()
 
     def _pick_placement(
         self, cls: PriorityClass, package: list[_Job], free: list[MeshLane]
@@ -701,45 +667,67 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         lane = min(free, key=lambda l: (l.wedged, l.occupancy.occupancy()))
         return "single", [lane]
 
+    def _package_extent(self, cls: PriorityClass, jobs) -> tuple[int, bool]:
+        """Under the scheduler, what a package of class `cls` takes of
+        `jobs` (the class in queue order, not empty): (the first n,
+        whether it is closed, i.e. nothing that arrives later could join
+        it). Same class only, capped at MAX_PACKAGE_SETS (and a bulk
+        package is ONE launch) — both bound how long an arriving gossip
+        block can wait behind the in-flight launch. What one bulk launch
+        can carry: one job, or, from a groupable head job on, further
+        jobs while each is groupable too and a slot is left — so a queue
+        of small backfill jobs never becomes a package of many launches.
+        (Where the mesh can shard, bulk stays one job a package: the
+        collective's units are that road's own.)"""
+        n = 0
+        if cls in BULK_CLASSES:
+            slots = (
+                MAX_GROUP_JOBS
+                if self.mesh.grouping_available() and not self.mesh.sharding_available()
+                else 1
+            )
+            for j in jobs:
+                if n and not _groupable(j):
+                    return n, True  # the next launch's job
+                n += 1
+                if n == slots or not _groupable(j):
+                    return n, True
+            return n, False
+        sets = 0
+        for j in jobs:
+            n += 1
+            sets += len(j.sets)
+            if sets >= MAX_PACKAGE_SETS:
+                return n, True
+        return n, False
+
+    def _package_formed(self) -> bool:
+        """Whether the package `_next_package()` would take now is
+        closed: taking it ahead of a free lane then changes no launch's
+        composition. An open one stays in the queue, where work that
+        arrives during the launch in flight still joins it."""
+        if not self.scheduler_enabled:
+            return False  # the FIFO arm drains whatever is there
+        cls = self._jobs.next_class()
+        return cls is not None and self._package_extent(cls, self._jobs.queued(cls))[1]
+
     async def _next_package(self) -> tuple[list[_Job], PriorityClass]:
         """Dequeue one job and drain immediately-available work into the
-        package: same class only under the scheduler, capped at
-        MAX_PACKAGE_SETS (and a bulk package is ONE launch) — both
-        bound how long an arriving gossip block can wait behind the
-        in-flight launch; everything available in FIFO mode (the
-        pre-scheduler arm). What one bulk launch can carry: one job, or,
-        from a groupable head job on, further jobs of its class while
-        each is groupable too and a slot is left — so a queue of small
-        backfill jobs never becomes a package of many launches. (Where
-        the mesh can shard, bulk stays one job a package: the
-        collective's units are that road's own.)"""
+        package: under the scheduler its class's `_package_extent`;
+        everything available in FIFO mode (the pre-scheduler arm)."""
         job, cls, waited_ns = await self._jobs.get()
         with telemetry.phase("bls.next_package"):
             self._record_sched_dequeue(job, cls, waited_ns)
             package = [job]
-            if self.scheduler_enabled and cls in BULK_CLASSES:
-                if (
-                    _groupable(job)
-                    and not self.mesh.sharding_available()
-                    and self.mesh.grouping_available()
-                ):
-                    while len(package) < MAX_GROUP_JOBS:
-                        head = self._jobs.peek(cls)
-                        if head is None or not _groupable(head):
-                            break
-                        nxt = self._jobs.get_nowait(cls)
-                        self._record_sched_dequeue(*nxt)
-                        package.append(nxt[0])
+            if self.scheduler_enabled:
+                queued = itertools.chain(package, self._jobs.queued(cls))
+                more, drain_cls = self._package_extent(cls, queued)[0] - 1, cls
             else:
-                drain_cls = cls if self.scheduler_enabled else None
-                package_sets = len(job.sets)
-                while not self.scheduler_enabled or package_sets < MAX_PACKAGE_SETS:
-                    nxt = self._jobs.get_nowait(drain_cls)
-                    if nxt is None:
-                        break
-                    self._record_sched_dequeue(*nxt)
-                    package.append(nxt[0])
-                    package_sets += len(nxt[0].sets)
+                more, drain_cls = len(self._jobs), None
+            for _ in range(more):
+                nxt = self._jobs.get_nowait(drain_cls)
+                self._record_sched_dequeue(*nxt)
+                package.append(nxt[0])
         return package, cls
 
     async def _place_and_launch(self, package, cls, prepped=None) -> None:
@@ -757,9 +745,9 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                 # placement (a cross-lane retry on an executor
                 # thread can trip any breaker): healthy lanes exist
                 # but are busy — their in-flight completions set
-                # _lane_free, so this wait always terminates
-                self._lane_free.clear()
-                await self._lane_free.wait()
+                # _wake, so this wait always terminates
+                self._wake.clear()
+                await self._wake.wait()
                 if self._closed:
                     raise asyncio.CancelledError("bls pool closed")
         except asyncio.CancelledError:
@@ -779,91 +767,100 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             task.add_done_callback(self._launch_tasks.discard)
 
     async def _run_jobs(self) -> None:
-        while not self._closed:
-            await self._wait_free_lane()
-            if self._closed:
-                return
-            package, cls = await self._next_package()
-            await self._place_and_launch(package, cls)
+        """THE dispatcher: wait for a lane, take a package, place it.
 
-    # -- prep→verify pipeline (dispatcher split into two stages) ---------------
+        The wait comes BEFORE the dequeue, so jobs stay in the priority
+        queue (keep reordering under arriving urgent work, and keep
+        being joined by work of their class) until the mesh has
+        capacity. Where packages are staged (`_staging`) one exception:
+        a package the queue already holds all of (`_package_formed`) is
+        taken while the lanes are busy and its prep goes to another
+        thread, unit by unit, so that a freed lane finds the first unit
+        ready — the look-ahead beyond the launches in flight is exactly
+        one package, and it is the package the freed lane would have
+        taken. It must not lengthen what an urgent job waits behind:
+        when the package in hand has waited for its lane and the
+        queue's own pick is by then of a more urgent class, that pick
+        is served first, and the package in hand keeps its prep and
+        goes after it — unless it has been in hand for the queue's
+        starvation bound, which the queue can no longer apply to it."""
+        package: list[_Job] | None = None  # in hand: out of the queue, not yet placed
+        err: BaseException = asyncio.CancelledError("bls pool closed")
+        try:
+            while not self._closed:
+                if package is None:
+                    while not (
+                        self._free_lanes() or (self._staging() and self._package_formed())
+                    ):
+                        self._wake.clear()
+                        await self._wake.wait()
+                        if self._closed:
+                            return
+                    package, cls = await self._next_package()
+                    taken_ns = time.monotonic_ns()
+                    prepped = self._stage(package, cls) if self._staging() else None
+                if not self._free_lanes():
+                    await self._wait_free_lane()
+                    if self._closed:
+                        return
+                    pick = self._jobs.next_class()
+                    if (
+                        self.scheduler_enabled
+                        and pick is not None
+                        and pick < cls
+                        and not self._jobs.aged(taken_ns)
+                    ):
+                        await self._place_and_launch(*await self._next_package())
+                        continue
+                if prepped is not None:
+                    # only the first unit: the launch takes the rest as it gets to them
+                    await asyncio.wrap_future((prepped.chunks + prepped.units)[0].prepared)
+                placing, package = package, None  # _place_and_launch answers for it from here
+                await self._place_and_launch(placing, cls, prepped=prepped)
+                # the launch reaches its thread, and its dispatch the GIL,
+                # before the next package's prep reaches another
+                await asyncio.sleep(0)
+        except asyncio.CancelledError:
+            raise
+        except BaseException as e:
+            err = e
+            raise
+        finally:
+            # close() only drains the queue: it cannot see the package in hand
+            for j in package or ():
+                if not j.future.done():
+                    j.future.set_exception(err)
 
-    async def _stage_jobs(self) -> None:
-        """Pipeline stage 1: reserve the staging slot, dequeue, submit
-        prep to an executor thread, hand the package to the verify
-        dispatcher through the 1-deep staging queue. The slot is
-        acquired BEFORE the dequeue, so package k+2 is not even taken
-        out of the priority queue until the dispatcher consumed k+1 —
-        the lookahead beyond the in-flight launches is exactly one
-        package, the same bound the pre-pipeline dispatcher's in-hand
-        package had."""
-        loop = asyncio.get_event_loop()
-        while not self._closed:
-            await self._stage_slot.acquire()
-            if self._closed:
-                self._stage_slot.release()
-                return
-            try:
-                package, cls = await self._next_package()
-            except BaseException:
-                # nothing dequeued: release the slot so a restarted
-                # stage loop (the self-heal contract) isn't deadlocked
-                # on a permit this dead task took to its grave
-                self._stage_slot.release()
-                raise
-            try:
-                if self.scheduler_enabled and cls in BULK_CLASSES:
-                    # bulk may shard across lanes; the collective launch
-                    # preps inline exactly like the unpipelined pool
-                    prep = None
-                else:
-                    prep = loop.run_in_executor(
-                        None, self._prep_package, package
-                    )
-                # the slot reservation guarantees room: never blocks
-                self._staged_q.put_nowait(_Staged(package, cls, prep))
-            except BaseException as e:
-                # ANY failure here (cancellation, an executor refusing
-                # work at shutdown, ...) must fail the in-hand package's
-                # futures — no one else can see it — and return the
-                # staging permit before the task dies
-                self._stage_slot.release()
-                err = (
-                    asyncio.CancelledError("bls pool closed")
-                    if isinstance(e, asyncio.CancelledError)
-                    else e
-                )
-                for j in package:
-                    if not j.future.done():
-                        j.future.set_exception(err)
-                raise
+    # -- prep→verify pipeline ---------------------------------------------------
 
-    async def _dispatch_staged(self) -> None:
-        """Pipeline stage 2: wait for lane capacity, take the staged
-        package (releasing the staging slot), await its prep, place and
-        launch. Placement policy, verdict semantics, and the
-        fail-closed chain are the unpipelined dispatcher's — only the
-        prep wall time moved off the critical path."""
-        while not self._closed:
-            await self._wait_free_lane()
-            if self._closed:
-                return
-            staged = await self._staged_q.get()
-            self._stage_slot.release()
-            try:
-                prepped = await staged.prep if staged.prep is not None else None
-            except asyncio.CancelledError:
-                err = asyncio.CancelledError("bls pool closed")
-                for j in staged.package:
-                    if not j.future.done():
-                        j.future.set_exception(err)
-                raise
-            except Exception as e:  # prep infrastructure failure: fail closed
-                for j in staged.package:
-                    if not j.future.done():
-                        j.future.set_exception(e)
-                continue
-            await self._place_and_launch(staged.package, staged.cls, prepped=prepped)
+    def _staging(self) -> bool:
+        """Whether packages' prep is staged (PIPELINE_MODES): what "auto"
+        follows is read here, per package, not where the pool was built."""
+        if not self._stageable or self.pipeline_mode == "off":
+            return False
+        if self.pipeline_mode == "on" or len(self.mesh) > 1:
+            return True
+        # one lane: only where the staged prep touches no device
+        return self.mesh.staged_prep_is_host_only()
+
+    def _stage(self, package: list[_Job], cls: PriorityClass) -> _PreppedPackage | None:
+        """Form the package's launch units and hand their prep to an
+        executor thread; None where the package keeps its inline prep: a
+        bulk package on a mesh that can shard (the collective launch
+        preps inline), and unless forced "on" a package of one launch
+        unit that finds a lane free (no launch to hide its prep behind,
+        so staging would only add two thread hops to its verdict)."""
+        if self.scheduler_enabled and cls in BULK_CLASSES and self.mesh.sharding_available():
+            return None
+        chunks, units = _launch_units(package, self.mesh.grouping_available())
+        if self.pipeline_mode != "on" and len(chunks) + len(units) == 1 and self._free_lanes():
+            return None
+        prepped = _PreppedPackage(
+            [_PrepUnit(chunk, [s for j in chunk for s in j.sets]) for chunk in chunks],
+            [_PrepUnit(unit, _unit_sets(unit), grouped=len(unit) > 1) for unit in units],
+        )
+        asyncio.get_event_loop().run_in_executor(None, self._prep_package, prepped)
+        return prepped
 
     def _default_prep_fn(self, sets: list[SignatureSet], lane_hint: int | None):
         from lodestar_tpu.models.batch_verify import prepare_inputs_for_lane
@@ -882,7 +879,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             return None
         return min(free, key=lambda l: l.occupancy.occupancy()).index
 
-    def _prep_unit(self, jobs: list[_Job], sets: list, grouped: bool = False) -> _PrepUnit:
+    def _prep_unit(self, sets: list, grouped: bool = False) -> PreparedSets:
         """Stage prep for one launch unit (prep executor thread). Errors
         are CAPTURED, not raised: the launch re-preps through the plain
         verify path so a prep fault takes the exact pre-pipeline
@@ -906,21 +903,27 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                     inputs = self._prep_fn(sets, self._prep_lane_hint())
             except Exception as e:
                 error = e
+        spent = self._overlap.snapshot()
+        self.metrics["parse_ns"] = spent["prep_ns"]
+        self.metrics["parse_hidden_ns"] = spent["overlap_ns"]
         info = consume_prep_info()
         if info is not None and info["end_ns"] < t0_ns:
             info = None  # stale record from an earlier launch on this thread
-        return _PrepUnit(jobs, sets, PreparedSets(inputs, error, info))
+        return PreparedSets(inputs, error, info)
 
-    def _prep_package(self, package: list[_Job]) -> _PreppedPackage:
-        """Prep every launch unit the verify stage will dispatch:
-        `_launch_units`' boundaries, the ones `_verify_package` launches
-        unstaged, so the launch schedule is unchanged."""
+    def _prep_package(self, prepped: _PreppedPackage) -> None:
+        """Prep executor thread: the package's units in the order the
+        verify stage launches them (`_launch_units`' boundaries, the
+        ones `_verify_package` launches unstaged, so the launch schedule
+        is unchanged), each handed over as it is ready. Every unit gets
+        an outcome, whatever happens here: a launch may be waiting on it."""
         self._staged_packages += 1
-        chunks, units = _launch_units(package, self.mesh.grouping_available())
-        return _PreppedPackage(
-            [self._prep_unit(chunk, [s for j in chunk for s in j.sets]) for chunk in chunks],
-            [self._prep_unit(unit, _unit_sets(unit), grouped=len(unit) > 1) for unit in units],
-        )
+        for unit in prepped.chunks + prepped.units:
+            try:
+                outcome = self._prep_unit(unit.sets, unit.grouped)
+            except Exception as e:  # not a prep fault (those are captured): the launch re-preps inline
+                outcome = PreparedSets(error=e)
+            unit.prepared.set_result(outcome)
 
     def pipeline_stats(self) -> dict:
         """Pipeline wall-clock accounting: prep/verify busy time, their
@@ -935,7 +938,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         v = s["verify_ns"]
         s["overlap_occupancy_pct"] = (100.0 * s["overlap_ns"] / v) if v else 0.0
         s["staged_packages"] = self._staged_packages
-        s["pipeline_enabled"] = self._pipeline_enabled
+        s["pipeline_enabled"] = self._staging()
         return s
 
     def _release_lanes_early(self, to_release: list[MeshLane], held: list[MeshLane]) -> None:
@@ -947,14 +950,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             if lane in held:
                 held.remove(lane)
                 lane.inflight -= 1
-        self._lane_free.set()
-
-    def _with_verify_window(self, fn, *args) -> None:
-        """Executor-thread entry: every verify path runs inside the
-        overlap tracker's verify window (the denominator of the
-        pipeline's overlap-occupancy number)."""
-        with self._overlap.verify():
-            fn(*args)
+        self._wake.set()
 
     async def _launch(
         self,
@@ -967,13 +963,11 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         try:
             if mode == "sharded":
                 await asyncio.get_event_loop().run_in_executor(
-                    None, self._with_verify_window,
-                    self._verify_package_sharded, package, lanes, held,
+                    None, self._verify_package_sharded, package, lanes, held
                 )
             else:
                 await asyncio.get_event_loop().run_in_executor(
-                    None, self._with_verify_window,
-                    self._verify_package, package, lanes[0], False, prepped,
+                    None, self._verify_package, package, lanes[0], False, prepped
                 )
         except asyncio.CancelledError:
             # close() cancels launch tasks; if the executor work item
@@ -998,7 +992,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             # executor thread that outlives a cancelled launch task)
             # finds nothing left to double-decrement
             held.clear()
-            self._lane_free.set()
+            self._wake.set()
 
     # -- device launches (executor threads) ------------------------------------
 
@@ -1031,18 +1025,22 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         lane is exactly the pre-mesh fail-closed behavior). `prepared`
         carries staged pipeline inputs (see mesh_launch). `grouped`: the
         multi-job launch (`sets` a list of jobs' sets, `ok` a verdict a
-        job). Returns (ok, lane_that_served)."""
+        job). Returns (ok, lane_that_served). The launch is the overlap
+        tracker's verify window: it opens once the unit's inputs are in
+        hand, so prep a launch thread is still waiting for is not
+        counted as hidden."""
         from .mesh import mesh_launch
 
-        return mesh_launch(
-            self.mesh,
-            sets,
-            prefer=lane,
-            prepared=prepared,
-            grouped=grouped,
-            on_launch=lambda l: self._count_lane_launch(l, "grouped" if grouped else "single"),
-            on_wedge=self._on_lane_wedge,
-        )
+        with self._overlap.verify():
+            return mesh_launch(
+                self.mesh,
+                sets,
+                prefer=lane,
+                prepared=prepared,
+                grouped=grouped,
+                on_launch=lambda l: self._count_lane_launch(l, "grouped" if grouped else "single"),
+                on_wedge=self._on_lane_wedge,
+            )
 
     def _verify_package(
         self,
@@ -1055,7 +1053,9 @@ class BlsDeviceVerifierPool(IBlsVerifier):
 
         `prepped` carries the pipeline's staged launch units — the SAME
         unit boundaries as the inline path, so the launch schedule is
-        identical; only where prep ran differs. The batch-then-retry
+        identical; only where prep ran differs. A unit's staged inputs
+        are taken when the launches get to it: while unit i runs, the
+        prep thread is at unit i+1. The batch-then-retry
         road always re-preps INLINE (fresh blinding, fresh prep — one
         bad signature can't poison its neighbors, and a stale staged
         prep can't poison the retry)."""
@@ -1095,7 +1095,8 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         # RLC-batch the batchable jobs in ≥16-set chunks; invalid batch →
         # retry each job individually (worker.ts:52-96)
         retries: list[_Job] = []
-        for jobs, all_sets, staged in chunk_units:
+        for jobs, all_sets, handed in chunk_units:
+            staged = handed.result() if handed is not None else None  # waits where the prep thread is not there yet
             t0 = time.monotonic_ns() if traced else 0
             try:
                 ok, served = self._launch_sets(lane, all_sets, prepared=staged)
@@ -1123,7 +1124,8 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                 self.metrics["batch_retries"] += 1
                 retries.extend(jobs)
 
-        for jobs, sets_, staged in job_units + [([j], j.sets, None) for j in retries]:
+        for jobs, sets_, handed in job_units + [([j], j.sets, None) for j in retries]:
+            staged = handed.result() if handed is not None else None
             if len(jobs) > 1:
                 self._verify_grouped_unit(jobs, sets_, staged, lane, traced)
                 continue
@@ -1232,10 +1234,8 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                         {"sets": len(j.sets)},
                     )
         t0 = time.monotonic_ns() if traced else 0
-        import contextlib
-
         try:
-            with contextlib.ExitStack() as stack:
+            with contextlib.ExitStack() as stack, self._overlap.verify():
                 for lane in lanes:
                     stack.enter_context(lane.occupancy.launch())
                 ok = bool(

@@ -95,10 +95,12 @@ def _add_scheduler_args(sp) -> None:
     sp.add_argument(
         "--bls-pipeline", choices=["auto", "on", "off"], default="auto",
         help="double-buffer the BLS prep→verify pipeline: stage input prep "
-        "of batch k+1 while batch k verifies (auto = only when the mesh "
-        "has a sibling lane to prep on, on = overlap even on one chip, "
-        "off = prep inline with the launch). Verdicts, priority "
-        "placement, and the fail-closed degradation chain are unchanged.",
+        "of the next launch while this one verifies (auto = when the mesh "
+        "has a sibling lane to prep on, or on one lane when the staged "
+        "prep is the host byte parse of --bls-single-launch; on = every "
+        "package, on one chip under either schedule; off = prep inline "
+        "with the launch). Verdicts, priority placement, and the "
+        "fail-closed degradation chain are unchanged.",
     )
     sp.add_argument(
         # literal copy of models.batch_verify.SINGLE_LAUNCH_MODES

@@ -121,12 +121,14 @@ class PriorityWorkQueue:
 
     # -- egress ----------------------------------------------------------------
 
-    def _select_class(self) -> PriorityClass | None:
+    def _pick(self) -> tuple[PriorityClass | None, bool]:
+        """(the class the next dequeue serves, whether aging promoted it
+        over the fair pick); touches no accounting."""
         nonempty = [c for c in PriorityClass if self._queues[c]]
         if not nonempty:
-            return None
+            return None, False
         if self.fifo:
-            return min(nonempty, key=lambda c: self._queues[c][0][1])
+            return min(nonempty, key=lambda c: self._queues[c][0][1]), False
         now = self._time_fn()
         fair = min(nonempty, key=lambda c: (self._pass[c], c))
         aged = [c for c in nonempty if now - self._queues[c][0][1] >= self._aging_ns]
@@ -136,16 +138,30 @@ class PriorityWorkQueue:
             # backlog under sustained saturation must not degenerate the
             # queue to global FIFO — an arriving urgent job waits out at
             # most ONE promotion before the fair order serves it
-            if chosen is not fair and self._last_was_promotion:
-                chosen = fair
-            if chosen is not fair:
-                self._last_was_promotion = True
+            if chosen is not fair and not self._last_was_promotion:
+                return chosen, True
+        return fair, False
+
+    def _select_class(self) -> PriorityClass | None:
+        chosen, promoted = self._pick()
+        if chosen is not None and not self.fifo:
+            self._last_was_promotion = promoted
+            if promoted:
                 self.starvation_promotions += 1
                 if self.metrics is not None:
                     self.metrics.starvation_promotions.inc()
-                return chosen
-        self._last_was_promotion = False
-        return fair
+        return chosen
+
+    def next_class(self) -> PriorityClass | None:
+        """The class `get_nowait()` would serve now, by the same stride
+        and aging order, left where it is; None when empty."""
+        return self._pick()[0]
+
+    def aged(self, since_ns: int) -> bool:
+        """Whether work that has waited since `since_ns` (this queue's
+        clock) is past the starvation bound: for a consumer that holds
+        an item it has already taken out."""
+        return self._time_fn() - since_ns >= self._aging_ns
 
     def get_nowait(
         self, cls: PriorityClass | None = None
@@ -176,11 +192,11 @@ class PriorityWorkQueue:
             self.metrics.jobs_dequeued.labels(cls.label).inc()
         return item, cls, waited_ns
 
-    def peek(self, cls: PriorityClass):
-        """The item `get_nowait(cls)` would pop, left where it is; None
-        when the class is empty. Touches no accounting."""
-        q = self._queues[PriorityClass(cls)]
-        return q[0][0] if q else None
+    def queued(self, cls: PriorityClass):
+        """The class's items in the order `get_nowait(cls)` would pop
+        them, left where they are. Touches no accounting; not to be
+        iterated across a put or a get."""
+        return (item for item, _enq_ns in self._queues[PriorityClass(cls)])
 
     async def get(self) -> tuple[object, PriorityClass, int]:
         while True:

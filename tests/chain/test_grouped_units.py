@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import asyncio
 import threading
+import time
 
 import pytest
 
@@ -417,7 +418,7 @@ def test_prep_package_and_verify_package_form_identical_units(monkeypatch):
             mesh=rig.mesh, pipeline="on", prep_fn=lambda sets, hint: ("inputs", [_tag(sets)])
         )
         package = [_Job(_sets(n, tag=i), False, PriorityClass.API) for i, n in enumerate((66, 10, 65, 70))]
-        prepped = pool._prep_package(package)
+        prepped = pool._stage(package, PriorityClass.API)
         _, units = _launch_units(package, grouping=True)
         assert [u.jobs for u in prepped.units] == units
         pool._verify_package(package, rig.mesh.lanes[0], prepped=prepped)
@@ -467,3 +468,153 @@ def test_the_staged_pipeline_serves_a_block_with_one_staged_multi_job_launch(mon
     assert got == [True, False]
     assert prepared_seen == [("grouped-inputs", [66, 65])] and rig.launches == []
     assert stats["pipeline_enabled"] and stats["staged_packages"] == 1
+
+
+# -- staging is per launch unit: the parse of unit i+1 rides the launch of unit i ---
+
+
+class StagedRig(Rig):
+    """A `Rig` whose lane also takes staged inputs, with a parse and a
+    launch that take a while. The staged parse of a unit hands the
+    lane what the unstaged entries are handed, so `launches` and `tags`
+    read the same on both roads; `events` orders parse and launch
+    starts and ends as they happened."""
+
+    def __init__(self, monkeypatch, bad=(), parse_s=0.0, launch_s=0.0):
+        super().__init__(bad=bad)
+        self.parse_s, self.launch_s = parse_s, launch_s
+        self.events: list[tuple[str, int]] = []
+        self._lock = threading.Lock()
+        self.mesh.lanes[0].verify_prepared_fn = self.verify_prepared
+        monkeypatch.setattr(bv, "prepare_grouped_launch_inputs", lambda jobs: self.parse("grouped", jobs))
+
+    def note(self, what: str, first_tag: int) -> None:
+        with self._lock:
+            self.events.append((what, first_tag))
+
+    def parse(self, kind: str, payload):
+        first = _tag(payload[0]) if kind == "grouped" else _tag(payload)
+        self.note("parse-start", first)
+        time.sleep(self.parse_s)
+        self.note("parse-end", first)
+        return kind, payload
+
+    def prep_fn(self, sets, lane_hint):
+        return self.parse("single", sets)
+
+    def verify_prepared(self, inputs):
+        kind, payload = inputs
+        return self.verify_grouped(payload) if kind == "grouped" else self.verify(payload)
+
+    def verify(self, sets) -> bool:
+        self.note("launch-start", _tag(sets))
+        time.sleep(self.launch_s)
+        self.note("launch-end", _tag(sets))
+        # a chunk holds several jobs' sets: one bad set fails the batch
+        return super().verify(sets) and not {s.pubkey[1] for s in sets} & self.bad
+
+    def verify_grouped(self, jobs) -> list[bool]:
+        self.note("launch-start", _tag(jobs[0]))
+        time.sleep(self.launch_s)
+        self.note("launch-end", _tag(jobs[0]))
+        return super().verify_grouped(jobs)
+
+
+def test_the_second_units_parse_rides_the_first_units_launch(monkeypatch):
+    """Eight jobs of the 128 class in one latency-class package (four
+    tenants' blocks): two units of four. The package is staged though
+    it finds the lane free, because its second unit has the first to
+    hide behind: the second parse starts and ends inside the first
+    launch, and the second launch follows the first with no parse in
+    between."""
+    rig = StagedRig(monkeypatch, parse_s=0.03, launch_s=0.15)
+
+    async def go():
+        pool = BlsDeviceVerifierPool(mesh=rig.mesh, prep_fn=rig.prep_fn)
+        futs = [
+            asyncio.ensure_future(_submit(pool, _sets(66, tag=i), PriorityClass.GOSSIP_BLOCK))
+            for i in range(8)
+        ]
+        got = await asyncio.gather(*futs)
+        stats, metrics = pool.pipeline_stats(), dict(pool.metrics)
+        await pool.close()
+        return got, stats, metrics
+
+    got, stats, metrics = _run(go())
+    assert got == [True] * 8
+    assert rig.launches == [("grouped", [66] * 4)] * 2 and rig.tags == [[0, 1, 2, 3], [4, 5, 6, 7]]
+    at = {event: i for i, event in enumerate(rig.events)}
+    assert len(at) == len(rig.events) == 8
+    assert at["parse-end", 0] < at["launch-start", 0]  # only the first unit is waited for
+    assert at["parse-end", 0] < at["parse-start", 4] < at["parse-end", 4] < at["launch-end", 0]
+    assert at["launch-start", 4] == at["launch-end", 0] + 1  # no parse between the launches
+    assert stats["staged_packages"] == 1
+    # one parse of the two had a launch to hide behind
+    assert 0 < metrics["parse_hidden_ns"] <= metrics["parse_ns"]
+    assert 0.2 < metrics["parse_hidden_ns"] / metrics["parse_ns"] < 0.8
+
+
+def test_a_block_that_finds_the_lane_free_keeps_the_inline_road(monkeypatch):
+    """One caller, one block, the next only after the verdict: a
+    package of one unit that finds the lane free has no launch to hide
+    its parse behind, and staging it would put two thread hops into
+    every verdict. Under "auto" it is launched as an unpipelined pool
+    launches it."""
+    rig = StagedRig(monkeypatch, parse_s=0.01, launch_s=0.02)
+
+    async def go():
+        pool = BlsDeviceVerifierPool(mesh=rig.mesh, prep_fn=rig.prep_fn)
+        got = [await _submit(pool, _sets(131, tag=i), PriorityClass.GOSSIP_BLOCK) for i in range(3)]
+        stats, metrics = pool.pipeline_stats(), dict(pool.metrics)
+        await pool.close()
+        return got, stats, metrics
+
+    got, stats, metrics = _run(go())
+    assert got == [True] * 3
+    assert stats["pipeline_enabled"] is True and stats["staged_packages"] == 0
+    assert rig.launches == [("grouped", [66, 65])] * 3
+    assert not any(what.startswith("parse") for what, _ in rig.events)
+    assert metrics["parse_ns"] == 0 == metrics["parse_hidden_ns"]
+
+
+@pytest.mark.parametrize(
+    "priority, batchable_share",
+    [(PriorityClass.GOSSIP_BLOCK, 0.0), (PriorityClass.RANGE_SYNC, 0.0), (PriorityClass.GOSSIP_ATTESTATION, 0.5)],
+    ids=["latency-class-packages", "bulk-one-launch-packages", "with-batchable-chunks"],
+)
+def test_staged_replay_launches_the_unpipelined_pools_units_in_its_order(monkeypatch, priority, batchable_share):
+    """Seeded replay, one class, everything queued behind a first
+    launch, a quarter of the jobs planted bad: the launch sequence
+    (units, sizes, order) and every job's verdict are the unpipelined
+    pool's. Only where the parse ran differs."""
+    import random
+
+    def replay(pipeline: str):
+        rng = random.Random(20301)
+        bad = {i for i in range(30) if rng.random() < 0.25}
+        rig = StagedRig(monkeypatch, bad=bad, launch_s=0.005)
+
+        async def go():
+            pool = BlsDeviceVerifierPool(  # a batchable job goes to the queue as it comes
+                mesh=rig.mesh, pipeline=pipeline, prep_fn=rig.prep_fn, max_buffered_sigs=0
+            )
+            futs = [
+                asyncio.ensure_future(_submit(
+                    pool, _sets(rng.choice((8, 66, 65, 100, 128)), tag=i), priority,
+                    batchable=rng.random() < batchable_share,
+                ))
+                for i in range(30)
+            ]
+            got = await asyncio.gather(*futs)
+            stats = pool.pipeline_stats()
+            await pool.close()
+            return got, stats
+
+        got, stats = _run(go())
+        return got, rig.launches, rig.tags, stats, bad
+
+    got, launches, tags, stats, bad = replay("auto")
+    got_off, launches_off, tags_off, stats_off, _ = replay("off")
+    assert got == got_off == [i not in bad for i in range(30)]
+    assert (launches, tags) == (launches_off, tags_off)
+    assert stats["staged_packages"] > 0 == stats_off["staged_packages"]

@@ -7,7 +7,11 @@
   batch k's device verdict stands,
 * close() drains both stages without stranding futures,
 * 1-lane / no-mesh under the default "auto" mode keeps the exact
-  pre-pipeline launch schedule (the PR 8 single-lane equality doctrine),
+  pre-pipeline launch schedule under the split schedule (the PR 8
+  single-lane equality doctrine) and stages the host parse under the
+  single launch, bulk packages too where the mesh cannot shard,
+* an urgent arrival overtakes the package taken ahead, and the
+  `parse_ns` / `parse_hidden_ns` counters say how much was hidden,
 * staged inputs actually reach the lanes' verify_prepared seam, and
 * the --bls-pipeline mode wiring (cli ↔ BeaconNodeOptions ↔ pool).
 """
@@ -16,6 +20,7 @@ from __future__ import annotations
 
 import asyncio
 import random
+import threading
 import time
 
 import pytest
@@ -278,44 +283,353 @@ def test_auto_single_lane_keeps_pre_pipeline_schedule():
     assert stats_auto["prep_ns"] == 0  # the prep stage never ran
 
 
-def test_auto_single_launch_keeps_pre_pipeline_schedule():
-    """Satellite regression (the PR 9 shape, round 13): 1-lane
-    `--bls-single-launch auto` + `--bls-pipeline auto` keeps schedule
-    equality with the pipeline off — zero staged packages, identical
-    launch sequence. On this container single-launch auto resolves OFF
-    (it follows device prep auto, and the Pallas backend is dead), so
-    the default pool must be bit-identical to the pre-single-launch
-    schedule."""
+@pytest.fixture
+def single_launch():
+    """Resolve `--bls-single-launch` as the fixture's user asks: under it
+    the staged prep is host-only, which is what one-lane "auto" follows."""
     from lodestar_tpu.models import batch_verify as bv
 
-    def replay(pipeline: str):
-        rig = FakeLaneRig(1, call_s=0.01, with_sharded=False)
+    prev = bv.configure_single_launch()
+    yield lambda mode: bv.configure_single_launch(mode=mode)
+    bv.configure_single_launch(mode=prev)
 
-        async def go():
-            pool = BlsDeviceVerifierPool(
-                mesh=rig.mesh, scheduler_enabled=True, pipeline=pipeline
-            )
-            assert pool.pipeline_stats()["pipeline_enabled"] is False
-            for i in range(4):
-                assert await pool.verify_signature_sets(
-                    _sets(1, tag=i), VerifySignatureOpts(batchable=False)
+
+def _parse_that_takes_a_while(sets, lane_hint):
+    time.sleep(0.01)
+    return FakeLaneRig.prep_fn(sets, lane_hint)
+
+
+def _queued_behind_a_launch(rig, n: int, priority=PriorityClass.RANGE_SYNC, **pool_kwargs):
+    """`n` one-set jobs of one class, all queued while the first holds
+    the lane (a bulk class: one job a package): (verdicts, pipeline
+    stats, pool metrics)."""
+
+    async def go():
+        pool = BlsDeviceVerifierPool(
+            mesh=rig.mesh, scheduler_enabled=True, prep_fn=_parse_that_takes_a_while, **pool_kwargs
+        )
+        futs = [
+            asyncio.ensure_future(
+                pool.verify_signature_sets(
+                    _sets(1, tag=i), VerifySignatureOpts(batchable=False, priority=priority)
                 )
-            stats = pool.pipeline_stats()
-            await pool.close()
-            return rig.calls, stats
+            )
+            for i in range(n)
+        ]
+        ok = await asyncio.gather(*futs)
+        stats, metrics = pool.pipeline_stats(), dict(pool.metrics)
+        await pool.close()
+        return ok, stats, metrics
 
-        return _run(go())
+    return _run(go())
 
-    prev = bv.configure_single_launch(mode="auto")
+
+def test_auto_single_lane_stages_the_host_parse_under_the_single_launch(single_launch):
+    """One lane, `--bls-pipeline auto`, the single launch active: the
+    staged prep is host-only, so the pool stages the next package while
+    the lane runs this one. The first package finds the lane free and
+    has nothing to hide behind (inline); the rest go through the
+    prepared seam, in the order an unpipelined pool launches them."""
+    single_launch("on")
+    rig = FakeLaneRig(1, call_s=0.03, with_prepared=True, with_sharded=False)
+    ok, stats, metrics = _queued_behind_a_launch(rig, 5)
+    off = FakeLaneRig(1, call_s=0.03, with_prepared=True, with_sharded=False)
+    ok_off, stats_off, metrics_off = _queued_behind_a_launch(off, 5, pipeline="off")
+    assert ok == ok_off == [True] * 5
+    assert rig.calls == off.calls  # identical lane/size launch sequence
+    assert stats["pipeline_enabled"] is True and stats_off["pipeline_enabled"] is False
+    assert stats["staged_packages"] == 4 and len(rig.prepared_calls) == 4
+    assert stats_off["staged_packages"] == 0 and off.prepared_calls == []
+    # the counters the benchmark reads: parse time, and the part of it a launch hid
+    assert 0 < metrics["parse_hidden_ns"] <= metrics["parse_ns"]
+    assert metrics["parse_ns"] == stats["prep_ns"] and metrics["parse_hidden_ns"] == stats["overlap_ns"]
+    assert metrics_off["parse_ns"] == 0 == metrics_off["parse_hidden_ns"]
+
+
+def test_auto_single_lane_split_schedule_stages_nothing(single_launch):
+    """The same pool with the single launch configured off: staged prep
+    would be device launches on the die that verifies, so "auto" stays
+    off, and follows the mode when it flips (read per package)."""
+    single_launch("off")
+    rig = FakeLaneRig(1, call_s=0.01, with_prepared=True, with_sharded=False)
+    ok, stats, metrics = _queued_behind_a_launch(rig, 4)
+    assert ok == [True] * 4
+    assert stats["pipeline_enabled"] is False and stats["staged_packages"] == 0
+    assert rig.prepared_calls == [] and metrics["parse_ns"] == 0
+    single_launch("on")
+    ok, stats, _ = _queued_behind_a_launch(rig, 4)
+    assert ok == [True] * 4 and stats["pipeline_enabled"] is True and stats["staged_packages"] == 3
+
+
+@pytest.mark.parametrize("can_shard", [False, True], ids=["mesh-cannot-shard", "mesh-can-shard"])
+def test_bulk_packages_are_staged_where_the_mesh_cannot_shard(single_launch, can_shard):
+    """A RANGE_SYNC package keeps its inline prep only for the
+    collective road's sake: on a mesh that cannot shard it is staged
+    like any other class."""
+    single_launch("on")
+    rig = FakeLaneRig(1, call_s=0.03, with_prepared=True, with_sharded=can_shard)
+    ok, stats, _ = _queued_behind_a_launch(rig, 4, priority=PriorityClass.RANGE_SYNC)
+    assert ok == [True] * 4 and rig.sharded_calls == []  # one lane never shards; the stage only asks
+    assert stats["staged_packages"] == (0 if can_shard else 3)
+    assert len(rig.prepared_calls) == (0 if can_shard else 3)
+
+
+def _overtaking(staged_class, arriving_class, aging_ms=None, staged_sets=1):
+    """A launch in flight, a package of `staged_class` taken ahead of the
+    lane (one the queue holds all of: a bulk job, or `staged_sets` a full
+    package's worth), and then, during that launch, a job of
+    `arriving_class`: the tags in the order the lane served them (0 in
+    flight, 1 staged, 2 the late arrival)."""
+    rig = FakeLaneRig(1, call_s=0.25, with_prepared=True, with_sharded=False)
+    served = []
+
+    def serve(sets):
+        if served[-1:] != [sets[0].pubkey[1]]:  # a package of several launches reads once
+            served.append(sets[0].pubkey[1])
+        return True
+
+    rig.verdict_fn = serve
+
+    async def go():
+        pool = BlsDeviceVerifierPool(
+            mesh=rig.mesh, scheduler_enabled=True, pipeline="on", prep_fn=FakeLaneRig.prep_fn,
+            **({} if aging_ms is None else {"aging_ms": aging_ms}),
+        )
+
+        def submit(tag, cls, n=1):
+            return asyncio.ensure_future(pool.verify_signature_sets(
+                _sets(n, tag=tag), VerifySignatureOpts(batchable=False, priority=cls)))
+
+        futs = [submit(0, staged_class)]
+        await asyncio.sleep(0.01)  # a package of its own, whatever its class
+        futs.append(submit(1, staged_class, staged_sets))
+        for _ in range(40):  # 0 holds the lane; 1 is in hand, its parse under way or done
+            if pool.pipeline_stats()["staged_packages"] == 2:
+                break
+            await asyncio.sleep(0.005)
+        assert pool.pipeline_stats()["staged_packages"] == 2 and served == []
+        futs.append(submit(2, arriving_class))
+        assert all(await asyncio.gather(*futs))
+        await pool.close()
+
+    _run(go())
+    return served
+
+
+@pytest.mark.parametrize(
+    "staged_class, arriving_class, aging_ms, staged_sets, want",
+    [
+        (PriorityClass.RANGE_SYNC, PriorityClass.GOSSIP_BLOCK, None, 1, [0, 2, 1]),
+        (PriorityClass.RANGE_SYNC, PriorityClass.RANGE_SYNC, None, 1, [0, 1, 2]),
+        (PriorityClass.RANGE_SYNC, PriorityClass.BACKFILL, None, 1, [0, 1, 2]),
+        (PriorityClass.GOSSIP_BLOCK, PriorityClass.GOSSIP_BLOCK, None, 512, [0, 1, 2]),
+        (PriorityClass.API, PriorityClass.GOSSIP_BLOCK, None, 512, [0, 2, 1]),
+        (PriorityClass.RANGE_SYNC, PriorityClass.GOSSIP_BLOCK, 1.0, 1, [0, 1, 2]),
+    ],
+    ids=["urgent-overtakes", "own-class-waits", "less-urgent-waits", "urgent-behind-urgent",
+         "urgent-overtakes-a-full-api-package", "past-the-starvation-bound"],
+)
+def test_an_urgent_arrival_overtakes_the_package_taken_ahead(
+    staged_class, arriving_class, aging_ms, staged_sets, want
+):
+    """The look-ahead must not lengthen what an urgent job waits behind:
+    a GOSSIP_BLOCK that arrives during a bulk launch goes before the
+    bulk package already staged (which keeps its parse and goes after
+    it), as the queue's own order would have had it; a job of the staged
+    package's class or a less urgent one does not, and a package held
+    for the queue's starvation bound is overtaken no more."""
+    assert _overtaking(staged_class, arriving_class, aging_ms, staged_sets) == want
+
+
+def _arrivals_during_a_launch(pipeline: str) -> list:
+    """One attestation starts a launch; one arrives 5 ms into it and
+    nine more during it: the lane's launches, (lane, sets) each."""
+    rig = FakeLaneRig(1, call_s=0.3, with_prepared=True, with_sharded=False)
+
+    async def go():
+        pool = BlsDeviceVerifierPool(
+            mesh=rig.mesh, scheduler_enabled=True, pipeline=pipeline,
+            prep_fn=FakeLaneRig.prep_fn, buffer_wait_ms=1,
+        )
+
+        def submit(tag):
+            return asyncio.ensure_future(pool.verify_signature_sets(
+                _sets(1, tag=tag),
+                VerifySignatureOpts(batchable=True, priority=PriorityClass.GOSSIP_ATTESTATION)))
+
+        futs = [submit(0)]
+        while not rig.mesh.lanes[0].inflight:
+            await asyncio.sleep(0.001)
+        for tag in range(1, 11):
+            await asyncio.sleep(0.005)
+            futs.append(submit(tag))
+        assert all(await asyncio.gather(*futs))
+        await pool.close()
+
+    _run(go())
+    return rig.calls
+
+
+@pytest.mark.parametrize("pipeline", ["auto", "on"])
+def test_arrivals_during_a_launch_ride_the_next_launch_together(single_launch, pipeline):
+    """Open-loop traffic of one class (gossip attestations): what arrives
+    while the lane is busy is ONE package when it frees, staged or not.
+    The dispatcher must not take the first arrival out of the queue
+    alone, ahead of the lane, and leave the rest a launch behind."""
+    single_launch("on")
+    staged = _arrivals_during_a_launch(pipeline)
+    assert staged == _arrivals_during_a_launch("off")
+    assert [n for _lane, n in staged] == [1, 10]
+
+
+def test_a_parse_the_launch_thread_waits_for_is_not_hidden(single_launch):
+    """`parse_hidden_ns` counts parse time under a launch actually
+    dispatched. A two-unit package whose parse (100 ms a unit) outlasts
+    its launches (20 ms): the second parse has the first launch to hide
+    behind and no more, though the launch thread is in the package's
+    verify all the while it waits for the hand-over."""
+    single_launch("on")
+    rig = FakeLaneRig(1, call_s=0.02, with_prepared=True, with_sharded=False)
+
+    def slow_prep(sets, lane_hint):
+        time.sleep(0.1)
+        return FakeLaneRig.prep_fn(sets, lane_hint)
+
+    async def go():
+        pool = BlsDeviceVerifierPool(mesh=rig.mesh, scheduler_enabled=True, prep_fn=slow_prep)
+        futs = [
+            asyncio.ensure_future(pool.verify_signature_sets(
+                _sets(1, tag=i), VerifySignatureOpts(batchable=False)))
+            for i in range(2)
+        ]
+        assert all(await asyncio.gather(*futs))
+        metrics, stats = dict(pool.metrics), pool.pipeline_stats()
+        await pool.close()
+        return metrics, stats
+
+    metrics, stats = _run(go())
+    assert stats["staged_packages"] == 1 and len(rig.prepared_calls) == 2
+    assert metrics["parse_ns"] >= 0.2e9
+    # the first 20 ms launch hides that much of the second parse; the old window, all 100 ms of it
+    assert metrics["parse_hidden_ns"] <= stats["verify_ns"] and metrics["parse_hidden_ns"] < 0.07e9
+
+
+def _formed(cls, sizes, *, grouping=True, can_shard=False, scheduler=True) -> bool:
+    """Whether a queue holding jobs of `sizes` sets, class `cls`, holds
+    all of the package it would give."""
+    from lodestar_tpu.chain.bls.pool import _Job
+
+    rig = FakeLaneRig(1, with_prepared=True, with_sharded=can_shard)
+    rig.mesh.grouping_available = lambda: grouping
+
+    async def go():
+        pool = BlsDeviceVerifierPool(mesh=rig.mesh, scheduler_enabled=scheduler)
+        for i, n in enumerate(sizes):
+            pool._enqueue(_Job(_sets(n, tag=i), False, cls))
+        formed = pool._package_formed()
+        package = (await pool._next_package())[0] if sizes else []
+        await pool.close()
+        return formed, [len(j.sets) for j in package]
+
+    return _run(go())
+
+
+@pytest.mark.parametrize(
+    "cls, sizes, kwargs, formed, package",
+    [
+        (PriorityClass.API, [], {}, False, []),
+        (PriorityClass.API, [128, 128, 128], {}, False, [128, 128, 128]),
+        (PriorityClass.API, [128, 128, 128, 100, 20], {}, False, [128, 128, 128, 100, 20]),
+        (PriorityClass.API, [128, 128, 128, 128, 40], {}, True, [128, 128, 128, 128]),
+        (PriorityClass.GOSSIP_BLOCK, [66, 65] * 4, {}, True, [66, 65] * 4),
+        (PriorityClass.GOSSIP_BLOCK, [66, 65] * 3, {}, False, [66, 65] * 3),
+        (PriorityClass.RANGE_SYNC, [10, 10], {}, True, [10]),
+        (PriorityClass.RANGE_SYNC, [128, 128, 128], {}, False, [128, 128, 128]),
+        (PriorityClass.RANGE_SYNC, [128] * 5, {}, True, [128] * 4),
+        (PriorityClass.RANGE_SYNC, [128, 128, 10, 128], {}, True, [128, 128]),
+        (PriorityClass.RANGE_SYNC, [128, 128], {"grouping": False}, True, [128]),
+        (PriorityClass.BACKFILL, [128, 128], {"can_shard": True}, True, [128]),
+        (PriorityClass.RANGE_SYNC, [128] * 5, {"scheduler": False}, False, [128] * 5),
+    ],
+    ids=["empty", "api-open", "api-open-below-the-cap", "api-full", "block-wave-full",
+         "block-wave-open", "bulk-small-job-alone", "bulk-group-open", "bulk-group-full",
+         "bulk-group-closed-by-a-small-job", "bulk-no-grouping", "bulk-can-shard", "fifo-never"],
+)
+def test_a_package_is_formed_when_no_arrival_can_join_it(cls, sizes, kwargs, formed, package):
+    """`_package_formed()` is what lets the dispatcher take a package
+    ahead of a free lane: true only where `_next_package()` would give
+    the same package however long it waited."""
+    assert _formed(cls, sizes, **kwargs) == (formed, package)
+
+
+def test_staged_hand_over_under_thread_switching_stress(single_launch):
+    """Prep thread, launch threads and the loop hand units to each other
+    under a 10 us switch interval: every job gets its own verdict, every
+    launch went through the staged seam or the inline road and never
+    both, and the hidden parse never exceeds the parse."""
+    import sys
+
+    single_launch("on")
+    rig = FakeLaneRig(2, with_prepared=True, with_sharded=False)
+    rig.verdict_fn = lambda sets: all(s.message[1] != 13 for s in sets)
+    rng = random.Random(7)
+    tags = [13 if rng.random() < 0.2 else i % 11 for i in range(240)]
+    classes = [rng.choice(list(PriorityClass)) for _ in tags]
+
+    async def go():
+        pool = BlsDeviceVerifierPool(mesh=rig.mesh, scheduler_enabled=True, prep_fn=FakeLaneRig.prep_fn)
+        futs = []
+        for i, (tag, cls) in enumerate(zip(tags, classes)):
+            futs.append(asyncio.ensure_future(pool.verify_signature_sets(
+                _sets(1 + i % 3, tag=tag), VerifySignatureOpts(batchable=i % 4 == 0, priority=cls))))
+            if i % 16 == 15:
+                await asyncio.sleep(0.001)  # arrivals while packages are in hand and in flight
+        got = await asyncio.wait_for(asyncio.gather(*futs), timeout=60)
+        metrics, stats = dict(pool.metrics), pool.pipeline_stats()
+        await pool.close()
+        return got, metrics, stats
+
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
     try:
-        assert bv.single_launch_active() is False  # auto = off without Pallas
-        calls_auto, stats_auto = replay("auto")
-        calls_off, stats_off = replay("off")
+        got, metrics, stats = _run(go())
     finally:
-        bv.configure_single_launch(mode=prev)
-    assert calls_auto == calls_off
-    assert stats_auto["staged_packages"] == 0 == stats_off["staged_packages"]
-    assert stats_auto["prep_ns"] == 0
+        sys.setswitchinterval(prev)
+    assert got == [tag != 13 for tag in tags]
+    assert stats["staged_packages"] > 0 and rig.prepared_calls
+    assert 0 <= metrics["parse_hidden_ns"] <= metrics["parse_ns"]
+    assert metrics["jobs_started"] == len(tags) and metrics["errors"] == 0
+
+
+def test_close_with_a_unit_half_staged_strands_no_future(single_launch):
+    """close() while the package in hand is still in its parse: its
+    futures fail, the launch in flight resolves, nothing is left."""
+    single_launch("on")
+    rig = FakeLaneRig(1, call_s=0.1, with_prepared=True, with_sharded=False)
+    parsing = threading.Event()
+
+    def slow_prep(sets, lane_hint):
+        parsing.set()
+        time.sleep(0.15)
+        return FakeLaneRig.prep_fn(sets, lane_hint)
+
+    async def go():
+        pool = BlsDeviceVerifierPool(mesh=rig.mesh, scheduler_enabled=True, prep_fn=slow_prep)
+        futs = [
+            asyncio.ensure_future(pool.verify_signature_sets(
+                _sets(1, tag=i), VerifySignatureOpts(batchable=False, priority=PriorityClass.RANGE_SYNC)))
+            for i in range(4)
+        ]
+        while not parsing.is_set():
+            await asyncio.sleep(0.005)
+        await pool.close()
+        results = await asyncio.wait_for(asyncio.gather(*futs, return_exceptions=True), timeout=5)
+        return futs, results
+
+    futs, results = _run(go())
+    assert all(f.done() for f in futs)
+    assert len(rig.calls) == 1 and rig.prepared_calls == []  # the launch in flight; nothing staged was launched
+    assert all(isinstance(r, asyncio.CancelledError) for r in results[1:]), results
 
 
 # -- mode wiring ---------------------------------------------------------------
@@ -389,11 +703,11 @@ def test_mesh_launch_drops_staged_inputs_on_cross_lane_retry():
     assert calls == ["l0-prepared", "l1-plain"]
 
 
-def test_dead_dispatch_stage_restarts_on_next_submit():
-    """A dead verify dispatcher (stage 2) with a live staging loop must
-    self-heal on the next submit instead of filling the 1-deep queue
-    and hanging every later verify."""
-    rig = FakeLaneRig(1, with_prepared=True, with_sharded=False)
+def test_dead_dispatcher_restarts_on_next_submit_and_fails_what_it_held():
+    """A dispatcher that dies with a package in hand fails that
+    package's futures (nobody else can see it) and the next submit
+    starts a new one."""
+    rig = FakeLaneRig(1, call_s=0.3, with_prepared=True, with_sharded=False)
 
     async def go():
         pool = BlsDeviceVerifierPool(
@@ -402,19 +716,24 @@ def test_dead_dispatch_stage_restarts_on_next_submit():
             pipeline="on",
             prep_fn=FakeLaneRig.prep_fn,
         )
-        assert await pool.verify_signature_sets(
-            _sets(1), VerifySignatureOpts(batchable=False)
-        )
-        pool._verify_runner.cancel()
-        await asyncio.sleep(0)  # let the cancellation land
-        assert pool._verify_runner.done()
+        futs = [
+            asyncio.ensure_future(pool.verify_signature_sets(
+                _sets(1, tag=i), VerifySignatureOpts(batchable=False, priority=PriorityClass.RANGE_SYNC)))
+            for i in range(2)
+        ]
+        await asyncio.sleep(0.02)  # 0 in flight, 1 in the dispatcher's hand
+        pool._runner.cancel()
+        first = await asyncio.gather(*futs, return_exceptions=True)
+        assert pool._runner.done()
         ok = await pool.verify_signature_sets(
-            _sets(1, tag=1), VerifySignatureOpts(batchable=False)
+            _sets(1, tag=2), VerifySignatureOpts(batchable=False)
         )
         await pool.close()
-        return ok
+        return first, ok
 
-    assert _run(go()) is True
+    first, ok = _run(go())
+    assert first[0] is True and isinstance(first[1], asyncio.CancelledError)
+    assert ok is True
 
 
 # -- live pipeline gauges (lodestar_bls_pipeline_*) ----------------------------

@@ -58,11 +58,22 @@ def test_no_reconnect_while_verifications_outstanding():
         reconnects.append(ep.target)
         orig_reconnect(self, ep)
 
+    orig_probe_one = BlsOffloadClient._probe_one
+    in_flight = []
+
+    def probe_with_an_rpc_in_flight(self, ep):
+        # on the probe thread, ahead of its first probe: set from the test's
+        # thread, the first failed probe's redial could win the race
+        if not in_flight:
+            in_flight.append(ep)
+            with self._lock:
+                ep.outstanding = 1  # simulate an in-flight RPC
+        return orig_probe_one(self, ep)
+
     BlsOffloadClient._reconnect = spy_reconnect
+    BlsOffloadClient._probe_one = probe_with_an_rpc_in_flight
     try:
         client = BlsOffloadClient(DEAD_TARGET, probe_interval_s=0.05)
-        with client._lock:
-            client._endpoints[0].outstanding = 1  # simulate an in-flight RPC
         ep = client._endpoints[0]
         # two failed probes are ~0.5 s apart on an idle machine (the first
         # backoff step) and later on a loaded one: wait for them, not for a
@@ -81,6 +92,7 @@ def test_no_reconnect_while_verifications_outstanding():
         assert len(reconnects) >= 1  # resumed once the work drained
     finally:
         BlsOffloadClient._reconnect = orig_reconnect
+        BlsOffloadClient._probe_one = orig_probe_one
         asyncio.run(client.close())
 
 

@@ -210,18 +210,59 @@ def test_bulk_classes_cover_sync_paths():
     )
 
 
-def test_peek_shows_the_class_head_and_moves_nothing():
-    """`peek(cls)` is the item `get_nowait(cls)` would pop: no pop, no
-    fairness accounting, no metric."""
+def test_queued_shows_the_class_in_dequeue_order_and_moves_nothing():
+    """`queued(cls)` is what `get_nowait(cls)` would pop, in order: no
+    pop, no fairness accounting, no metric."""
     q = PriorityWorkQueue()
-    assert q.peek(PriorityClass.BACKFILL) is None
+    assert list(q.queued(PriorityClass.BACKFILL)) == []
     q.put_nowait("b0", PriorityClass.BACKFILL)
     q.put_nowait("b1", PriorityClass.BACKFILL)
     q.put_nowait("g0", PriorityClass.GOSSIP_BLOCK)
     before = q.stats()
-    assert q.peek(PriorityClass.BACKFILL) == "b0"
-    assert q.peek(PriorityClass.GOSSIP_BLOCK) == "g0"
-    assert q.peek(PriorityClass.API) is None
+    assert list(q.queued(PriorityClass.BACKFILL)) == ["b0", "b1"]
+    assert list(q.queued(PriorityClass.GOSSIP_BLOCK)) == ["g0"]
+    assert list(q.queued(PriorityClass.API)) == []
     assert q.stats() == before and len(q) == 3
     assert q.get_nowait(PriorityClass.BACKFILL)[0] == "b0"
-    assert q.peek(PriorityClass.BACKFILL) == "b1"
+    assert list(q.queued(PriorityClass.BACKFILL)) == ["b1"]
+
+
+@pytest.mark.parametrize("fifo", [False, True], ids=["scheduler", "fifo"])
+def test_next_class_is_the_class_the_next_dequeue_serves_and_moves_nothing(fifo):
+    """`next_class()` answers by the same stride and aging order as
+    `get_nowait()`, through a saturated mix with an aged backlog, and
+    leaves the fairness accounting, the promotion counter and the
+    alternation of promotions where they were."""
+    clock = FakeNs()
+    q = PriorityWorkQueue(aging_ms=100.0, fifo=fifo, time_fn=clock)
+    assert q.next_class() is None
+    for i in range(6):
+        q.put_nowait(f"bf{i}", PriorityClass.BACKFILL)
+        clock.advance_ms(1)
+        q.put_nowait(f"rs{i}", PriorityClass.RANGE_SYNC)
+    clock.advance_ms(150)  # the bulk backlog is past the aging window
+    for i in range(6):
+        q.put_nowait(f"block{i}", PriorityClass.GOSSIP_BLOCK)
+        clock.advance_ms(1)
+    served = []
+    while len(q):
+        before = q.stats()
+        said = q.next_class()
+        assert q.next_class() is said and q.stats() == before
+        served.append(q.get_nowait()[1])
+        assert served[-1] is said
+    assert len(served) == 18 and set(served) == {
+        PriorityClass.BACKFILL, PriorityClass.RANGE_SYNC, PriorityClass.GOSSIP_BLOCK}
+    assert (q.starvation_promotions > 0) is not fifo
+    assert q.next_class() is None
+
+
+def test_aged_holds_taken_out_work_to_the_queues_starvation_bound():
+    clock = FakeNs()
+    q = PriorityWorkQueue(aging_ms=100.0, time_fn=clock)
+    taken = clock()
+    assert not q.aged(taken)
+    clock.advance_ms(99)
+    assert not q.aged(taken)
+    clock.advance_ms(1)
+    assert q.aged(taken)
