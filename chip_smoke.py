@@ -198,8 +198,7 @@ def phase_programs(batches: dict, oracle) -> dict:
     """Every default verify program against the oracle (ROADMAP S1 gate)."""
     from lodestar_tpu.models import batch_verify as bv
 
-    check(bv.single_launch_active(), "single launch does not resolve active by default here")
-    check(bv.device_prep_active(), "device prep does not resolve active by default here")
+    check(bv.single_launch_active(), "this backend does not run the single-launch schedule")
     programs = {
         "single_launch": bv.verify_sets_single_launch,
         "staged_fused_prep": bv._verify_sets_split,

@@ -15,12 +15,15 @@ drove one chip. This module is the mesh's serving shape:
   occupancy over *available* chips and the per-chip table (a wedged
   chip drops out of the advertised capacity).
 * `build_device_mesh` — production construction from the models layer's
-  device enumeration. `"auto"` engages only when the backend is a TPU
-  AND more than one device is visible (the same doctrine as
-  `--bls-device-prep auto`): on the CPU-forced 8-device test platform
-  auto stays single-lane, so a default pool behaves exactly like the
-  pre-mesh code unless a test asks for the mesh explicitly. A backend
-  that cannot initialise raises — it is never read as "one CPU lane".
+  device enumeration, and the one place a production lane is made: the
+  models layer says which verify schedule the backend runs, and the
+  lanes carry the answer (what they can take, and whether prep staged
+  for them touches a device). `"auto"` engages only when the backend is
+  a TPU AND more than one device is visible: on the CPU-forced 8-device
+  test platform auto stays single-lane, so a default pool behaves
+  exactly like the pre-mesh code unless a test asks for the mesh
+  explicitly. A backend that cannot initialise raises — it is never
+  read as "one CPU lane".
 
 Placement policy lives in the pool (`chain/bls/pool.py`): latency-class
 work dequeues to the least-occupied free lane; bulk work shards across
@@ -101,15 +104,14 @@ class MeshLane:
     themselves run on executor threads. `verify_prepared_fn` (optional)
     verifies a `PreparedSets.inputs` staged by the pipelined pool's
     prep stage (either staged shape — see models verify_prepared);
-    lanes without one always re-prep inline. `verify_single_fn`
-    (optional) is the lane-pinned single-launch entry
-    (models `make_lane_verify_single_fn`): `mesh_launch` prefers it for
-    unstaged work while `--bls-single-launch` resolves active, so a
-    whole batch is one resident program on this lane's die.
-    `verify_grouped_fn` (optional) is the multi-job entry (models
+    lanes without one always re-prep inline. `verify_grouped_fn`
+    (optional) is the multi-job entry (models
     `make_lane_verify_grouped_fn`): a list of jobs' sets in, ONE launch,
     a list of verdicts out; the pool forms multi-job units only where
-    every lane has one."""
+    every lane has one. `staged_prep_host_only` is a fact fixed here,
+    by whoever built the lane: prep staged for `verify_prepared_fn`
+    touches no device (the single launch's host byte parse), so it can
+    be hidden behind a launch on this lane's own die."""
 
     def __init__(
         self,
@@ -120,8 +122,8 @@ class MeshLane:
         wedge_threshold: int = LANE_WEDGE_THRESHOLD,
         wedge_reset_s: float = LANE_WEDGE_RESET_S,
         verify_prepared_fn: Callable | None = None,
-        verify_single_fn: Callable | None = None,
         verify_grouped_fn: Callable | None = None,
+        staged_prep_host_only: bool = False,
     ) -> None:
         from lodestar_tpu.offload.resilience import CircuitBreaker
 
@@ -129,8 +131,8 @@ class MeshLane:
         self.label = label if label is not None else f"dev{index}"
         self.verify_fn = verify_fn
         self.verify_prepared_fn = verify_prepared_fn
-        self.verify_single_fn = verify_single_fn
         self.verify_grouped_fn = verify_grouped_fn
+        self.staged_prep_host_only = staged_prep_host_only
         self.occupancy = OccupancyTracker()
         self.breaker = CircuitBreaker(
             failure_threshold=wedge_threshold,
@@ -196,21 +198,17 @@ class VerifierMesh:
 
     def grouping_available(self) -> bool:
         """Whether a multi-job unit is ONE launch on whichever lane
-        serves it: every lane has the grouped entry and
-        `--bls-single-launch` resolves active (the grouped program is
-        the single-launch program with a slot a job)."""
-        return (
-            all(lane.verify_grouped_fn is not None for lane in self.lanes)
-            and _single_launch_active()
-        )
+        serves it: every lane has the grouped entry (the single-launch
+        program with a slot a job; a lane of the split schedule is
+        built without one)."""
+        return all(lane.verify_grouped_fn is not None for lane in self.lanes)
 
     def staged_prep_is_host_only(self) -> bool:
         """Whether prep staged ahead of a launch touches no device: every
-        lane takes staged inputs and `--bls-single-launch` resolves
-        active, so what is staged is the host byte parse."""
-        return (
-            all(lane.verify_prepared_fn is not None for lane in self.lanes)
-            and _single_launch_active()
+        lane takes staged inputs and says so of them."""
+        return all(
+            lane.verify_prepared_fn is not None and lane.staged_prep_host_only
+            for lane in self.lanes
         )
 
     def occupancy(self) -> float:
@@ -230,15 +228,6 @@ class VerifierMesh:
 
     def lane_states(self) -> list[dict]:
         return [lane.state() for lane in self.lanes]
-
-
-def _single_launch_active() -> bool:
-    """Whether `--bls-single-launch` resolves active right now. Only
-    consulted when a lane carries a `verify_single_fn` (which came from
-    the models layer), so mock-lane meshes never pay the import."""
-    from lodestar_tpu.models.batch_verify import single_launch_active
-
-    return single_launch_active()
 
 
 def mesh_launch(
@@ -319,16 +308,6 @@ def mesh_launch(
                         ok = current.verify_prepared_fn(prepared.inputs)
                     elif grouped:
                         ok = current.verify_grouped_fn(sets)
-                    elif (
-                        current.verify_single_fn is not None
-                        and _single_launch_active()
-                    ):
-                        # lane-pinned single-launch road (one resident
-                        # program per batch); its single→split degradation
-                        # lives in the model layer, so an error here means
-                        # even the split schedule failed on this lane — the
-                        # same breaker/cross-lane semantics as verify_fn
-                        ok = bool(current.verify_single_fn(sets))
                     else:
                         ok = bool(current.verify_fn(sets))
                     ok = [bool(v) for v in ok] if grouped else bool(ok)
@@ -360,27 +339,10 @@ def mesh_launch(
         return ok, current
 
 
-def single_lane_mesh(
-    verify_fn: Callable,
-    *,
-    wedge_threshold: int = LANE_WEDGE_THRESHOLD,
-    verify_prepared_fn: Callable | None = None,
-    verify_single_fn: Callable | None = None,
-    verify_grouped_fn: Callable | None = None,
-) -> VerifierMesh:
-    """The pre-mesh shape: one lane, no sharded collective."""
-    return VerifierMesh(
-        [
-            MeshLane(
-                0,
-                verify_fn,
-                wedge_threshold=wedge_threshold,
-                verify_prepared_fn=verify_prepared_fn,
-                verify_single_fn=verify_single_fn,
-                verify_grouped_fn=verify_grouped_fn,
-            )
-        ]
-    )
+def single_lane_mesh(verify_fn: Callable, **lane_kwargs) -> VerifierMesh:
+    """The pre-mesh shape: one lane (`MeshLane`'s keywords), no sharded
+    collective."""
+    return VerifierMesh([MeshLane(0, verify_fn, **lane_kwargs)])
 
 
 def build_device_mesh(
@@ -394,24 +356,44 @@ def build_device_mesh(
     mode "off" (or a single visible device) yields the single-lane
     shape around `fallback_verify_fn` (default:
     `verify_signature_sets_device`) — bit-identical to the pre-mesh
-    pool. mode "auto" requires the TPU backend (same doctrine as device
-    prep auto); mode "on" forces the mesh whenever more than one device
-    is visible. Import and backend-initialisation errors propagate: the
-    caller asked for a device verifier, and a host whose chip is taken
-    must say so instead of serving something else unseen."""
+    pool. mode "auto" requires the TPU backend; mode "on" forces the
+    mesh whenever more than one device is visible. Import and
+    backend-initialisation errors propagate: the caller asked for a
+    device verifier, and a host whose chip is taken must say so instead
+    of serving something else unseen.
+
+    The verify schedule is asked of the models layer HERE, once a mesh,
+    and the lanes carry it: where the backend runs the single launch they
+    take multi-job units and their staged prep is the host byte parse;
+    where it runs the split schedule they have no grouped entry and
+    their staged prep is device work."""
     if mode not in MESH_MODES:
         raise ValueError(f"bls_mesh must be one of {MESH_MODES}, got {mode!r}")
     from lodestar_tpu.models import batch_verify as bv
 
+    def _lanes(entries) -> list[MeshLane]:
+        """Lanes over (verify_fn, verify_prepared_fn, verify_grouped_fn)
+        entries, one a device, with the backend's schedule as facts."""
+        single_launch = bv.single_launch_active()
+        return [
+            MeshLane(
+                index,
+                verify_fn,
+                wedge_threshold=wedge_threshold,
+                verify_prepared_fn=verify_prepared_fn,
+                verify_grouped_fn=verify_grouped_fn if single_launch else None,
+                staged_prep_host_only=single_launch,
+            )
+            for index, (verify_fn, verify_prepared_fn, verify_grouped_fn) in enumerate(entries)
+        ]
+
     def _single() -> VerifierMesh:
         if fallback_verify_fn is not None:
             return single_lane_mesh(fallback_verify_fn, wedge_threshold=wedge_threshold)
-        return single_lane_mesh(
-            bv.verify_signature_sets_device,
-            wedge_threshold=wedge_threshold,
-            verify_prepared_fn=bv.verify_prepared,
-            verify_single_fn=bv.verify_sets_single_launch,
-            verify_grouped_fn=bv.verify_sets_grouped_launch,
+        return VerifierMesh(
+            _lanes(
+                [(bv.verify_signature_sets_device, bv.verify_prepared, bv.verify_sets_grouped_launch)]
+            )
         )
 
     if mode == "off":
@@ -424,15 +406,14 @@ def build_device_mesh(
     n = bv.mesh_device_count()
     if n <= 1:
         return _single()
-    lanes = [
-        MeshLane(
-            i,
-            bv.make_lane_verify_fn(i),
-            wedge_threshold=wedge_threshold,
-            verify_prepared_fn=bv.make_lane_verify_prepared_fn(i),
-            verify_single_fn=bv.make_lane_verify_single_fn(i),
-            verify_grouped_fn=bv.make_lane_verify_grouped_fn(i),
-        )
-        for i in range(n)
-    ]
+    lanes = _lanes(
+        [
+            (
+                bv.make_lane_verify_fn(i),
+                bv.make_lane_verify_prepared_fn(i),
+                bv.make_lane_verify_grouped_fn(i),
+            )
+            for i in range(n)
+        ]
+    )
     return VerifierMesh(lanes, sharded_fn=bv.make_mesh_sharded_fn())

@@ -35,13 +35,19 @@ N-worker thread pool replaced by one device pipeline:
   data-parallel (`verify_signature_sets_sharded`) across the idle chips.
   With a single visible device the mesh is one lane and the launch
   schedule is bit-identical to the pre-mesh pool (regression-tested).
-* **Staged prep** (`PIPELINE_MODES`): where it can be hidden, a
-  package's prep — under the single launch the host byte parse — runs
-  on another thread, launch unit by launch unit, while the device runs
-  the unit before. The dispatcher takes the next package while the
-  lanes are busy only once the queue holds all of it (nothing that
-  arrives later could join it, so no launch's composition changes);
-  an urgent arrival still overtakes the package taken ahead.
+* **Staged prep** (`_staging`): where it can be hidden, a package's
+  prep — under the single launch the host byte parse — runs on another
+  thread, launch unit by launch unit, while the device runs the unit
+  before: the mesh has a sibling lane to stage prep on, or — one lane —
+  the lanes say their staged prep touches no device. One lane under
+  the split schedule keeps the exact pre-pipeline launch schedule:
+  staged prep there is device launches on the die that verifies. A
+  package that finds a lane free and is one launch unit has no launch
+  to hide its prep behind and takes the inline road. The dispatcher
+  takes the next package while the lanes are busy only once the queue
+  holds all of it (nothing that arrives later could join it, so no
+  launch's composition changes); an urgent arrival still overtakes the
+  package taken ahead.
 * **Wedge detection** (`offload/resilience.CircuitBreaker`): each lane
   carries its OWN wedge breaker — consecutive launch errors on a chip
   open it, the dispatcher stops placing work there, and in-flight work
@@ -63,8 +69,8 @@ N-worker thread pool replaced by one device pipeline:
   server ships to clients. `scheduler_enabled=False` restores arrival
   order (the control arm for the saturation tests).
 
-The verify backend is injected as a callable (default: the device model
-`models.batch_verify.verify_signature_sets_device`), which keeps the seam
+The verify backend is injected as a callable (default: the lanes
+`mesh.build_device_mesh` makes of the device model), which keeps the seam
 mockable and lets tests drive the retry paths deterministically; passing
 an explicit callable pins the pool to a single lane (a mock cannot be
 enumerated per device). Tests inject multi-lane topologies via `mesh=`.
@@ -110,25 +116,7 @@ __all__ = [
     "MAX_BUFFER_WAIT_MS",
     "MAX_JOBS_CAN_ACCEPT_WORK",
     "BATCHABLE_MIN_PER_CHUNK",
-    "PIPELINE_MODES",
 ]
-
-#: prep→verify pipeline modes (--bls-pipeline): while a lane runs launch
-#: unit k, the prep of the unit it runs next (the package's next unit,
-#: else the next package's first) is under way on another thread.
-#: "auto" stages where that buys something: the mesh has a sibling lane
-#: to stage prep on, or — one lane — the staged prep is host-only, i.e.
-#: the lanes take staged inputs and --bls-single-launch resolves active
-#: (byte parse, xmd and blinding of k+1 against the ONE launch of k).
-#: One lane under the split schedule keeps the exact pre-pipeline launch
-#: schedule: staged prep there is device launches on the die that
-#: verifies. A package that finds a lane free and is one launch unit
-#: has no launch to hide its prep behind and takes the inline road.
-#: "on" stages every package, on one chip under either schedule; "off"
-#: keeps prep inline with the launch. Wherever packages are staged, one
-#: is taken ahead of a free lane only when the queue already holds all
-#: of it: an open one stays queued, where arrivals still join it.
-PIPELINE_MODES = ("auto", "on", "off")
 
 # tuning constants — same values/rationale as the reference (index.ts:30-62)
 MAX_SIGNATURE_SETS_PER_JOB = 128
@@ -329,80 +317,41 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         sched_metrics=None,
         mesh: VerifierMesh | None = None,
         mesh_mode: str | None = None,
-        pipeline: str = "auto",
         prep_fn: Callable | None = None,
         pipeline_metrics=None,
     ) -> None:
-        explicit_fn = verify_fn is not None
-        if verify_fn is None:
-            from lodestar_tpu.models.batch_verify import verify_signature_sets_device
-
-            verify_fn = verify_signature_sets_device
-        self._verify_fn = verify_fn
         self._buffer_wait_ms = buffer_wait_ms
         self._max_buffered_sigs = max_buffered_sigs
         self._log = get_logger(name="lodestar.bls-pool")
 
         # mesh construction: an injected mesh wins (tests/topologies);
-        # a mesh_mode builds from the device enumeration unless the
-        # caller pinned an explicit verify_fn (a mock can't be
-        # enumerated per device); default is the single-lane pre-mesh
-        # shape around verify_fn
+        # an explicit verify_fn is ONE lane that only speaks sets (a
+        # mock can't be enumerated per device, and mesh_launch re-preps
+        # inline through it); otherwise the lanes are the device
+        # model's, from the one place they are made
         if mesh is not None:
             self.mesh = mesh
         elif mesh_mode is not None and mesh_mode not in MESH_MODES:
             raise ValueError(f"bls_mesh must be one of {MESH_MODES}, got {mesh_mode!r}")
-        elif mesh_mode in ("auto", "on") and not explicit_fn:
-            self.mesh = build_device_mesh(
-                mesh_mode, wedge_threshold=DEVICE_WEDGE_THRESHOLD
-            )
+        elif verify_fn is not None:
+            self.mesh = single_lane_mesh(verify_fn, wedge_threshold=DEVICE_WEDGE_THRESHOLD)
         else:
-            prepared_fn = None
-            single_fn = None
-            grouped_fn = None
-            if not explicit_fn:
-                # the default backend can verify staged inputs directly
-                # and serve the single-launch road; an injected mock
-                # only speaks sets, so its lane leaves both seams unset
-                # and mesh_launch re-preps inline through the mock
-                from lodestar_tpu.models.batch_verify import (
-                    verify_prepared,
-                    verify_sets_grouped_launch,
-                    verify_sets_single_launch,
-                )
-
-                prepared_fn = verify_prepared
-                single_fn = verify_sets_single_launch
-                grouped_fn = verify_sets_grouped_launch
-            self.mesh = single_lane_mesh(
-                verify_fn,
-                wedge_threshold=DEVICE_WEDGE_THRESHOLD,
-                verify_prepared_fn=prepared_fn,
-                verify_single_fn=single_fn,
-                verify_grouped_fn=grouped_fn,
+            self.mesh = build_device_mesh(
+                mesh_mode or "off", wedge_threshold=DEVICE_WEDGE_THRESHOLD
             )
 
-        # prep→verify double buffering (PIPELINE_MODES). Staging requires
-        # lanes that can CONSUME staged inputs (or an injected prep_fn):
-        # a mesh of plain verify callables would pay real prep for
-        # inputs nobody uses — and a prep-stage structural reject would
-        # overrule a backend that never saw the sets. Whether "auto"
-        # engages on one lane follows the schedule, which is resolved
-        # where packages are staged (`_staging`): a pool may be built
-        # before, and tests flip the mode around calls
-        if pipeline not in PIPELINE_MODES:
-            raise ValueError(
-                f"bls_pipeline must be one of {PIPELINE_MODES}, got {pipeline!r}"
-            )
-        self.pipeline_mode = pipeline
-        self._stageable = prep_fn is not None or all(
-            lane.verify_prepared_fn is not None for lane in self.mesh.lanes
-        )
-        if pipeline == "on" and not self._stageable:
-            self._log.warn(
-                "bls pipeline forced on but no lane can verify staged inputs; "
-                "running unpipelined"
-            )
+        # prep→verify double buffering: whether packages' prep is
+        # staged. Staging requires lanes that can CONSUME staged inputs
+        # (or an injected prep_fn): a mesh of plain verify callables
+        # would pay real prep for inputs nobody uses — and a prep-stage
+        # structural reject would overrule a backend that never saw the
+        # sets. And it must have somewhere to hide: a sibling lane to
+        # stage on or — one lane — staged prep that touches no device.
+        # All facts the lanes were built with, so read once
+        self._staging = (
+            prep_fn is not None
+            or all(lane.verify_prepared_fn is not None for lane in self.mesh.lanes)
+        ) and (len(self.mesh) > 1 or self.mesh.staged_prep_is_host_only())
         self._prep_fn = prep_fn if prep_fn is not None else self._default_prep_fn
         self._overlap = _OverlapTracker()
         self._staged_packages = 0  # guarded by: advisory-only (monotonic count, prep threads under the GIL)
@@ -790,7 +739,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             while not self._closed:
                 if package is None:
                     while not (
-                        self._free_lanes() or (self._staging() and self._package_formed())
+                        self._free_lanes() or (self._staging and self._package_formed())
                     ):
                         self._wake.clear()
                         await self._wake.wait()
@@ -798,7 +747,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                             return
                     package, cls = await self._next_package()
                     taken_ns = time.monotonic_ns()
-                    prepped = self._stage(package, cls) if self._staging() else None
+                    prepped = self._stage(package, cls) if self._staging else None
                 if not self._free_lanes():
                     await self._wait_free_lane()
                     if self._closed:
@@ -833,27 +782,17 @@ class BlsDeviceVerifierPool(IBlsVerifier):
 
     # -- prep→verify pipeline ---------------------------------------------------
 
-    def _staging(self) -> bool:
-        """Whether packages' prep is staged (PIPELINE_MODES): what "auto"
-        follows is read here, per package, not where the pool was built."""
-        if not self._stageable or self.pipeline_mode == "off":
-            return False
-        if self.pipeline_mode == "on" or len(self.mesh) > 1:
-            return True
-        # one lane: only where the staged prep touches no device
-        return self.mesh.staged_prep_is_host_only()
-
     def _stage(self, package: list[_Job], cls: PriorityClass) -> _PreppedPackage | None:
         """Form the package's launch units and hand their prep to an
         executor thread; None where the package keeps its inline prep: a
         bulk package on a mesh that can shard (the collective launch
-        preps inline), and unless forced "on" a package of one launch
-        unit that finds a lane free (no launch to hide its prep behind,
-        so staging would only add two thread hops to its verdict)."""
+        preps inline), and a package of one launch unit that finds a
+        lane free (no launch to hide its prep behind, so staging would
+        only add two thread hops to its verdict)."""
         if self.scheduler_enabled and cls in BULK_CLASSES and self.mesh.sharding_available():
             return None
         chunks, units = _launch_units(package, self.mesh.grouping_available())
-        if self.pipeline_mode != "on" and len(chunks) + len(units) == 1 and self._free_lanes():
+        if len(chunks) + len(units) == 1 and self._free_lanes():
             return None
         prepped = _PreppedPackage(
             [_PrepUnit(chunk, [s for j in chunk for s in j.sets]) for chunk in chunks],
@@ -930,7 +869,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         overlap, the overlap share of verify time, and the staged
         package count (0 = pipeline never engaged). The device path per
         batch is either the split schedule (3-launch fused prep + the
-        RLC verify dispatch) or, under --bls-single-launch, ONE
+        RLC verify dispatch) or, on an accelerator, ONE
         resident program — in which case the prep accumulator measures
         the staged host byte-parse and the verify accumulator the
         single launch."""
@@ -938,7 +877,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         v = s["verify_ns"]
         s["overlap_occupancy_pct"] = (100.0 * s["overlap_ns"] / v) if v else 0.0
         s["staged_packages"] = self._staged_packages
-        s["pipeline_enabled"] = self._staging()
+        s["pipeline_enabled"] = self._staging
         return s
 
     def _release_lanes_early(self, to_release: list[MeshLane], held: list[MeshLane]) -> None:

@@ -86,37 +86,6 @@ def _add_scheduler_args(sp) -> None:
         "debug/comparison only)",
     )
     sp.add_argument(
-        "--bls-device-prep", choices=["auto", "on", "off"], default="auto",
-        help="run batch-verify input prep (G1/G2 decompression, subgroup "
-        "checks, hash-to-G2) on the device: auto = only when the Pallas "
-        "backend is live, on = always, off = host prep (native C++ / "
-        "python oracle). Device-prep errors fall back to host prep.",
-    )
-    sp.add_argument(
-        "--bls-pipeline", choices=["auto", "on", "off"], default="auto",
-        help="double-buffer the BLS prep→verify pipeline: stage input prep "
-        "of the next launch while this one verifies (auto = when the mesh "
-        "has a sibling lane to prep on, or on one lane when the staged "
-        "prep is the host byte parse of --bls-single-launch; on = every "
-        "package, on one chip under either schedule; off = prep inline "
-        "with the launch). Verdicts, priority placement, and the "
-        "fail-closed degradation chain are unchanged.",
-    )
-    sp.add_argument(
-        # literal copy of models.batch_verify.SINGLE_LAUNCH_MODES
-        # (argparse-import doctrine: BeaconNodeOptions re-validates
-        # against the canonical tuple post-parse)
-        "--bls-single-launch", choices=["auto", "on", "off"], default="auto",
-        help="verify each BLS batch as ONE resident device program "
-        "(decompression, subgroup checks, hash-to-G2, RLC aggregation, "
-        "Miller loop, final exponentiation in a single counted "
-        "dispatch): auto = when the accelerator backend is live "
-        "(unless --bls-device-prep is pinned off), on = always, off = "
-        "the split prep-then-verify schedule. Single-"
-        "launch errors degrade per batch to the split schedule, then "
-        "host prep.",
-    )
-    sp.add_argument(
         "--htr-device", choices=["auto", "on", "off"], default="auto",
         help="flush state hashTreeRoot dirty subtrees through the device "
         "SHA-256 kernel (one batched launch per tree level): auto = when "
@@ -417,9 +386,6 @@ async def _run_dev(args) -> int:
             offload_quarantine_cooloff_s=args.offload_quarantine_sec,
             offload_unquarantine=args.offload_unquarantine,
             scheduler_enabled=not args.sched_disable,
-            bls_device_prep=args.bls_device_prep,
-            bls_pipeline=args.bls_pipeline,
-            bls_single_launch=args.bls_single_launch,
             htr_device=args.htr_device,
             bls_mesh=args.bls_mesh,
             offload_tenant=args.offload_tenant,
@@ -591,9 +557,6 @@ async def _run_beacon(args) -> int:
             offload_quarantine_cooloff_s=args.offload_quarantine_sec,
             offload_unquarantine=args.offload_unquarantine,
             scheduler_enabled=not args.sched_disable,
-            bls_device_prep=args.bls_device_prep,
-            bls_pipeline=args.bls_pipeline,
-            bls_single_launch=args.bls_single_launch,
             htr_device=args.htr_device,
             bls_mesh=args.bls_mesh,
             offload_tenant=args.offload_tenant,

@@ -50,13 +50,10 @@ from lodestar_tpu.ops import tower as tw
 
 __all__ = [
     "COEFF_BITS",
-    "SINGLE_LAUNCH_MODES",
     "SingleLaunchInputs",
     "GroupedLaunchInputs",
     "configure_device_prep",
-    "configure_single_launch",
     "consume_prep_info",
-    "device_prep_active",
     "single_launch_active",
     "prepare_sets",
     "prepare_sets_device",
@@ -64,7 +61,6 @@ __all__ = [
     "prepare_grouped_launch_inputs",
     "build_device_inputs",
     "device_batch_verify",
-    "device_batch_verify_many",
     "device_batch_verify_sharded",
     "make_synthetic_sets",
     "verify_signature_sets_device",
@@ -76,60 +72,56 @@ __all__ = [
     "mesh_device_count",
     "make_lane_verify_fn",
     "make_lane_verify_prepared_fn",
-    "make_lane_verify_single_fn",
     "make_lane_verify_grouped_fn",
     "make_mesh_sharded_fn",
 ]
 
 COEFF_BITS = 64  # blinding scalar width, matches blst's 64-bit rand coeffs
 
-# --- device input prep (ops/prep.py) -----------------------------------------
-# Mode knob wired from --bls-device-prep: "auto" runs the on-chip prep
-# pipeline only when the Pallas backend is live (a CPU XLA prep would
-# just be a slower host prep), "on" forces it (tests, benches), "off"
-# keeps the host path (native C++ / python oracle). The host path stays
-# the verified fallback: a device-prep ERROR falls back per the same
-# degradation doctrine as BLS verify (errors degrade, verdicts — incl.
-# "structurally invalid set" — are final).
-PREP_MODES = ("auto", "on", "off")
-_prep_mode = "auto"  # guarded by: GIL (single str slot, set at node init / bench setup)
+# --- the verify schedule -----------------------------------------------------
+# Which schedule a batch runs is a fact of the backend, constant for a
+# process, and this module is the one place it is asked
+# (`single_launch_active`). On an accelerator (the Pallas backend live)
+# the whole chain — field stage (decompression sqrt chains, hash-to-
+# field reduction, SSWU candidates), subgroup ladders, hash finish +
+# 3-isogeny, RLC aggregation, Miller loop, final exponentiation — is ONE
+# resident device program per pow-2 size class, dispatched once through
+# ops/prep.py's counted `_dispatch` seam
+# (`ops.prep.SINGLE_LAUNCH_BUDGET` == 1). Staged-jit miscompile
+# doctrine: the split schedule (3-launch fused device prep + separate
+# verify dispatch) is RETAINED as the differential reference, and a
+# single-launch device error (or verdict-shape anomaly) degrades that
+# batch to it — then to host prep inside build_device_inputs, each
+# counted (errors degrade, verdicts — incl. "structurally invalid set"
+# — are final). Elsewhere (the CPU backend) a batch is host prep
+# (native C++ / python oracle) and the monolithic verify program: a CPU
+# XLA prep would just be a slower host prep. The lanes built from this
+# answer carry it (chain/bls/mesh.py `build_device_mesh`); nobody above
+# asks again, and nobody can set it.
 _prep_metrics = None  # guarded by: GIL (set once at node init)
 _prep_tls = threading.local()  # per-executor-thread prep span info
 
 
-def configure_device_prep(mode: str | None = None, metrics=None) -> str:
-    """Set the process-wide prep mode and/or the lodestar_bls_prep_*
-    metric family (node init; tests/benches flip the mode around calls).
-    Returns the PREVIOUS mode so callers can save/restore."""
-    global _prep_mode, _prep_metrics
-    prev = _prep_mode
-    if mode is not None:
-        if mode not in PREP_MODES:
-            raise ValueError(f"bls_device_prep must be one of {PREP_MODES}, got {mode!r}")
-        _prep_mode = mode
-    if metrics is not None:
-        _prep_metrics = metrics
-        # the launches counter increments at the dispatch site inside
-        # ops/prep.py (the only place that actually knows when a device
-        # program is launched) — hand it over here, the one config seam
-        launches = getattr(metrics, "launches", None)
-        if launches is not None:
-            from lodestar_tpu.ops import prep as _dp
-
-            _dp.configure_launch_counter(launches)
-    return prev
-
-
-def device_prep_active(mode: str | None = None) -> bool:
-    """Resolve a prep mode ("auto" follows the Pallas backend)."""
-    mode = mode or _prep_mode
-    if mode == "on":
-        return True
-    if mode == "off":
-        return False
+def single_launch_active() -> bool:
+    """Whether this process's backend runs the single-launch schedule
+    (and, on its error road, device prep): the Pallas backend is live."""
     from lodestar_tpu.ops import fp_pallas
 
     return fp_pallas.use_pallas()
+
+
+def configure_device_prep(metrics) -> None:
+    """Install the lodestar_bls_prep_* metric family (node init)."""
+    global _prep_metrics
+    _prep_metrics = metrics
+    # the launches counter increments at the dispatch site inside
+    # ops/prep.py (the only place that actually knows when a device
+    # program is launched) — hand it over here, the one config seam
+    launches = getattr(metrics, "launches", None)
+    if launches is not None:
+        from lodestar_tpu.ops import prep as _dp
+
+        _dp.configure_launch_counter(launches)
 
 
 def consume_prep_info():
@@ -168,62 +160,6 @@ def _note_prep_fallback(err: Exception) -> None:
         "device input prep failed, falling back to host prep",
         {"error": str(err)[:120]},
     )
-
-
-# --- single-launch verification (--bls-single-launch) -------------------------
-# The whole verification chain — field stage (decompression sqrt chains,
-# hash-to-field reduction, SSWU candidates), subgroup ladders, hash
-# finish + 3-isogeny, RLC aggregation, Miller loop, final exponentiation
-# — as ONE resident device program per pow-2 size class, dispatched once
-# through ops/prep.py's counted `_dispatch` seam
-# (`ops.prep.SINGLE_LAUNCH_BUDGET` == 1). "auto" engages when the
-# Pallas backend is live — the same doctrine as every other auto mode —
-# UNLESS the operator pinned device prep off: the single program
-# subsumes the prep stages, so an explicit host-prep pin keeps the
-# split schedule. (Prep "on" does NOT force single launch: that flag
-# is the tests'/benches' force-the-prep-stages knob.) Staged-jit
-# miscompile doctrine: the 3-launch fused prep + separate verify
-# dispatch is RETAINED as the differential reference, and a single-
-# launch device error (or verdict-shape anomaly) degrades that batch to
-# it — then to host prep inside build_device_inputs, exactly the
-# fused-vs-unfused chain.
-SINGLE_LAUNCH_MODES = ("auto", "on", "off")
-_single_launch_mode = "auto"  # guarded by: GIL (single str slot, set at node init / bench setup)
-
-
-def configure_single_launch(mode: str | None = None) -> str:
-    """Set the process-wide single-launch verification mode (node init;
-    tests/benches flip it around calls). Returns the PREVIOUS mode so
-    callers can save/restore."""
-    global _single_launch_mode
-    prev = _single_launch_mode
-    if mode is not None:
-        if mode not in SINGLE_LAUNCH_MODES:
-            raise ValueError(
-                f"bls_single_launch must be one of {SINGLE_LAUNCH_MODES}, got {mode!r}"
-            )
-        _single_launch_mode = mode
-    return prev
-
-
-def single_launch_active(mode: str | None = None) -> bool:
-    """Resolve a single-launch mode: "auto" engages when the Pallas
-    backend is live (the same doctrine as prep/mesh auto) UNLESS the
-    operator pinned device prep off — the single program subsumes the
-    prep stages, so an explicit host-prep pin keeps the split schedule.
-    Prep "on" does NOT implicitly engage single launch: it is the
-    tests'/benches' force-the-prep-stages knob and must keep meaning
-    exactly that."""
-    mode = mode or _single_launch_mode
-    if mode == "on":
-        return True
-    if mode == "off":
-        return False
-    if _prep_mode == "off":
-        return False
-    from lodestar_tpu.ops import fp_pallas
-
-    return fp_pallas.use_pallas()
 
 
 def _note_single_launch_fallback(err: Exception) -> None:
@@ -355,7 +291,7 @@ def _parse_host_arrays(sets: list[SignatureSet], size: int):
     return pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi
 
 
-def _prepare_sets_device_arrays(sets: list[SignatureSet], size: int, fused: bool = True):
+def _prepare_sets_device_arrays(sets: list[SignatureSet], size: int):
     """Device-resident prep on arrays padded to `size` (one compiled
     program per size class, same bucketing as the verify stages).
 
@@ -363,10 +299,8 @@ def _prepare_sets_device_arrays(sets: list[SignatureSet], size: int, fused: bool
     expand_message_xmd); every field op — decompression sqrt, subgroup
     checks, hash-to-field reduction, SSWU/isogeny/cofactor — runs in the
     staged device programs of ops/prep.py: `FUSED_PREP_LAUNCHES` counted
-    dispatches per batch on the production (fused) schedule; `fused=False`
-    keeps the pre-fusion one-launch-per-leg reference. Returns
-    (pk, h, sig, ok) where ok is the all-sets-structurally-valid verdict
-    (host bool)."""
+    dispatches per batch. Returns (pk, h, sig, ok) where ok is the
+    all-sets-structurally-valid verdict (host bool)."""
     from lodestar_tpu.ops import prep as dp
 
     n = len(sets)
@@ -377,8 +311,7 @@ def _prepare_sets_device_arrays(sets: list[SignatureSet], size: int, fused: bool
         return None, None, None, False
     pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi = parsed
 
-    prep_arrays = dp.prepare_arrays_fused if fused else dp.prepare_arrays_unfused
-    pk, pk_ok, sig, sig_ok, h = prep_arrays(
+    pk, pk_ok, sig, sig_ok, h = dp.prepare_arrays_fused(
         pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi
     )
 
@@ -391,17 +324,16 @@ def _prepare_sets_device_arrays(sets: list[SignatureSet], size: int, fused: bool
     return pk, h, sig, bool(valid.all())
 
 
-def prepare_sets_device(sets: list[SignatureSet], fused: bool = True):
+def prepare_sets_device(sets: list[SignatureSet]):
     """Device-path twin of `prepare_sets`: same contract (device-layout
     arrays or None if any set is structurally invalid), raw compressed
     bytes in, no per-set big-int math on the host. Internally padded to
-    the verify size classes so callers share compiled programs. The
-    fused schedule costs `ops.prep.FUSED_PREP_LAUNCHES` dispatches per
-    batch; `fused=False` runs the pre-fusion per-leg reference."""
+    the verify size classes so callers share compiled programs:
+    `ops.prep.FUSED_PREP_LAUNCHES` dispatches per batch."""
     if not sets:
         return None
     n = len(sets)
-    pk, h, sig, ok = _prepare_sets_device_arrays(sets, _pad_pow2(n), fused=fused)
+    pk, h, sig, ok = _prepare_sets_device_arrays(sets, _pad_pow2(n))
     if not ok:
         return None
     return (
@@ -648,26 +580,6 @@ def device_batch_verify(pk, h, sig, coeff_bits, mask) -> jax.Array:
         )
 
 
-_device_batch_verify_many_impl = jax.jit(jax.vmap(_device_batch_verify_impl))
-
-
-def device_batch_verify_many(pk, h, sig, coeff_bits, mask) -> jax.Array:
-    """J independent RLC jobs verified in ONE device launch (leading axis
-    J on every input). Each job keeps its own blinding, fold, final
-    exponentiation and verdict — the device translation of the
-    reference's \"one job per worker core\" concurrency
-    (`multithread/index.ts:348`): the program is latency-bound, so
-    stacking jobs widens every op's batch and multiplies throughput at
-    ~constant wall time.
-
-    Returns (J,) bool verdicts.
-    """
-    return _device_batch_verify_many_impl(
-        pk[0], pk[1], h[0], h[1], sig[0], sig[1],
-        jnp.asarray(coeff_bits), jnp.asarray(mask),
-    )
-
-
 @functools.lru_cache(maxsize=None)
 def _sharded_program(mesh):
     """The jitted data-parallel verify program for one mesh (jit
@@ -796,9 +708,7 @@ def _finish_inputs(pk, h, sig, n: int, size: int):
     return pk, h, sig, bits, mask
 
 
-def build_device_inputs(
-    sets: list[SignatureSet], size: int | None = None, prep: str | None = None
-):
+def build_device_inputs(sets: list[SignatureSet], size: int | None = None):
     """Input prep + padding: decode/validate/hash N sets and pad the
     arrays to `size` (default: next power of two >= 8, the size-class
     bucketing that keeps one compiled program per class — the device
@@ -806,10 +716,11 @@ def build_device_inputs(
     `multithread/index.ts:34-39`). Returns (pk, h, sig, bits, mask) device
     inputs with fresh blinding coefficients, or None on invalid input.
 
-    `prep` overrides the process-wide device-prep mode for this call
-    (see configure_device_prep). On the device path a prep ERROR falls
-    back to the verified host pipeline (native C++ → python oracle); a
-    structural-invalid verdict is final on whichever layer produced it.
+    Prep runs on the device where the backend is an accelerator
+    (`single_launch_active`), on the host otherwise. A device prep
+    ERROR falls back to the verified host pipeline (native C++ → python
+    oracle); a structural-invalid verdict is final on whichever layer
+    produced it.
     """
     if not sets:
         return None
@@ -820,7 +731,7 @@ def build_device_inputs(
         raise ValueError("pad size smaller than batch")
 
     with telemetry.phase("bls.parse"):
-        if device_prep_active(prep):
+        if single_launch_active():
             t0 = time.monotonic_ns()
             try:
                 pk, h, sig, ok = _prepare_sets_device_arrays(sets, size)
@@ -864,11 +775,10 @@ def make_synthetic_sets(n: int, seed: int = 1) -> list[SignatureSet]:
 def verify_signature_sets_device(sets: list[SignatureSet]) -> bool:
     """End-to-end single-device batch verify of N signature sets.
 
-    Routes through the single-launch program when `--bls-single-launch`
-    resolves active (one counted dispatch, bytes-in → verdict-out, with
-    its own degradation chain back to the split schedule); otherwise
-    runs the split schedule: 3-launch fused device prep (or host prep)
-    followed by the RLC verify dispatch."""
+    On an accelerator the single-launch program (one counted dispatch,
+    bytes-in → verdict-out, with its own degradation chain back to the
+    split schedule); otherwise the split schedule: host prep followed
+    by the RLC verify dispatch."""
     if single_launch_active():
         return verify_sets_single_launch(sets)
     return _verify_sets_split(sets)
@@ -876,7 +786,8 @@ def verify_signature_sets_device(sets: list[SignatureSet]) -> bool:
 
 def _verify_sets_split(sets: list[SignatureSet]) -> bool:
     """The split (prep-then-verify) schedule: `build_device_inputs`
-    (fused 3-launch device prep, host prep on error or by mode) plus
+    (fused 3-launch device prep, host prep on error or off an
+    accelerator) plus
     the separate RLC verify dispatch — the single-launch program's
     differential reference and per-batch fallback."""
     inputs = build_device_inputs(sets)
@@ -1122,7 +1033,7 @@ def prepare_inputs_for_lane(sets: list[SignatureSet], lane_index: int | None = N
     to a device (mock lanes, single-device hosts) preps unpinned —
     placement is an optimization, never a correctness seam.
 
-    With single-launch verification active the prep stage stays on the
+    Where the backend runs the single launch the prep stage stays on the
     HOST (byte parse + xmd + blinding, zero dispatches): every device
     op of batch k+1 rides its one launch, so the pipeline overlaps the
     host byte-parse/reject of k+1 with the single launch of k. A
@@ -1197,26 +1108,9 @@ def make_lane_verify_prepared_fn(device_index: int):
     return lane_verify_prepared
 
 
-def make_lane_verify_single_fn(device_index: int):
-    """Single-launch twin of `make_lane_verify_fn`, pinned to one chip:
-    the mesh pool's unstaged verify road when `--bls-single-launch`
-    resolves active — each lane keeps its own compiled copy of the one
-    resident program on its die. Degradation (single-launch error →
-    split schedule → host prep) rides inside, so lane/breaker error
-    semantics are unchanged."""
-
-    def lane_verify_single(sets: list[SignatureSet]) -> bool:
-        dev = jax.devices()[device_index]
-        with jax.default_device(dev):
-            return verify_sets_single_launch(sets)
-
-    lane_verify_single.__name__ = f"lane_verify_single_dev{device_index}"
-    return lane_verify_single
-
-
 def make_lane_verify_grouped_fn(device_index: int):
-    """Multi-job twin of `make_lane_verify_single_fn`, pinned to one
-    chip: a list of jobs in, one launch, a list of verdicts out."""
+    """Multi-job twin of `make_lane_verify_fn`, pinned to one chip: a
+    list of jobs in, one launch, a list of verdicts out."""
 
     def lane_verify_grouped(jobs: list[list[SignatureSet]]) -> list[bool]:
         dev = jax.devices()[device_index]

@@ -54,9 +54,6 @@ class BeaconNodeOptions:
         offload_quarantine_cooloff_s: float | None = None,
         offload_unquarantine: list[str] | None = None,
         scheduler_enabled: bool = True,
-        bls_device_prep: str = "auto",
-        bls_pipeline: str = "auto",
-        bls_single_launch: str = "auto",
         htr_device: str = "auto",
         bls_mesh: str = "auto",
         offload_tenant: str | None = None,
@@ -151,46 +148,6 @@ class BeaconNodeOptions:
         # device work scheduler (lodestar_tpu.scheduler) for the in-process
         # pool; False restores FIFO launches (debug/comparison only)
         self.scheduler_enabled = scheduler_enabled
-        # batch-verify input prep placement (models/batch_verify prep
-        # modes): "auto" runs decompression/subgroup/hash-to-G2 on the
-        # device only when the Pallas backend is live; "on"/"off" force.
-        # Validated against the model layer's canonical mode set (cli.py
-        # keeps a literal copy — argparse choices must not import jax)
-        from lodestar_tpu.models.batch_verify import PREP_MODES
-
-        if bls_device_prep not in PREP_MODES:
-            raise ValueError(
-                f"bls_device_prep must be one of {PREP_MODES}, got {bls_device_prep!r}"
-            )
-        self.bls_device_prep = bls_device_prep
-        # prep→verify double buffering (chain/bls/pool.py): "auto"
-        # overlaps prep of batch k+1 with verify of batch k only when
-        # the mesh has a sibling lane; "on"/"off" force. Validated
-        # against the pool's canonical mode set (cli.py keeps a literal
-        # copy — argparse choices must not import the chain.bls package)
-        from lodestar_tpu.chain.bls.pool import PIPELINE_MODES
-
-        if bls_pipeline not in PIPELINE_MODES:
-            raise ValueError(
-                f"bls_pipeline must be one of {PIPELINE_MODES}, got {bls_pipeline!r}"
-            )
-        self.bls_pipeline = bls_pipeline
-        # single-launch verification (models/batch_verify.py): "auto"
-        # verifies each batch as ONE resident device program when the
-        # Pallas backend is live (an explicit device-prep "off" pin
-        # keeps the split schedule); "on"/"off" force. Single-launch
-        # errors degrade per batch to the split prep-then-verify
-        # schedule, then host prep. Validated against the model layer's
-        # canonical mode set (cli.py keeps a literal copy — argparse
-        # choices must not import jax)
-        from lodestar_tpu.models.batch_verify import SINGLE_LAUNCH_MODES
-
-        if bls_single_launch not in SINGLE_LAUNCH_MODES:
-            raise ValueError(
-                f"bls_single_launch must be one of {SINGLE_LAUNCH_MODES}, "
-                f"got {bls_single_launch!r}"
-            )
-        self.bls_single_launch = bls_single_launch
         # state hashTreeRoot placement (ssz/device_htr.py collector):
         # "auto" flushes dirty subtrees through the device SHA-256
         # kernel only when the Pallas backend is live; "on"/"off" force.
@@ -258,7 +215,6 @@ def _device_pool(opts: BeaconNodeOptions, metrics: BeaconMetrics):
         scheduler_enabled=opts.scheduler_enabled,
         sched_metrics=metrics.sched,
         mesh_mode=opts.bls_mesh,
-        pipeline=opts.bls_pipeline,
         pipeline_metrics=metrics.bls_pipeline,
     )
 
@@ -361,20 +317,19 @@ def _offload_verifier(opts: BeaconNodeOptions, metrics: BeaconMetrics) -> IBlsVe
 def configure_device_runtime(opts: BeaconNodeOptions, metrics: BeaconMetrics) -> dict:
     """Observe the backend once and configure the process-global device
     seams from it (they live in the model/ssz/ops layers, below any
-    node object): batch-verify prep placement and single-launch mode,
-    state hashTreeRoot placement, the KZG fallback counter and launch
-    telemetry, each with its metric family. Returns what the node logs
-    once at start: platform, device_kind, count, verifier, hasher.
+    node object): the batch-verify prep metrics, state hashTreeRoot
+    placement, the KZG fallback counter and launch telemetry, each with
+    its metric family. The verify schedule is not among them: it is
+    what the backend is (models/batch_verify `single_launch_active`).
+    Returns what the node logs once at start: platform, device_kind,
+    count, verifier, hasher.
 
     A node that verifies through `--bls-offload` without a local device
     fallback leaves the chip to the process that owns it: it
     initialises no backend and every "auto" resolves to the host."""
     from lodestar_tpu import telemetry
     from lodestar_tpu.crypto.kzg import configure_kzg_fallback_counter
-    from lodestar_tpu.models.batch_verify import (
-        configure_device_prep,
-        configure_single_launch,
-    )
+    from lodestar_tpu.models.batch_verify import configure_device_prep
     from lodestar_tpu.ssz.device_htr import configure_device_htr, device_htr_active
     from lodestar_tpu.utils import probe_accelerator
 
@@ -386,9 +341,7 @@ def configure_device_runtime(opts: BeaconNodeOptions, metrics: BeaconMetrics) ->
     )
     on_tpu = accel["platform"] == "tpu"
 
-    configure_device_prep(mode=opts.bls_device_prep, metrics=metrics.bls_prep)
-    # single-launch mode rides the same seam; metrics shared with prep
-    configure_single_launch(mode=opts.bls_single_launch)
+    configure_device_prep(metrics.bls_prep)
     configure_device_htr(
         mode=opts.htr_device, metrics=metrics.ssz_htr, accelerator=on_tpu
     )

@@ -10,8 +10,9 @@ disagrees with the oracle stops the boot instead of serving.
 The programs are the pool's launch units (`chain/bls/pool._launch_units`):
 one job alone rides the flat 128-row program; two to four jobs of one
 package ride `_grouped_launch_verify` at (256 rows, 2 slots) or (512, 4).
-Where the mesh cannot group (`--bls-single-launch off`) every job rides
-the flat program of whatever schedule serves, and that alone is warmed.
+Where the mesh cannot group (its lanes have no grouped entry: a backend
+on the split schedule, a faked lane) every job rides the flat program of
+whatever schedule serves, and that alone is warmed.
 Smaller size classes (a job of 64 sets or fewer) stay cold: their first
 use compiles (ROADMAP S4 (a)).
 """

@@ -733,7 +733,6 @@ class VerifyBackend(NamedTuple):
 
 def build_backend(
     bls_mesh: str = "auto",
-    bls_single_launch: str = "auto",
     *,
     pool_factory: Callable | None = None,
     sched_metrics=None,
@@ -763,13 +762,6 @@ def build_backend(
         return VerifyBackend(
             verify_signature_sets, None, {**accel, "verifier": "cpu-oracle", "lanes": 0}
         )
-    # the lanes route through the process-global single-launch mode
-    # (models/batch_verify); pin it from the server's own flag so a
-    # serving host is never one env change away from a surprise
-    # first-use compile of the monolithic program
-    from lodestar_tpu.models.batch_verify import configure_single_launch
-
-    configure_single_launch(mode=bls_single_launch)
     if pool_factory is not None:
         pool = pool_factory()
     else:
@@ -825,7 +817,6 @@ def boot_host(
     workers: int = 4,
     metrics_port: int = 0,
     bls_mesh: str = "auto",
-    bls_single_launch: str = "auto",
     tenant_weights: dict[str, int] | None = None,
     tenant_default_weight: int = 1,
     tenant_slots: int | None = None,
@@ -863,7 +854,6 @@ def boot_host(
     tenant_metrics = create_tenant_metrics(creator)
     backend = build_backend(
         bls_mesh,
-        bls_single_launch,
         pool_factory=pool_factory,
         sched_metrics=create_sched_metrics(creator),
         pipeline_metrics=create_bls_pipeline_metrics(creator),
@@ -885,7 +875,7 @@ def boot_host(
 
             from .known_answer import check_known_answers
 
-            configure_device_prep(metrics=create_bls_prep_metrics(creator))
+            configure_device_prep(create_bls_prep_metrics(creator))
             telemetry.configure_launch_telemetry(metrics=create_device_launch_metrics(creator))
             backend.verify.start()
             warmed = backend.verify.run(check_known_answers(backend.verify.pool, log=log))
@@ -948,16 +938,6 @@ def main() -> int:
         "unless this is on.",
     )
     ap.add_argument(
-        # literal copy of models.batch_verify.SINGLE_LAUNCH_MODES
-        # (argparse-import doctrine: re-validated by configure below)
-        "--bls-single-launch", choices=["auto", "on", "off"], default="auto",
-        help="verify each served batch as ONE resident device program "
-        "(see the node flag of the same name): auto = when the Pallas "
-        "backend is live, on = always, off = pin the split "
-        "prep-then-verify schedule — the serving-host knob for "
-        "avoiding the monolithic program's first-use compile",
-    )
-    ap.add_argument(
         "--tenant-weight", action="append", default=[], metavar="NAME=WEIGHT",
         help="stride-fair service share for a tenant (repeatable); unlisted "
         "tenants get --tenant-default-weight",
@@ -1008,7 +988,6 @@ def main() -> int:
             workers=args.workers,
             metrics_port=args.metrics_port,
             bls_mesh=args.bls_mesh,
-            bls_single_launch=args.bls_single_launch,
             tenant_weights=parse_tenant_weights(args.tenant_weight),
             tenant_default_weight=args.tenant_default_weight,
             tenant_slots=args.tenant_slots,

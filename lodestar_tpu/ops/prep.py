@@ -715,8 +715,8 @@ def prepare_arrays_fused(pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi):
 
 
 def prepare_arrays_unfused(pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi):
-    """The pre-fusion one-launch-per-leg schedule, kept as the bench's
-    before/after reference and the fused path's differential oracle
+    """The pre-fusion one-launch-per-leg schedule, kept as the fused
+    path's differential oracle: no program calls it, the tests do
     (`UNFUSED_PREP_LAUNCHES` counted dispatches). Same contract as
     `prepare_arrays_fused`."""
     pk_x, pk_y, pk_ok = _dispatch(g1_decompress_subgroup, pk_limbs, pk_sign)

@@ -86,7 +86,10 @@ class FakeLaneRig:
     lane-kill tests. The collective `sharded_fn` records the device
     subset per launch and delegates the verdict to `verdict_fn`
     (default: all sets valid). `calls`/`sharded_calls` are appended
-    under a lock so executor threads can't tear them."""
+    under a lock so executor threads can't tear them. The lanes state
+    their own facts, as production lanes do: `with_prepared` gives them
+    the staged-inputs seam, `staged_prep_host_only` says that what is
+    staged for it touches no device (so a one-lane pool stages too)."""
 
     def __init__(
         self,
@@ -97,6 +100,7 @@ class FakeLaneRig:
         verdict_fn=None,
         with_sharded: bool = True,
         with_prepared: bool = False,
+        staged_prep_host_only: bool = False,
     ) -> None:
         self.call_s = call_s
         self.verdict_fn = verdict_fn or (lambda sets: True)
@@ -113,6 +117,7 @@ class FakeLaneRig:
                 verify_prepared_fn=(
                     self._make_prepared_fn(i) if with_prepared else None
                 ),
+                staged_prep_host_only=staged_prep_host_only,
             )
             for i in range(n_lanes)
         ]

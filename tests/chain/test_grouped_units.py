@@ -25,15 +25,6 @@ from lodestar_tpu.models import batch_verify as bv
 from lodestar_tpu.scheduler import PriorityClass
 
 
-@pytest.fixture(autouse=True)
-def single_on():
-    """The grouped program is the single-launch program with a slot a
-    job: the pool groups only where that mode resolves active."""
-    prev = bv.configure_single_launch(mode="on")
-    yield
-    bv.configure_single_launch(mode=prev)
-
-
 def _sets(n: int, tag: int = 0) -> list[SignatureSet]:
     return [
         SignatureSet(
@@ -50,18 +41,37 @@ def _tag(sets) -> int:
 
 
 class Rig:
-    """One lane that speaks sets and jobs. `launches` lists every call:
-    ("single", [n_sets]) or ("grouped", [n_sets of each job]). A job is
-    invalid where its tag is in `bad`."""
+    """One lane that speaks sets and jobs, as the single launch's lane
+    does: the pool groups because the lane has the grouped entry.
+    `launches` lists every call: ("single", [n_sets]) or ("grouped",
+    [n_sets of each job]). A job is invalid where its tag is in `bad`.
+    `prepared` gives the lane the staged-inputs seam, and with it the
+    single launch's other fact: what is staged for it touches no
+    device, so the one-lane pool stages."""
 
-    def __init__(self, bad=(), grouped_error: Exception | None = None, hold: threading.Event | None = None):
+    def __init__(
+        self,
+        bad=(),
+        grouped_error: Exception | None = None,
+        hold: threading.Event | None = None,
+        prepared=None,
+    ):
         self.bad = set(bad)
         self.grouped_error = grouped_error
         self.hold = hold
         self.launches: list[tuple[str, list[int]]] = []
         self.tags: list[list[int]] = []
         self.mesh = VerifierMesh(
-            [MeshLane(0, self.verify, verify_grouped_fn=self.verify_grouped, wedge_threshold=8)]
+            [
+                MeshLane(
+                    0,
+                    self.verify,
+                    verify_grouped_fn=self.verify_grouped,
+                    verify_prepared_fn=prepared,
+                    staged_prep_host_only=prepared is not None,
+                    wedge_threshold=8,
+                )
+            ]
         )
 
     def verify(self, sets) -> bool:
@@ -224,20 +234,6 @@ def test_lanes_without_a_grouped_entry_keep_todays_units():
 
     assert _run(go()) is True
     assert calls == [66, 65]
-
-
-def test_single_launch_off_keeps_todays_units():
-    rig = Rig()
-    bv.configure_single_launch(mode="off")
-
-    async def go():
-        pool = BlsDeviceVerifierPool(mesh=rig.mesh)
-        got = await _submit(pool, _sets(131), PriorityClass.GOSSIP_BLOCK)
-        await pool.close()
-        return got
-
-    assert _run(go()) is True
-    assert rig.launches == [("single", [66]), ("single", [65])]
 
 
 # -- bulk: a package is one launch -----------------------------------------------
@@ -404,18 +400,17 @@ def test_prep_package_and_verify_package_form_identical_units(monkeypatch):
         return ("grouped-inputs", [_tag(s) for s in job_sets])
 
     monkeypatch.setattr(bv, "prepare_grouped_launch_inputs", fake_grouped_prep)
-    rig = Rig()
     prepared_seen = []
 
     def verify_prepared(inputs):
         prepared_seen.append(inputs)
         return [True] * len(inputs[1]) if inputs[0] == "grouped-inputs" else True
 
-    rig.mesh.lanes[0].verify_prepared_fn = verify_prepared
+    rig = Rig(prepared=verify_prepared)
 
     async def go():
         pool = BlsDeviceVerifierPool(
-            mesh=rig.mesh, pipeline="on", prep_fn=lambda sets, hint: ("inputs", [_tag(sets)])
+            mesh=rig.mesh, prep_fn=lambda sets, hint: ("inputs", [_tag(sets)])
         )
         package = [_Job(_sets(n, tag=i), False, PriorityClass.API) for i, n in enumerate((66, 10, 65, 70))]
         prepped = pool._stage(package, PriorityClass.API)
@@ -440,33 +435,43 @@ def test_prep_package_and_verify_package_form_identical_units(monkeypatch):
     assert rig.launches == []  # every staged unit went through verify_prepared_fn
 
 
-def test_the_staged_pipeline_serves_a_block_with_one_staged_multi_job_launch(monkeypatch):
+def test_the_staged_pipeline_serves_a_bulk_group_with_one_staged_multi_job_launch(monkeypatch):
+    """Four bulk jobs of the 128 class queued behind a held launch: the
+    queue holds all of their package, so it is taken ahead of the lane
+    and its one unit, a multi-job launch, is staged — through the
+    pool's own prep road, which hands a multi-job unit to
+    `prepare_grouped_launch_inputs`."""
     monkeypatch.setattr(
         bv, "prepare_grouped_launch_inputs", lambda job_sets: ("grouped-inputs", [len(s) for s in job_sets])
     )
-    rig = Rig()
     prepared_seen = []
 
     def verify_prepared(inputs):
         prepared_seen.append(inputs)
-        return [True, False]
+        return [True, False, True, True]
 
-    rig.mesh.lanes[0].verify_prepared_fn = verify_prepared
+    hold = threading.Event()
+    rig = Rig(hold=hold, prepared=verify_prepared)
 
     async def go():
-        pool = BlsDeviceVerifierPool(mesh=rig.mesh, pipeline="on")
+        pool = BlsDeviceVerifierPool(mesh=rig.mesh)
         futs = [
-            asyncio.ensure_future(_submit(pool, _sets(n, tag=i), PriorityClass.GOSSIP_BLOCK))
-            for i, n in enumerate((66, 65))
+            asyncio.ensure_future(_submit(pool, _sets(n, tag=i), PriorityClass.RANGE_SYNC))
+            for i, n in enumerate((10, 66, 65, 66, 65))
         ]
+        while pool.pipeline_stats()["staged_packages"] == 0:
+            await asyncio.sleep(0.005)
+        hold.set()
         got = await asyncio.gather(*futs)
         stats = pool.pipeline_stats()
         await pool.close()
         return got, stats
 
     got, stats = _run(go())
-    assert got == [True, False]
-    assert prepared_seen == [("grouped-inputs", [66, 65])] and rig.launches == []
+    assert got == [True, True, False, True, True]
+    # the first job found the lane free (inline); the group behind it was staged
+    assert rig.launches == [("single", [10])]
+    assert prepared_seen == [("grouped-inputs", [66, 65, 66, 65])]
     assert stats["pipeline_enabled"] and stats["staged_packages"] == 1
 
 
@@ -474,18 +479,18 @@ def test_the_staged_pipeline_serves_a_block_with_one_staged_multi_job_launch(mon
 
 
 class StagedRig(Rig):
-    """A `Rig` whose lane also takes staged inputs, with a parse and a
+    """A `Rig` whose lane also takes staged inputs (unless `stages` is
+    False: the control arm, the same lane without the seam), with a parse and a
     launch that take a while. The staged parse of a unit hands the
     lane what the unstaged entries are handed, so `launches` and `tags`
     read the same on both roads; `events` orders parse and launch
     starts and ends as they happened."""
 
-    def __init__(self, monkeypatch, bad=(), parse_s=0.0, launch_s=0.0):
-        super().__init__(bad=bad)
+    def __init__(self, monkeypatch, bad=(), parse_s=0.0, launch_s=0.0, stages=True):
+        super().__init__(bad=bad, prepared=self.verify_prepared if stages else None)
         self.parse_s, self.launch_s = parse_s, launch_s
         self.events: list[tuple[str, int]] = []
         self._lock = threading.Lock()
-        self.mesh.lanes[0].verify_prepared_fn = self.verify_prepared
         monkeypatch.setattr(bv, "prepare_grouped_launch_inputs", lambda jobs: self.parse("grouped", jobs))
 
     def note(self, what: str, first_tag: int) -> None:
@@ -558,8 +563,8 @@ def test_a_block_that_finds_the_lane_free_keeps_the_inline_road(monkeypatch):
     """One caller, one block, the next only after the verdict: a
     package of one unit that finds the lane free has no launch to hide
     its parse behind, and staging it would put two thread hops into
-    every verdict. Under "auto" it is launched as an unpipelined pool
-    launches it."""
+    every verdict. It is launched as a pool that cannot stage launches
+    it."""
     rig = StagedRig(monkeypatch, parse_s=0.01, launch_s=0.02)
 
     async def go():
@@ -585,18 +590,18 @@ def test_a_block_that_finds_the_lane_free_keeps_the_inline_road(monkeypatch):
 def test_staged_replay_launches_the_unpipelined_pools_units_in_its_order(monkeypatch, priority, batchable_share):
     """Seeded replay, one class, everything queued behind a first
     launch, a quarter of the jobs planted bad: the launch sequence
-    (units, sizes, order) and every job's verdict are the unpipelined
-    pool's. Only where the parse ran differs."""
+    (units, sizes, order) and every job's verdict are those of a pool
+    whose lane takes no staged inputs. Only where the parse ran differs."""
     import random
 
-    def replay(pipeline: str):
+    def replay(stages: bool):
         rng = random.Random(20301)
         bad = {i for i in range(30) if rng.random() < 0.25}
-        rig = StagedRig(monkeypatch, bad=bad, launch_s=0.005)
+        rig = StagedRig(monkeypatch, bad=bad, launch_s=0.005, stages=stages)
 
         async def go():
             pool = BlsDeviceVerifierPool(  # a batchable job goes to the queue as it comes
-                mesh=rig.mesh, pipeline=pipeline, prep_fn=rig.prep_fn, max_buffered_sigs=0
+                mesh=rig.mesh, prep_fn=rig.prep_fn if stages else None, max_buffered_sigs=0
             )
             futs = [
                 asyncio.ensure_future(_submit(
@@ -613,8 +618,8 @@ def test_staged_replay_launches_the_unpipelined_pools_units_in_its_order(monkeyp
         got, stats = _run(go())
         return got, rig.launches, rig.tags, stats, bad
 
-    got, launches, tags, stats, bad = replay("auto")
-    got_off, launches_off, tags_off, stats_off, _ = replay("off")
+    got, launches, tags, stats, bad = replay(True)
+    got_off, launches_off, tags_off, stats_off, _ = replay(False)
     assert got == got_off == [i not in bad for i in range(30)]
     assert (launches, tags) == (launches_off, tags_off)
     assert stats["staged_packages"] > 0 == stats_off["staged_packages"]

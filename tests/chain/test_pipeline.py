@@ -6,14 +6,18 @@
 * a prep error in batch k+1 degrades only that batch to host prep —
   batch k's device verdict stands,
 * close() drains both stages without stranding futures,
-* 1-lane / no-mesh under the default "auto" mode keeps the exact
-  pre-pipeline launch schedule under the split schedule (the PR 8
-  single-lane equality doctrine) and stages the host parse under the
-  single launch, bulk packages too where the mesh cannot shard,
+* 1-lane / no-mesh keeps the exact pre-pipeline launch schedule where
+  the lane's staged prep is device work (the PR 8 single-lane equality
+  doctrine) and stages the host parse where the lane says its staged
+  prep is host-only, bulk packages too where the mesh cannot shard,
 * an urgent arrival overtakes the package taken ahead, and the
   `parse_ns` / `parse_hidden_ns` counters say how much was hidden,
-* staged inputs actually reach the lanes' verify_prepared seam, and
-* the --bls-pipeline mode wiring (cli ↔ BeaconNodeOptions ↔ pool).
+* staged inputs actually reach the lanes' verify_prepared seam.
+
+A rig's lanes state their own facts (`FakeLaneRig(with_prepared=,
+staged_prep_host_only=)`): that, and a job queued behind a held launch,
+is what makes a pool stage here. The one-lane rigs that must stage are
+`_host_parse_rig`s; the control arm is a rig of plain lanes.
 """
 
 from __future__ import annotations
@@ -26,7 +30,6 @@ import time
 import pytest
 
 from lodestar_tpu.chain.bls import BlsDeviceVerifierPool, VerifySignatureOpts
-from lodestar_tpu.chain.bls.pool import PIPELINE_MODES
 from lodestar_tpu.crypto.bls.api import SignatureSet
 from lodestar_tpu.scheduler import PriorityClass
 from lodestar_tpu.testing.mesh import FakeLaneRig
@@ -47,28 +50,38 @@ def _run(coro):
     return asyncio.run(coro)
 
 
+def _host_parse_rig(call_s: float = 0.0, with_sharded: bool = False) -> FakeLaneRig:
+    """One lane as the single launch's: it takes staged inputs, and what
+    is staged for it touches no device — so the pool stages behind it."""
+    return FakeLaneRig(
+        1, call_s=call_s, with_prepared=True, with_sharded=with_sharded,
+        staged_prep_host_only=True,
+    )
+
+
 # -- verdict equivalence -------------------------------------------------------
 
 
 def test_verdicts_identical_pipelined_vs_unpipelined():
     """Seeded replay: the same job stream (some invalid) produces the
-    same per-job verdicts with the pipeline on and off. tag==13 marks a
+    same per-job verdicts on two lanes that take staged inputs (the
+    pool stages) and on two plain lanes (it cannot). tag==13 marks a
     set invalid, so the batch-then-retry road is exercised too."""
 
     def verdict_fn(sets):
         return all(s.message[1] != 13 for s in sets)
 
-    def replay(pipeline: str):
+    def replay(staged: bool):
         rng = random.Random(42)
-        rig = FakeLaneRig(2, with_prepared=True, with_sharded=False)
+        rig = FakeLaneRig(2, with_prepared=staged, with_sharded=False)
 
         async def go():
             pool = BlsDeviceVerifierPool(
                 mesh=rig.mesh,
                 scheduler_enabled=True,
-                pipeline=pipeline,
-                prep_fn=FakeLaneRig.prep_fn,
+                prep_fn=FakeLaneRig.prep_fn if staged else None,
             )
+            assert pool.pipeline_stats()["pipeline_enabled"] is staged
             jobs = []
             for i in range(24):
                 tag = 13 if rng.random() < 0.25 else i % 7
@@ -88,7 +101,7 @@ def test_verdicts_identical_pipelined_vs_unpipelined():
         rig.verdict_fn = verdict_fn
         return _run(go())
 
-    assert replay("off") == replay("on")
+    assert replay(False) == replay(True)
 
 
 # -- overlap -------------------------------------------------------------------
@@ -97,7 +110,7 @@ def test_verdicts_identical_pipelined_vs_unpipelined():
 def test_prep_of_next_batch_overlaps_verify_of_current():
     """While lane L verifies batch k, the stage loop preps batch k+1 —
     the overlap tracker must record concurrent prep+verify wall time."""
-    rig = FakeLaneRig(1, call_s=0.08, with_prepared=True, with_sharded=False)
+    rig = _host_parse_rig(call_s=0.08)
 
     def slow_prep(sets, lane_hint):
         time.sleep(0.04)
@@ -107,7 +120,6 @@ def test_prep_of_next_batch_overlaps_verify_of_current():
         pool = BlsDeviceVerifierPool(
             mesh=rig.mesh,
             scheduler_enabled=True,
-            pipeline="on",
             prep_fn=slow_prep,
         )
         jobs = []
@@ -115,7 +127,9 @@ def test_prep_of_next_batch_overlaps_verify_of_current():
             jobs.append(
                 asyncio.ensure_future(
                     pool.verify_signature_sets(
-                        _sets(1, tag=i), VerifySignatureOpts(batchable=False)
+                        # a bulk class: a job is a package, taken ahead of the busy lane
+                        _sets(1, tag=i),
+                        VerifySignatureOpts(batchable=False, priority=PriorityClass.RANGE_SYNC),
                     )
                 )
             )
@@ -134,72 +148,73 @@ def test_prep_of_next_batch_overlaps_verify_of_current():
 
 
 def test_staged_inputs_reach_the_prepared_verify_seam():
-    rig = FakeLaneRig(1, with_prepared=True, with_sharded=False)
-
-    async def go():
-        pool = BlsDeviceVerifierPool(
-            mesh=rig.mesh,
-            scheduler_enabled=True,
-            pipeline="on",
-            prep_fn=FakeLaneRig.prep_fn,
-        )
-        ok = await pool.verify_signature_sets(
-            _sets(3), VerifySignatureOpts(batchable=False)
-        )
-        await pool.close()
-        return ok
-
-    assert _run(go()) is True
-    assert rig.prepared_calls, "staged inputs never reached verify_prepared_fn"
+    """The job that finds the lane free takes the inline road; the one
+    queued behind its launch is staged and goes through the seam."""
+    rig = _host_parse_rig(call_s=0.03)
+    ok, _stats, _metrics = _queued_behind_a_launch(rig, 2)
+    assert ok == [True, True]
+    assert rig.prepared_calls == [(0, 1)], "staged inputs never reached verify_prepared_fn"
 
 
 # -- degradation ---------------------------------------------------------------
 
 
 def test_prep_error_in_batch_k1_degrades_only_that_batch(monkeypatch):
-    """Device prep forced on, the SECOND device-prep call injected to
-    fail: batch k preps on device and its device verdict stands; batch
-    k+1 degrades to host prep (fallback counted once) and still
-    verifies True. The degradation chain is build_device_inputs' own —
-    the pipeline only moved WHERE it runs."""
+    """The split schedule with device prep, as an accelerator runs it on
+    the single launch's error road, forced here at the call: the lane is
+    `_verify_sets_split` / `verify_prepared`, staged prep is
+    `build_device_inputs`, and the resolver is patched so that prep runs
+    on the device. The device prep of batch k+1 is injected to fail:
+    batch k preps on device and its device verdict stands; batch k+1,
+    staged behind k's launch, degrades to host prep (fallback counted
+    once) and still verifies True. The degradation chain is
+    build_device_inputs' own — the pipeline only moved WHERE it runs."""
+    from lodestar_tpu.chain.bls.mesh import single_lane_mesh
     from lodestar_tpu.metrics import create_metrics
     from lodestar_tpu.models import batch_verify as bv
     from lodestar_tpu.ops import prep as dp
 
     metrics = create_metrics()
-    bv.configure_device_prep(mode="on", metrics=metrics.bls_prep)
+    bv.configure_device_prep(metrics.bls_prep)
+    monkeypatch.setattr(bv, "single_launch_active", lambda: True)
     real = bv._prepare_sets_device_arrays
-    calls = {"n": 0}
-
-    def flaky(sets, size, fused=True):
-        calls["n"] += 1
-        if calls["n"] == 2:
-            raise RuntimeError("injected device prep fault in batch k+1")
-        return real(sets, size, fused=fused)
-
-    monkeypatch.setattr(bv, "_prepare_sets_device_arrays", flaky)
     sets_k = bv.make_synthetic_sets(4, seed=61)
     sets_k1 = bv.make_synthetic_sets(4, seed=62)
 
+    def flaky(sets, size):
+        if sets[0].pubkey == sets_k1[0].pubkey:
+            raise RuntimeError("injected device prep fault in batch k+1")
+        return real(sets, size)
+
+    monkeypatch.setattr(bv, "_prepare_sets_device_arrays", flaky)
+    # one CPU device has no sibling die to stage on: the lane says its
+    # staged prep may share it, which is all `_staging` asks
+    mesh = single_lane_mesh(
+        bv._verify_sets_split, verify_prepared_fn=bv.verify_prepared, staged_prep_host_only=True
+    )
+
     async def go():
-        pool = BlsDeviceVerifierPool(pipeline="on")
-        ok_k = await pool.verify_signature_sets(
-            sets_k, VerifySignatureOpts(batchable=False)
+        pool = BlsDeviceVerifierPool(
+            mesh=mesh, prep_fn=lambda sets, lane_hint: bv.build_device_inputs(sets)
         )
-        ok_k1 = await pool.verify_signature_sets(
-            sets_k1, VerifySignatureOpts(batchable=False)
-        )
+        bulk = VerifySignatureOpts(batchable=False, priority=PriorityClass.RANGE_SYNC)
+        futs = [
+            asyncio.ensure_future(pool.verify_signature_sets(sets, bulk))
+            for sets in (sets_k, sets_k1)
+        ]
+        ok = await asyncio.gather(*futs)
+        stats = pool.pipeline_stats()
         await pool.close()
-        return ok_k, ok_k1
+        return ok, stats
 
     try:
-        ok_k, ok_k1 = _run(go())
+        (ok_k, ok_k1), stats = _run(go())
     finally:
         dp.configure_launch_counter(None)
-        bv.configure_device_prep(mode="auto")
         bv._prep_metrics = None
         bv.consume_prep_info()
     assert ok_k is True and ok_k1 is True
+    assert stats["staged_packages"] == 1  # k found the lane free; k+1 was staged behind it
     assert metrics.bls_prep.sets.labels("device")._value.get() == 4
     assert metrics.bls_prep.sets.labels("host")._value.get() == 4
     assert metrics.bls_prep.fallbacks._value.get() == 1
@@ -209,7 +224,7 @@ def test_prep_error_in_batch_k1_degrades_only_that_batch(monkeypatch):
 
 
 def test_close_drains_both_stages_without_stranding_futures():
-    rig = FakeLaneRig(1, call_s=0.2, with_prepared=True, with_sharded=False)
+    rig = _host_parse_rig(call_s=0.2)
 
     def slow_prep(sets, lane_hint):
         time.sleep(0.1)
@@ -219,7 +234,6 @@ def test_close_drains_both_stages_without_stranding_futures():
         pool = BlsDeviceVerifierPool(
             mesh=rig.mesh,
             scheduler_enabled=True,
-            pipeline="on",
             prep_fn=slow_prep,
         )
         futures = [
@@ -230,7 +244,7 @@ def test_close_drains_both_stages_without_stranding_futures():
             )
             for i in range(6)
         ]
-        await asyncio.sleep(0.05)  # one verifying, one staged, rest queued
+        await asyncio.sleep(0.05)  # one package of six units in hand, its first still in its parse
         await pool.close()
         results = await asyncio.gather(*futures, return_exceptions=True)
         return futures, results
@@ -244,54 +258,27 @@ def test_close_drains_both_stages_without_stranding_futures():
 # -- 1-lane schedule regression ------------------------------------------------
 
 
-def test_auto_single_lane_keeps_pre_pipeline_schedule():
-    """Default mode on a 1-lane / no-mesh pool: the pipeline must NOT
-    engage — launches stay serialized, the launch sequence matches an
-    explicit pipeline="off" pool job for job, and nothing is staged."""
+def test_single_lane_of_plain_callables_keeps_pre_pipeline_schedule():
+    """A 1-lane / no-mesh pool whose lane only speaks sets: the pipeline
+    must NOT engage — launches stay serialized, one a job in arrival
+    order, and nothing is staged."""
+    rig = FakeLaneRig(1, call_s=0.01, with_sharded=False)
 
-    def replay(pipeline: str):
-        rig = FakeLaneRig(1, call_s=0.01, with_sharded=False)
-
-        async def go():
-            pool = BlsDeviceVerifierPool(
-                mesh=rig.mesh, scheduler_enabled=True, pipeline=pipeline
+    async def go():
+        pool = BlsDeviceVerifierPool(mesh=rig.mesh, scheduler_enabled=True)
+        assert pool.pipeline_stats()["pipeline_enabled"] is False
+        for i in range(5):
+            assert await pool.verify_signature_sets(
+                _sets(1, tag=i), VerifySignatureOpts(batchable=False)
             )
-            assert pool.pipeline_stats()["pipeline_enabled"] is False
-            windows = []
+        stats = pool.pipeline_stats()
+        await pool.close()
+        return stats
 
-            orig = rig.verdict_fn
-
-            def timed(sets):
-                windows.append((time.monotonic(), len(sets)))
-                return orig(sets)
-
-            rig.verdict_fn = timed
-            for i in range(5):
-                assert await pool.verify_signature_sets(
-                    _sets(1, tag=i), VerifySignatureOpts(batchable=False)
-                )
-            stats = pool.pipeline_stats()
-            await pool.close()
-            return rig.calls, stats
-
-        return _run(go())
-
-    calls_auto, stats_auto = replay("auto")
-    calls_off, stats_off = replay("off")
-    assert calls_auto == calls_off  # identical lane/size launch sequence
-    assert stats_auto["staged_packages"] == 0 == stats_off["staged_packages"]
-    assert stats_auto["prep_ns"] == 0  # the prep stage never ran
-
-
-@pytest.fixture
-def single_launch():
-    """Resolve `--bls-single-launch` as the fixture's user asks: under it
-    the staged prep is host-only, which is what one-lane "auto" follows."""
-    from lodestar_tpu.models import batch_verify as bv
-
-    prev = bv.configure_single_launch()
-    yield lambda mode: bv.configure_single_launch(mode=mode)
-    bv.configure_single_launch(mode=prev)
+    stats = _run(go())
+    assert rig.calls == [(0, 1)] * 5
+    assert stats["staged_packages"] == 0
+    assert stats["prep_ns"] == 0  # the prep stage never ran
 
 
 def _parse_that_takes_a_while(sets, lane_hint):
@@ -299,14 +286,14 @@ def _parse_that_takes_a_while(sets, lane_hint):
     return FakeLaneRig.prep_fn(sets, lane_hint)
 
 
-def _queued_behind_a_launch(rig, n: int, priority=PriorityClass.RANGE_SYNC, **pool_kwargs):
+def _queued_behind_a_launch(rig, n: int, priority=PriorityClass.RANGE_SYNC):
     """`n` one-set jobs of one class, all queued while the first holds
     the lane (a bulk class: one job a package): (verdicts, pipeline
     stats, pool metrics)."""
 
     async def go():
         pool = BlsDeviceVerifierPool(
-            mesh=rig.mesh, scheduler_enabled=True, prep_fn=_parse_that_takes_a_while, **pool_kwargs
+            mesh=rig.mesh, scheduler_enabled=True, prep_fn=_parse_that_takes_a_while
         )
         futs = [
             asyncio.ensure_future(
@@ -324,17 +311,16 @@ def _queued_behind_a_launch(rig, n: int, priority=PriorityClass.RANGE_SYNC, **po
     return _run(go())
 
 
-def test_auto_single_lane_stages_the_host_parse_under_the_single_launch(single_launch):
-    """One lane, `--bls-pipeline auto`, the single launch active: the
-    staged prep is host-only, so the pool stages the next package while
-    the lane runs this one. The first package finds the lane free and
-    has nothing to hide behind (inline); the rest go through the
-    prepared seam, in the order an unpipelined pool launches them."""
-    single_launch("on")
-    rig = FakeLaneRig(1, call_s=0.03, with_prepared=True, with_sharded=False)
+def test_single_lane_stages_the_host_parse_under_the_single_launch():
+    """One lane whose staged prep is host-only (the single launch's): the
+    pool stages the next package while the lane runs this one. The
+    first package finds the lane free and has nothing to hide behind
+    (inline); the rest go through the prepared seam, in the order a
+    pool over a plain lane, which cannot stage, launches them."""
+    rig = _host_parse_rig(call_s=0.03)
     ok, stats, metrics = _queued_behind_a_launch(rig, 5)
-    off = FakeLaneRig(1, call_s=0.03, with_prepared=True, with_sharded=False)
-    ok_off, stats_off, metrics_off = _queued_behind_a_launch(off, 5, pipeline="off")
+    off = FakeLaneRig(1, call_s=0.03, with_sharded=False)
+    ok_off, stats_off, metrics_off = _queued_behind_a_launch(off, 5)
     assert ok == ok_off == [True] * 5
     assert rig.calls == off.calls  # identical lane/size launch sequence
     assert stats["pipeline_enabled"] is True and stats_off["pipeline_enabled"] is False
@@ -346,28 +332,23 @@ def test_auto_single_lane_stages_the_host_parse_under_the_single_launch(single_l
     assert metrics_off["parse_ns"] == 0 == metrics_off["parse_hidden_ns"]
 
 
-def test_auto_single_lane_split_schedule_stages_nothing(single_launch):
-    """The same pool with the single launch configured off: staged prep
-    would be device launches on the die that verifies, so "auto" stays
-    off, and follows the mode when it flips (read per package)."""
-    single_launch("off")
+def test_single_lane_split_schedule_stages_nothing():
+    """The same lane as the split schedule builds it (it takes staged
+    inputs, but they are device launches on the die that verifies): the
+    pool stages nothing."""
     rig = FakeLaneRig(1, call_s=0.01, with_prepared=True, with_sharded=False)
     ok, stats, metrics = _queued_behind_a_launch(rig, 4)
     assert ok == [True] * 4
     assert stats["pipeline_enabled"] is False and stats["staged_packages"] == 0
     assert rig.prepared_calls == [] and metrics["parse_ns"] == 0
-    single_launch("on")
-    ok, stats, _ = _queued_behind_a_launch(rig, 4)
-    assert ok == [True] * 4 and stats["pipeline_enabled"] is True and stats["staged_packages"] == 3
 
 
 @pytest.mark.parametrize("can_shard", [False, True], ids=["mesh-cannot-shard", "mesh-can-shard"])
-def test_bulk_packages_are_staged_where_the_mesh_cannot_shard(single_launch, can_shard):
+def test_bulk_packages_are_staged_where_the_mesh_cannot_shard(can_shard):
     """A RANGE_SYNC package keeps its inline prep only for the
     collective road's sake: on a mesh that cannot shard it is staged
     like any other class."""
-    single_launch("on")
-    rig = FakeLaneRig(1, call_s=0.03, with_prepared=True, with_sharded=can_shard)
+    rig = _host_parse_rig(call_s=0.03, with_sharded=can_shard)
     ok, stats, _ = _queued_behind_a_launch(rig, 4, priority=PriorityClass.RANGE_SYNC)
     assert ok == [True] * 4 and rig.sharded_calls == []  # one lane never shards; the stage only asks
     assert stats["staged_packages"] == (0 if can_shard else 3)
@@ -380,7 +361,7 @@ def _overtaking(staged_class, arriving_class, aging_ms=None, staged_sets=1):
     package's worth), and then, during that launch, a job of
     `arriving_class`: the tags in the order the lane served them (0 in
     flight, 1 staged, 2 the late arrival)."""
-    rig = FakeLaneRig(1, call_s=0.25, with_prepared=True, with_sharded=False)
+    rig = _host_parse_rig(call_s=0.25)
     served = []
 
     def serve(sets):
@@ -392,7 +373,7 @@ def _overtaking(staged_class, arriving_class, aging_ms=None, staged_sets=1):
 
     async def go():
         pool = BlsDeviceVerifierPool(
-            mesh=rig.mesh, scheduler_enabled=True, pipeline="on", prep_fn=FakeLaneRig.prep_fn,
+            mesh=rig.mesh, scheduler_enabled=True, prep_fn=FakeLaneRig.prep_fn,
             **({} if aging_ms is None else {"aging_ms": aging_ms}),
         )
 
@@ -403,11 +384,11 @@ def _overtaking(staged_class, arriving_class, aging_ms=None, staged_sets=1):
         futs = [submit(0, staged_class)]
         await asyncio.sleep(0.01)  # a package of its own, whatever its class
         futs.append(submit(1, staged_class, staged_sets))
-        for _ in range(40):  # 0 holds the lane; 1 is in hand, its parse under way or done
-            if pool.pipeline_stats()["staged_packages"] == 2:
+        for _ in range(40):  # 0 holds the lane (inline: it found it free); 1 is in hand, its parse under way or done
+            if pool.pipeline_stats()["staged_packages"] == 1:
                 break
             await asyncio.sleep(0.005)
-        assert pool.pipeline_stats()["staged_packages"] == 2 and served == []
+        assert pool.pipeline_stats()["staged_packages"] == 1 and served == []
         futs.append(submit(2, arriving_class))
         assert all(await asyncio.gather(*futs))
         await pool.close()
@@ -441,16 +422,18 @@ def test_an_urgent_arrival_overtakes_the_package_taken_ahead(
     assert _overtaking(staged_class, arriving_class, aging_ms, staged_sets) == want
 
 
-def _arrivals_during_a_launch(pipeline: str) -> list:
+def _arrivals_during_a_launch(staged: bool) -> list:
     """One attestation starts a launch; one arrives 5 ms into it and
-    nine more during it: the lane's launches, (lane, sets) each."""
-    rig = FakeLaneRig(1, call_s=0.3, with_prepared=True, with_sharded=False)
+    nine more during it: the lane's launches, (lane, sets) each. On a
+    lane the pool stages behind, or on a plain one."""
+    rig = _host_parse_rig(call_s=0.3) if staged else FakeLaneRig(1, call_s=0.3, with_sharded=False)
 
     async def go():
         pool = BlsDeviceVerifierPool(
-            mesh=rig.mesh, scheduler_enabled=True, pipeline=pipeline,
-            prep_fn=FakeLaneRig.prep_fn, buffer_wait_ms=1,
+            mesh=rig.mesh, scheduler_enabled=True,
+            prep_fn=FakeLaneRig.prep_fn if staged else None, buffer_wait_ms=1,
         )
+        assert pool.pipeline_stats()["pipeline_enabled"] is staged
 
         def submit(tag):
             return asyncio.ensure_future(pool.verify_signature_sets(
@@ -470,26 +453,23 @@ def _arrivals_during_a_launch(pipeline: str) -> list:
     return rig.calls
 
 
-@pytest.mark.parametrize("pipeline", ["auto", "on"])
-def test_arrivals_during_a_launch_ride_the_next_launch_together(single_launch, pipeline):
+def test_arrivals_during_a_launch_ride_the_next_launch_together():
     """Open-loop traffic of one class (gossip attestations): what arrives
     while the lane is busy is ONE package when it frees, staged or not.
     The dispatcher must not take the first arrival out of the queue
     alone, ahead of the lane, and leave the rest a launch behind."""
-    single_launch("on")
-    staged = _arrivals_during_a_launch(pipeline)
-    assert staged == _arrivals_during_a_launch("off")
+    staged = _arrivals_during_a_launch(True)
+    assert staged == _arrivals_during_a_launch(False)
     assert [n for _lane, n in staged] == [1, 10]
 
 
-def test_a_parse_the_launch_thread_waits_for_is_not_hidden(single_launch):
+def test_a_parse_the_launch_thread_waits_for_is_not_hidden():
     """`parse_hidden_ns` counts parse time under a launch actually
     dispatched. A two-unit package whose parse (100 ms a unit) outlasts
     its launches (20 ms): the second parse has the first launch to hide
     behind and no more, though the launch thread is in the package's
     verify all the while it waits for the hand-over."""
-    single_launch("on")
-    rig = FakeLaneRig(1, call_s=0.02, with_prepared=True, with_sharded=False)
+    rig = _host_parse_rig(call_s=0.02)
 
     def slow_prep(sets, lane_hint):
         time.sleep(0.1)
@@ -562,14 +542,13 @@ def test_a_package_is_formed_when_no_arrival_can_join_it(cls, sizes, kwargs, for
     assert _formed(cls, sizes, **kwargs) == (formed, package)
 
 
-def test_staged_hand_over_under_thread_switching_stress(single_launch):
+def test_staged_hand_over_under_thread_switching_stress():
     """Prep thread, launch threads and the loop hand units to each other
     under a 10 us switch interval: every job gets its own verdict, every
     launch went through the staged seam or the inline road and never
     both, and the hidden parse never exceeds the parse."""
     import sys
 
-    single_launch("on")
     rig = FakeLaneRig(2, with_prepared=True, with_sharded=False)
     rig.verdict_fn = lambda sets: all(s.message[1] != 13 for s in sets)
     rng = random.Random(7)
@@ -601,11 +580,10 @@ def test_staged_hand_over_under_thread_switching_stress(single_launch):
     assert metrics["jobs_started"] == len(tags) and metrics["errors"] == 0
 
 
-def test_close_with_a_unit_half_staged_strands_no_future(single_launch):
+def test_close_with_a_unit_half_staged_strands_no_future():
     """close() while the package in hand is still in its parse: its
     futures fail, the launch in flight resolves, nothing is left."""
-    single_launch("on")
-    rig = FakeLaneRig(1, call_s=0.1, with_prepared=True, with_sharded=False)
+    rig = _host_parse_rig(call_s=0.1)
     parsing = threading.Event()
 
     def slow_prep(sets, lane_hint):
@@ -630,33 +608,6 @@ def test_close_with_a_unit_half_staged_strands_no_future(single_launch):
     assert all(f.done() for f in futs)
     assert len(rig.calls) == 1 and rig.prepared_calls == []  # the launch in flight; nothing staged was launched
     assert all(isinstance(r, asyncio.CancelledError) for r in results[1:]), results
-
-
-# -- mode wiring ---------------------------------------------------------------
-
-
-class TestPipelineModeWiring:
-    def test_pool_rejects_unknown_mode(self):
-        with pytest.raises(ValueError):
-            BlsDeviceVerifierPool(lambda sets: True, pipeline="bogus")
-
-    def test_cli_flag_accepts_exactly_the_pool_modes(self):
-        from lodestar_tpu import cli
-
-        ap = cli._build_parser()
-        for mode in PIPELINE_MODES:
-            args = ap.parse_args(["beacon", "--bls-pipeline", mode])
-            assert args.bls_pipeline == mode
-        with pytest.raises(SystemExit):
-            ap.parse_args(["beacon", "--bls-pipeline", "bogus"])
-
-    def test_node_options_validate_against_pool_modes(self):
-        from lodestar_tpu.node import BeaconNodeOptions
-
-        for mode in PIPELINE_MODES:
-            assert BeaconNodeOptions(bls_pipeline=mode).bls_pipeline == mode
-        with pytest.raises(ValueError):
-            BeaconNodeOptions(bls_pipeline="bogus")
 
 
 # -- review regressions --------------------------------------------------------
@@ -707,13 +658,12 @@ def test_dead_dispatcher_restarts_on_next_submit_and_fails_what_it_held():
     """A dispatcher that dies with a package in hand fails that
     package's futures (nobody else can see it) and the next submit
     starts a new one."""
-    rig = FakeLaneRig(1, call_s=0.3, with_prepared=True, with_sharded=False)
+    rig = _host_parse_rig(call_s=0.3)
 
     async def go():
         pool = BlsDeviceVerifierPool(
             mesh=rig.mesh,
             scheduler_enabled=True,
-            pipeline="on",
             prep_fn=FakeLaneRig.prep_fn,
         )
         futs = [
@@ -747,7 +697,7 @@ def test_pipeline_gauges_fresh_after_replay():
     from lodestar_tpu.metrics import create_metrics
 
     m = create_metrics()
-    rig = FakeLaneRig(1, call_s=0.05, with_prepared=True, with_sharded=False)
+    rig = _host_parse_rig(call_s=0.05)
 
     def slow_prep(sets, lane_hint):
         time.sleep(0.03)
@@ -757,7 +707,6 @@ def test_pipeline_gauges_fresh_after_replay():
         pool = BlsDeviceVerifierPool(
             mesh=rig.mesh,
             scheduler_enabled=True,
-            pipeline="on",
             prep_fn=slow_prep,
             pipeline_metrics=m.bls_pipeline,
         )
@@ -766,7 +715,9 @@ def test_pipeline_gauges_fresh_after_replay():
             jobs.append(
                 asyncio.ensure_future(
                     pool.verify_signature_sets(
-                        _sets(1, tag=i), VerifySignatureOpts(batchable=False)
+                        # a bulk class: a job is a package, taken ahead of the busy lane
+                        _sets(1, tag=i),
+                        VerifySignatureOpts(batchable=False, priority=PriorityClass.RANGE_SYNC),
                     )
                 )
             )
@@ -793,9 +744,9 @@ def test_pipeline_gauges_fresh_after_replay():
 
 
 def test_pipeline_gauges_read_zero_when_pipeline_never_engaged():
-    """An unpipelined pool (mode off) keeps all four gauges at their
-    zero/no-engagement values — the dashboard's '0 staged packages =
-    never engaged' read is trustworthy."""
+    """A pool that does not stage (one lane of the split schedule) keeps
+    all four gauges at their zero/no-engagement values — the dashboard's
+    '0 staged packages = never engaged' read is trustworthy."""
     from lodestar_tpu.metrics import create_metrics
 
     m = create_metrics()
@@ -805,7 +756,6 @@ def test_pipeline_gauges_read_zero_when_pipeline_never_engaged():
         pool = BlsDeviceVerifierPool(
             mesh=rig.mesh,
             scheduler_enabled=True,
-            pipeline="off",
             pipeline_metrics=m.bls_pipeline,
         )
         ok = await pool.verify_signature_sets(

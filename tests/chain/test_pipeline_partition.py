@@ -6,7 +6,12 @@ surviving lane, never strands, and the pipeline keeps serving.
 The kill is injected from inside the staged package's own prep call,
 which runs on an executor thread strictly between the two pipeline
 stages — the exact window the chaos harness's partition events cannot
-hit deterministically from outside."""
+hit deterministically from outside.
+
+Two lanes that take staged inputs: the pool stages every package of more
+than one launch unit (one unit that finds a lane free has no launch to
+hide its prep behind and is never staged), so each package here is two
+jobs or more."""
 
 from __future__ import annotations
 
@@ -54,7 +59,6 @@ def test_lane_killed_between_staging_and_dispatch_fails_over():
         pool = BlsDeviceVerifierPool(
             mesh=rig.mesh,
             scheduler_enabled=True,
-            pipeline="on",
             prep_fn=killing_prep,
         )
         jobs = [
@@ -92,19 +96,21 @@ def test_lane_killed_mid_pipeline_then_healed_serves_again():
         pool = BlsDeviceVerifierPool(
             mesh=rig.mesh,
             scheduler_enabled=True,
-            pipeline="on",
             prep_fn=prep,
         )
-        first = await pool.verify_signature_sets(_sets(2, tag=1), OPTS)
+        first = await asyncio.gather(
+            *[pool.verify_signature_sets(_sets(2, tag=i), OPTS) for i in range(2)]
+        )
+        assert state["n"] >= 1 and rig.served_by(1), "the kill must have fired from staging prep"
         with rig._record_lock:
             rig.failing.discard(0)
         rest = await asyncio.gather(
             *[pool.verify_signature_sets(_sets(2, tag=2 + i), OPTS) for i in range(4)]
         )
         await pool.close()
-        return [first, *rest]
+        return [*first, *rest]
 
-    assert asyncio.run(go()) == [True] * 5
+    assert asyncio.run(go()) == [True] * 6
 
 
 def test_all_lanes_partitioned_fails_closed_not_stranded():
@@ -122,18 +128,19 @@ def test_all_lanes_partitioned_fails_closed_not_stranded():
         pool = BlsDeviceVerifierPool(
             mesh=rig.mesh,
             scheduler_enabled=True,
-            pipeline="on",
             prep_fn=prep,
         )
         try:
-            fut = pool.verify_signature_sets(_sets(2, tag=9), OPTS)
-            verdict = await asyncio.wait_for(fut, timeout=10.0)
-            assert verdict in (True, False)
+            futs = asyncio.gather(
+                *[pool.verify_signature_sets(_sets(2, tag=9 + i), OPTS) for i in range(2)],
+                return_exceptions=True,  # a fail-closed error is an acceptable resolution
+            )
+            verdicts = await asyncio.wait_for(futs, timeout=10.0)
+            assert all(v is False or isinstance(v, Exception) for v in verdicts), verdicts
         except asyncio.TimeoutError:
             pytest.fail("staged future stranded with every lane dead")
-        except Exception:
-            pass  # fail-closed error is an acceptable resolution
         finally:
             await pool.close()
+        assert pool.pipeline_stats()["staged_packages"] == 1
 
     asyncio.run(go())

@@ -110,35 +110,3 @@ class TestShardedBatchVerify:
             pubkey=sets[5].pubkey, message=sets[5].message, signature=other.signature
         )
         assert verify_signature_sets_sharded(sets, mesh) is False
-
-
-class TestMultiJobVerify:
-    @pytest.mark.skipif(
-        not __import__("os").environ.get("LODESTAR_TPU_SLOW_TESTS"),
-        reason="vmapped multi-job program compiles for tens of minutes; "
-        "set LODESTAR_TPU_SLOW_TESTS=1 to include",
-    )
-    def test_vmapped_jobs_independent_verdicts(self):
-        """device_batch_verify_many: J stacked jobs, per-job verdicts —
-        a tampered job flips only its own lane."""
-        import numpy as np
-
-        from lodestar_tpu.models import batch_verify as bv
-
-        good = bv.make_synthetic_sets(2, seed=5)
-        bad = list(good)
-        other = bv.make_synthetic_sets(1, seed=6)[0]
-        from lodestar_tpu.crypto.bls.api import SignatureSet as _SS
-        bad[1] = _SS(
-            pubkey=bad[1].pubkey, message=bad[1].message, signature=other.signature
-        )
-        gi = bv.build_device_inputs(good)
-        bi = bv.build_device_inputs(bad)
-        stack = lambda a, b: tuple(np.stack([x, y]) for x, y in zip(a, b))
-        PK = stack(gi[0], bi[0])
-        H = stack(gi[1], bi[1])
-        SIG = stack(gi[2], bi[2])
-        B = np.stack([gi[3], bi[3]])
-        M = np.stack([gi[4], bi[4]])
-        ok = np.asarray(bv.device_batch_verify_many(PK, H, SIG, B, M))
-        assert ok.tolist() == [True, False]

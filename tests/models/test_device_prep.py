@@ -40,11 +40,18 @@ def sets4():
 
 
 @pytest.fixture(autouse=True)
-def _restore_prep_mode():
+def _restore_prep_seams():
     yield
-    bv.configure_device_prep(mode="auto")
     bv._prep_metrics = None
     bv.consume_prep_info()
+
+
+@pytest.fixture
+def device_prep(monkeypatch):
+    """Prep on the device, as an accelerator's split schedule (the single
+    launch's error road) runs it: the resolver answers as it does there,
+    and the tests call `_verify_sets_split`, that road's entry."""
+    monkeypatch.setattr(bv, "single_launch_active", lambda: True)
 
 
 class TestPrepareSetsDevice:
@@ -83,22 +90,20 @@ class TestPrepareSetsDevice:
 
 
 class TestVerifyWithDevicePrep:
-    def test_bytes_in_verdict_out(self, sets4):
-        bv.configure_device_prep(mode="on")
-        assert bv.verify_signature_sets_device(sets4) is True
+    def test_bytes_in_verdict_out(self, sets4, device_prep):
+        assert bv._verify_sets_split(sets4) is True
         info = bv.consume_prep_info()
         assert info is not None and info["layer"] == "device"
 
-    def test_tampered_signature_rejects(self, sets4):
-        bv.configure_device_prep(mode="on")
+    def test_tampered_signature_rejects(self, sets4, device_prep):
         bad = list(sets4)
         other = make_sets(1, seed=9)[0]
         bad[2] = SignatureSet(
             pubkey=bad[2].pubkey, message=bad[2].message, signature=other.signature
         )
-        assert bv.verify_signature_sets_device(bad) is False
+        assert bv._verify_sets_split(bad) is False
 
-    def test_no_host_bigint_math_on_device_path(self, sets4, monkeypatch):
+    def test_no_host_bigint_math_on_device_path(self, sets4, device_prep, monkeypatch):
         """The device-prep path must not touch the python big-int
         pipeline (hash_to_g2 / point decompression / subgroup checks) or
         the native C++ prep — stub them all to raise."""
@@ -111,69 +116,64 @@ class TestVerifyWithDevicePrep:
         monkeypatch.setattr(bv, "hash_to_g2", _boom)
         monkeypatch.setattr(bv, "g1_from_bytes", _boom)
         monkeypatch.setattr(bv, "g2_from_bytes", _boom)
-        bv.configure_device_prep(mode="on")
-        assert bv.verify_signature_sets_device(sets4) is True
+        assert bv._verify_sets_split(sets4) is True
 
-    def test_device_error_falls_back_to_host(self, sets4, monkeypatch):
+    def test_device_error_falls_back_to_host(self, sets4, device_prep, monkeypatch):
         from lodestar_tpu.metrics import create_metrics
 
         metrics = create_metrics()
-        bv.configure_device_prep(mode="on", metrics=metrics.bls_prep)
+        bv.configure_device_prep(metrics.bls_prep)
 
         def _boom(*a, **k):
             raise RuntimeError("injected device prep fault")
 
         monkeypatch.setattr(bv, "_prepare_sets_device_arrays", _boom)
-        assert bv.verify_signature_sets_device(sets4) is True
+        assert bv._verify_sets_split(sets4) is True
         info = bv.consume_prep_info()
         assert info is not None and info["layer"] == "host"
         assert metrics.bls_prep.fallbacks._value.get() == 1
 
-    def test_host_path_with_prep_off(self, sets4):
-        bv.configure_device_prep(mode="off")
+    def test_host_path_off_an_accelerator(self, sets4):
         assert bv.verify_signature_sets_device(sets4) is True
         info = bv.consume_prep_info()
         assert info is not None and info["layer"] == "host"
 
 
-class TestModeWiring:
-    def test_cli_flag_accepts_exactly_the_model_modes(self):
-        """The CLI keeps a literal copy of the mode choices (argparse must
-        not import jax); this ties it to the model layer's canonical set."""
-        from lodestar_tpu import cli
-
-        ap = cli._build_parser()
-        for mode in bv.PREP_MODES:
-            args = ap.parse_args(["beacon", "--bls-device-prep", mode])
-            assert args.bls_device_prep == mode
-        with pytest.raises(SystemExit):
-            ap.parse_args(["beacon", "--bls-device-prep", "bogus"])
-
-    def test_node_options_validate_against_model_modes(self):
-        from lodestar_tpu.node import BeaconNodeOptions
-
-        for mode in bv.PREP_MODES:
-            assert BeaconNodeOptions(bls_device_prep=mode).bls_device_prep == mode
-        with pytest.raises(ValueError):
-            BeaconNodeOptions(bls_device_prep="bogus")
-
-
 class TestPoolWithDevicePrep:
-    def test_pool_verdicts_both_modes(self, sets4):
+    def test_pool_verdicts_both_prep_layers(self, sets4, monkeypatch):
+        """The default pool on this backend (host prep), and a pool over
+        the split schedule's lane with prep on the device."""
         from lodestar_tpu.chain.bls.interface import VerifySignatureOpts
+        from lodestar_tpu.chain.bls.mesh import single_lane_mesh
         from lodestar_tpu.chain.bls.pool import BlsDeviceVerifierPool
+        from lodestar_tpu.metrics import create_metrics
+        from lodestar_tpu.ops import prep as dp
 
-        async def run(mode):
-            bv.configure_device_prep(mode=mode)
-            pool = BlsDeviceVerifierPool()
+        metrics = create_metrics()
+        bv.configure_device_prep(metrics.bls_prep)
+
+        async def run(**pool_kwargs):
+            pool = BlsDeviceVerifierPool(**pool_kwargs)
             ok = await pool.verify_signature_sets(
                 sets4, VerifySignatureOpts(batchable=False)
             )
             await pool.close()
             return ok
 
-        assert asyncio.run(run("on")) is True
-        assert asyncio.run(run("off")) is True
+        def prepped(layer):
+            return metrics.bls_prep.sets.labels(layer)._value.get()
+
+        try:
+            assert asyncio.run(run()) is True
+            assert (prepped("host"), prepped("device")) == (4, 0)
+            monkeypatch.setattr(bv, "single_launch_active", lambda: True)
+            split_lane = single_lane_mesh(
+                bv._verify_sets_split, verify_prepared_fn=bv.verify_prepared
+            )
+            assert asyncio.run(run(mesh=split_lane)) is True
+            assert (prepped("host"), prepped("device")) == (4, 4)
+        finally:
+            dp.configure_launch_counter(None)
 
     def test_bls_prep_span_recorded(self, sets4):
         """Satellite: the pool stamps a bls_prep span per traced job with
@@ -185,7 +185,6 @@ class TestPoolWithDevicePrep:
         tracer = tracing.reset()
         tracing.configure(enabled=True, slow_slot_ms=1e9)
         try:
-            bv.configure_device_prep(mode="off")
 
             async def run():
                 pool = BlsDeviceVerifierPool()

@@ -110,12 +110,11 @@ def test_prepare_counts_the_real_sets(sets):
     from lodestar_tpu.metrics import create_metrics
 
     metrics = create_metrics()
-    prev = bv.configure_device_prep(metrics=metrics.bls_prep)
+    bv.configure_device_prep(metrics.bls_prep)
     try:
         bv.prepare_grouped_launch_inputs([sets[:3], sets[3:5]])
     finally:
         dp.configure_launch_counter(None)
-        bv.configure_device_prep(mode=prev)
         bv._prep_metrics = None
         bv.consume_prep_info()
     assert metrics.bls_prep.sets.labels("single_launch")._value.get() == 5
@@ -129,10 +128,9 @@ def prep_metrics():
     from lodestar_tpu.metrics import create_metrics
 
     metrics = create_metrics()
-    prev = bv.configure_device_prep(metrics=metrics.bls_prep)
+    bv.configure_device_prep(metrics.bls_prep)
     yield metrics.bls_prep
     dp.configure_launch_counter(None)
-    bv.configure_device_prep(mode=prev)
     bv._prep_metrics = None
     bv.consume_prep_info()
 

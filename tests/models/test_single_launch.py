@@ -1,4 +1,4 @@
-"""Single-launch verification (`--bls-single-launch`): the whole chain —
+"""Single-launch verification (an accelerator's schedule): the whole chain —
 decompression, subgroup checks, hash-to-G2, RLC aggregation, Miller
 loop, final exponentiation — as ONE resident device program.
 
@@ -27,8 +27,14 @@ Tests that compile or dispatch the REAL single-launch program are
 marked ``slow``: its XLA compile alone is ~40 s on the CPU container
 and the tier-1 suite runs at ~825 s of an 870 s budget, so every real
 dispatch of the big program rides the slow lane (run with
-``pytest -m slow`` / no marker filter). The zero-launch, injected-fault
-degradation, and mode/CLI wiring assertions stay tier-1.
+``pytest -m slow`` / no marker filter). The zero-launch and
+injected-fault degradation assertions stay tier-1, and so does the one
+about who decides: `verify_signature_sets_device` follows the backend.
+
+The CPU backend runs the other schedule, so the tests force this one at
+the call: the single-launch entry points directly, and `single_on`
+patches the resolver where a road asks it (the error road's device prep,
+`prepare_inputs_for_lane`, the lane-pinned entry).
 """
 
 from __future__ import annotations
@@ -42,15 +48,20 @@ from lodestar_tpu.models import batch_verify as bv
 from lodestar_tpu.ops import prep as dp
 
 from tests.crypto.rfc9380_vectors import RFC9380_G2_RO_VECTORS
-from tests.ops.test_prep import _g1_noncurve_x, _g1_offsubgroup_point, _g2_offsubgroup_point
+from tests.ops.test_prep import (
+    _g1_noncurve_x,
+    _g1_offsubgroup_point,
+    _g2_offsubgroup_point,
+    prepare_sets_unfused,
+)
 from tests.ops.util import rng
 
 
 @pytest.fixture
-def single_on():
-    prev = bv.configure_single_launch(mode="on")
-    yield
-    bv.configure_single_launch(mode=prev)
+def single_on(monkeypatch):
+    """The schedule an accelerator runs, on this backend: the resolver
+    answers as it does there."""
+    monkeypatch.setattr(bv, "single_launch_active", lambda: True)
 
 
 def _split_verdict(sets, fused: bool) -> bool:
@@ -58,7 +69,9 @@ def _split_verdict(sets, fused: bool) -> bool:
     5-launch unfused per-leg prep, then the RLC verify dispatch)."""
     n = len(sets)
     size = bv._pad_pow2(n)
-    pk, h, sig, ok = bv._prepare_sets_device_arrays(sets, size, fused=fused)
+    pk, h, sig, ok = (
+        bv._prepare_sets_device_arrays(sets, size) if fused else prepare_sets_unfused(sets)
+    )
     if not ok:
         return False
     inputs = bv._finish_inputs(pk, h, sig, n, size)
@@ -92,7 +105,7 @@ class TestSingleLaunchBudget:
     @pytest.mark.slow
     def test_mode_router_serves_single_launch(self, single_on):
         """`verify_signature_sets_device` (the pool/mesh backend) routes
-        through the single-launch program while the mode is active."""
+        through the single-launch program where the resolver says so."""
         sets = bv.make_synthetic_sets(3, seed=113)
         base = dp.prep_launches_total()
         assert bv.verify_signature_sets_device(sets) is True
@@ -250,7 +263,7 @@ class TestSingleLaunchDegradation:
         from lodestar_tpu.metrics import create_metrics
 
         metrics = create_metrics()
-        prev_prep = bv.configure_device_prep(mode="on", metrics=metrics.bls_prep)
+        bv.configure_device_prep(metrics.bls_prep)
 
         def boom(*a, **k):
             raise RuntimeError("injected single-launch device fault")
@@ -262,7 +275,6 @@ class TestSingleLaunchDegradation:
             assert bv.verify_sets_single_launch(sets) is True
         finally:
             dp.configure_launch_counter(None)
-            bv.configure_device_prep(mode=prev_prep)
             bv._prep_metrics = None
             bv.consume_prep_info()
         assert metrics.bls_prep.single_launch_fallbacks._value.get() == 1
@@ -279,7 +291,7 @@ class TestSingleLaunchDegradation:
         from lodestar_tpu.metrics import create_metrics
 
         metrics = create_metrics()
-        prev_prep = bv.configure_device_prep(mode="on", metrics=metrics.bls_prep)
+        bv.configure_device_prep(metrics.bls_prep)
 
         def boom(*a, **k):
             raise RuntimeError("injected host-parse fault")
@@ -292,7 +304,6 @@ class TestSingleLaunchDegradation:
             assert bv.verify_sets_single_launch(sets) is True
         finally:
             dp.configure_launch_counter(None)
-            bv.configure_device_prep(mode=prev_prep)
             bv._prep_metrics = None
             bv.consume_prep_info()
         assert metrics.bls_prep.single_launch_fallbacks._value.get() == 1
@@ -308,7 +319,7 @@ class TestSingleLaunchDegradation:
         from lodestar_tpu.metrics import create_metrics
 
         metrics = create_metrics()
-        prev_prep = bv.configure_device_prep(mode="on", metrics=metrics.bls_prep)
+        bv.configure_device_prep(metrics.bls_prep)
 
         def flaky(*a, **k):
             raise RuntimeError("injected single-launch device fault")
@@ -323,7 +334,6 @@ class TestSingleLaunchDegradation:
             assert dp.prep_launches_total() - base == 1 + dp.FUSED_PREP_LAUNCHES
         finally:
             dp.configure_launch_counter(None)
-            bv.configure_device_prep(mode=prev_prep)
             bv._prep_metrics = None
             bv.consume_prep_info()
         assert metrics.bls_prep.single_launch_fallbacks._value.get() == 1
@@ -338,7 +348,7 @@ class TestSingleLaunchDegradation:
         from lodestar_tpu.metrics import create_metrics
 
         metrics = create_metrics()
-        prev_prep = bv.configure_device_prep(mode="on", metrics=metrics.bls_prep)
+        bv.configure_device_prep(metrics.bls_prep)
         sets = bv.make_synthetic_sets(2, seed=149)
         try:
             for anomalous in (
@@ -349,7 +359,6 @@ class TestSingleLaunchDegradation:
                 assert bv.verify_sets_single_launch(sets) is True
         finally:
             dp.configure_launch_counter(None)
-            bv.configure_device_prep(mode=prev_prep)
             bv._prep_metrics = None
             bv.consume_prep_info()
         assert metrics.bls_prep.single_launch_fallbacks._value.get() == 2
@@ -358,7 +367,7 @@ class TestSingleLaunchDegradation:
 class TestSingleLaunchStaging:
     @pytest.mark.slow
     def test_prepare_inputs_for_lane_stages_host_parse_only(self, single_on):
-        """The pipelined prep stage under single-launch mode is byte
+        """The pipelined prep stage under the single launch is byte
         work only (zero dispatches); verify_prepared runs the ONE
         launch — host parse of batch k+1 can overlap the launch of k."""
         sets = bv.make_synthetic_sets(3, seed=151)
@@ -380,53 +389,28 @@ class TestSingleLaunchStaging:
         assert dp.prep_launches_total() - base == 0
 
     @pytest.mark.slow
-    def test_lane_pinned_single_fn(self, single_on):
-        """`make_lane_verify_single_fn` serves the one-launch road
-        pinned to a device (the mesh lane seam)."""
-        fn = bv.make_lane_verify_single_fn(0)
+    def test_lane_pinned_verify_fn_is_the_one_launch_road(self, single_on):
+        """`make_lane_verify_fn` serves the one-launch road pinned to a
+        device (the mesh lane seam)."""
+        fn = bv.make_lane_verify_fn(0)
         sets = bv.make_synthetic_sets(2, seed=163)
         base = dp.prep_launches_total()
         assert fn(sets) is True
         assert dp.prep_launches_total() - base == 1
 
 
-class TestSingleLaunchModeWiring:
-    def test_configure_rejects_unknown_mode(self):
-        with pytest.raises(ValueError):
-            bv.configure_single_launch(mode="bogus")
+@pytest.mark.parametrize("accelerator", [True, False], ids=["accelerator", "cpu"])
+def test_the_device_entry_follows_the_backend(monkeypatch, accelerator):
+    """Which schedule `verify_signature_sets_device` runs is what the
+    backend is (the Pallas backend live or not), asked where the verdict
+    is made and settable nowhere: the single launch on an accelerator,
+    the split schedule otherwise."""
+    from lodestar_tpu.ops import fp_pallas
 
-    def test_auto_follows_pallas_unless_prep_pinned_off(self):
-        """auto follows the Pallas backend (dead on this container →
-        False) and an explicit device-prep "off" pin keeps it off; prep
-        "on" — the tests'/benches' force-the-prep-stages knob — must
-        NOT flip single launch on behind existing prep-on callers."""
-        prev = bv.configure_device_prep(mode="off")
-        try:
-            assert bv.single_launch_active("auto") is False  # prep pinned off
-            bv.configure_device_prep(mode="on")
-            # prep on does not force: auto still follows Pallas (dead here)
-            assert bv.single_launch_active("auto") is False
-        finally:
-            bv.configure_device_prep(mode=prev)
-        assert bv.single_launch_active("on") is True
-        assert bv.single_launch_active("off") is False
-
-    def test_cli_flag_accepts_exactly_the_model_modes(self):
-        from lodestar_tpu import cli
-
-        ap = cli._build_parser()
-        for mode in bv.SINGLE_LAUNCH_MODES:
-            args = ap.parse_args(["beacon", "--bls-single-launch", mode])
-            assert args.bls_single_launch == mode
-        with pytest.raises(SystemExit):
-            ap.parse_args(["beacon", "--bls-single-launch", "bogus"])
-
-    def test_node_options_validate_against_model_modes(self):
-        from lodestar_tpu.node import BeaconNodeOptions
-
-        for mode in bv.SINGLE_LAUNCH_MODES:
-            assert (
-                BeaconNodeOptions(bls_single_launch=mode).bls_single_launch == mode
-            )
-        with pytest.raises(ValueError):
-            BeaconNodeOptions(bls_single_launch="bogus")
+    went = []
+    monkeypatch.setattr(fp_pallas, "use_pallas", lambda: accelerator)
+    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda sets: went.append("single") or True)
+    monkeypatch.setattr(bv, "_verify_sets_split", lambda sets: went.append("split") or True)
+    assert bv.single_launch_active() is accelerator
+    assert bv.verify_signature_sets_device(bv.make_synthetic_sets(2, seed=167)) is True
+    assert went == ["single" if accelerator else "split"]

@@ -3,12 +3,15 @@ handshake -> session flow, PING/PONG, FINDNODE/NODES, and multi-node
 discovery feeding PeerDiscovery's enr_source seam."""
 
 import asyncio
+import hashlib
 
 import pytest
 
 from lodestar_tpu.network.discv5 import Discv5Node, Enr, log2_distance
 
 from cryptography.hazmat.primitives.asymmetric import ec
+
+_SECP256K1_ORDER = 0xFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFFEBAAEDCE6AF48A03BBFD25E8CD0364141
 
 
 def test_enr_roundtrip_and_signature():
@@ -62,17 +65,33 @@ def test_handshake_ping_findnode():
 
 def test_three_node_discovery():
     """C is only known to B; A discovers C via FINDNODE through B and
-    can then talk to C directly."""
+    can then talk to C directly.
+
+    The keys are fixed, not drawn: ``bootstrap`` asks B for the distance
+    bands {253..256, d(A,B), d(A,B)-1}, so A hears of C only if C sits at
+    log2 distance >= 253 from B. Three random keys put it there 15 times
+    in 16; these three put it at 256 every time. They are full-width
+    scalars, as a node's own are: the handshake's blinded ECDH ladder
+    assumes one (a key of 2 fails it every other time)."""
+    key_a, key_b, key_c = (
+        ec.derive_private_key(
+            int.from_bytes(hashlib.sha256(b"test_three_node_discovery/0/" + name).digest(), "big")
+            % _SECP256K1_ORDER,
+            ec.SECP256K1(),
+        )
+        for name in (b"A", b"B", b"C")
+    )
 
     async def run():
-        b = Discv5Node()
+        b = Discv5Node(private_key=key_b)
         await b.start()
-        c = Discv5Node(bootnodes=[])
+        c = Discv5Node(private_key=key_c, bootnodes=[])
         await c.start()
+        assert log2_distance(b.enr.node_id, c.enr.node_id) >= 253
         try:
             # C introduces itself to B (handshake fills B's table)
             assert await c.ping(b.enr)
-            a = Discv5Node(bootnodes=[b.enr])
+            a = Discv5Node(private_key=key_a, bootnodes=[b.enr])
             await a.start()
             try:
                 n = await a.bootstrap(rounds=2)

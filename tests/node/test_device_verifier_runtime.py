@@ -73,10 +73,10 @@ def test_device_pool_is_the_node_verifier(minimal_preset):
         build = _mk_node_and_validator(minimal_preset, use_device=True)
         node, validator = await build()
         assert isinstance(node.bls, BlsDeviceVerifierPool)
-        # the pool's verify_fn is the real device pipeline
+        # the pool's lane is the real device pipeline
         from lodestar_tpu.models.batch_verify import verify_signature_sets_device
 
-        assert node.bls._verify_fn is verify_signature_sets_device
+        assert [lane.verify_fn for lane in node.bls.mesh.lanes] == [verify_signature_sets_device]
 
         before = dict(node.bls.metrics)
         # two slots of real duties: proposals import via process_block

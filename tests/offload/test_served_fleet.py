@@ -22,7 +22,6 @@ from lodestar_tpu.chain.bls import BlsDeviceVerifierPool, VerifySignatureOpts
 from lodestar_tpu.chain.bls.mesh import MeshLane, VerifierMesh
 from lodestar_tpu.crypto.bls.api import SignatureSet, verify_signature_sets
 from lodestar_tpu.metrics import create_metrics
-from lodestar_tpu.models import batch_verify as bv
 from lodestar_tpu.node import BeaconNodeOptions, _offload_verifier
 from lodestar_tpu.offload import encode_sets, known_answer
 from lodestar_tpu.offload import server as offload_server
@@ -71,17 +70,15 @@ class OracleLane:
 
 @pytest.fixture
 def seams():
-    """`boot_host` writes process-global seams (single-launch mode, the
-    launch ledger's sink)."""
-    prev = bv.configure_single_launch()
+    """`boot_host` writes a process-global seam (the launch ledger's
+    sink)."""
     yield
-    bv.configure_single_launch(mode=prev)
     telemetry.reset_launch_telemetry()
 
 
 def boot(lane: OracleLane, **kw):
     return offload_server.boot_host(
-        port=kw.pop("port", 0), bls_single_launch="on",
+        port=kw.pop("port", 0),
         pool_factory=lambda: BlsDeviceVerifierPool(mesh=lane.mesh), **kw,
     )
 

@@ -64,6 +64,23 @@ def _g2_offsubgroup_point(r):
             return pt
 
 
+def prepare_sets_unfused(sets):
+    """`bv._prepare_sets_device_arrays` over the unfused per-leg
+    reference (`prep.prepare_arrays_unfused`): the same host parse, the
+    same validity verdict, (pk, h, sig, ok) on size-padded arrays."""
+    from lodestar_tpu.models import batch_verify as bv
+
+    n = len(sets)
+    pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi = bv._parse_host_arrays(
+        sets, bv._pad_pow2(n)
+    )
+    pk, pk_ok, sig, sig_ok, h = prep.prepare_arrays_unfused(
+        pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi
+    )
+    valid = pk_struct & sig_struct & np.asarray(pk_ok) & np.asarray(sig_ok)
+    return pk, h, sig, bool(valid[:n].all())
+
+
 def _g2_nontwist_x(r):
     while True:
         x = (_rand_fp(r), _rand_fp(r))
@@ -280,16 +297,16 @@ class TestFusedPrepSchedule:
 
         sets = bv.make_synthetic_sets(5, seed=23)
         base = prep.prep_launches_total()
-        fused = bv.prepare_sets_device(sets, fused=True)
+        fused = bv.prepare_sets_device(sets)
         assert prep.prep_launches_total() - base == prep.FUSED_PREP_LAUNCHES
         base = prep.prep_launches_total()
-        unfused = bv.prepare_sets_device(sets, fused=False)
+        *unfused, unfused_ok = prepare_sets_unfused(sets)
         assert prep.prep_launches_total() - base == prep.UNFUSED_PREP_LAUNCHES
-        assert fused is not None and unfused is not None
+        assert fused is not None and unfused_ok
         for leg_f, leg_u in zip(fused, unfused):
             for coord in range(2):
                 ff = np.asarray(fp.from_mont(leg_f[coord]))
-                uu = np.asarray(fp.from_mont(leg_u[coord]))
+                uu = np.asarray(fp.from_mont(leg_u[coord][: len(sets)]))
                 assert (ff == uu).all()
 
     def test_rfc9380_g2_known_answer_through_fused_stage(self):
@@ -319,7 +336,7 @@ class TestFusedPrepSchedule:
         from lodestar_tpu.models import batch_verify as bv
 
         metrics = create_metrics()
-        prev = bv.configure_device_prep(metrics=metrics.bls_prep)
+        bv.configure_device_prep(metrics.bls_prep)
         try:
             sets = bv.make_synthetic_sets(4, seed=29)
             assert bv.prepare_sets_device(sets) is not None
@@ -328,5 +345,4 @@ class TestFusedPrepSchedule:
             )
         finally:
             prep.configure_launch_counter(None)
-            bv.configure_device_prep(mode=prev)
             bv._prep_metrics = None

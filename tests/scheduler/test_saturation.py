@@ -89,7 +89,6 @@ def test_gossip_block_waits_out_one_multi_job_bulk_launch():
     package of them in ONE launch: four jobs ride the in-flight launch,
     and the block is served next, ahead of the queued rest."""
     from lodestar_tpu.chain.bls.mesh import MeshLane, VerifierMesh
-    from lodestar_tpu.models import batch_verify as bv
 
     launches: list[list[int]] = []
 
@@ -123,11 +122,7 @@ def test_gossip_block_waits_out_one_multi_job_bulk_launch():
         await asyncio.gather(*bulk, block)
         await pool.close()
 
-    prev = bv.configure_single_launch(mode="on")
-    try:
-        asyncio.run(go())
-    finally:
-        bv.configure_single_launch(mode=prev)
+    asyncio.run(go())
     # one bulk launch in flight when the block arrives, the block's next
     assert launches == [[66] * 4, [1], [66] * 2], launches
     assert done.index("block") == 4, done

@@ -226,10 +226,11 @@ class TestPrepSeam:
     def test_unfused_prep_lands_five_launches(self, tel):
         from lodestar_tpu.models import batch_verify as bv
         from lodestar_tpu.ops import prep
+        from tests.ops.test_prep import prepare_sets_unfused
 
         sets = bv.make_synthetic_sets(2, seed=5)
         base = len(tel.launch_ledger())
-        assert bv.prepare_sets_device(sets, fused=False) is not None
+        assert prepare_sets_unfused(sets)[-1]
         entries = tel.launch_ledger()[base:]
         assert len(entries) == prep.UNFUSED_PREP_LAUNCHES == 5
         assert [e["program"] for e in entries] == [
@@ -248,7 +249,7 @@ class TestSingleLaunchSeam:
     @pytest.mark.slow  # compiles the real single-launch program (~40 s
     # XLA compile on the CPU container — over tier-1's remaining budget)
     def test_one_record_per_batch_with_program_and_size_class(self, tel):
-        """A `--bls-single-launch on` verified batch lands in the ledger
+        """A batch verified by the single launch lands in the ledger
         as EXACTLY one record carrying the program's own name and the
         pow-2 size class, independent of batch size; compile-miss is
         counted once per (program, size_class); the slow-slot dump
@@ -258,20 +259,16 @@ class TestSingleLaunchSeam:
 
         probe = _Probe()
         tel.configure_launch_telemetry(metrics=probe)
-        prev = bv.configure_single_launch(mode="on")
-        try:
-            for n in (2, 3):
-                base = len(tel.launch_ledger())
-                assert bv.verify_sets_single_launch(
-                    bv.make_synthetic_sets(n, seed=n + 60)
-                )
-                entries = tel.launch_ledger()[base:]
-                assert len(entries) == prep.SINGLE_LAUNCH_BUDGET == 1
-                e = entries[0]
-                assert e["program"] == "_single_launch_verify"
-                assert e["size_class"] == 8  # both batches share the pow-2 class
-        finally:
-            bv.configure_single_launch(mode=prev)
+        for n in (2, 3):
+            base = len(tel.launch_ledger())
+            assert bv.verify_sets_single_launch(
+                bv.make_synthetic_sets(n, seed=n + 60)
+            )
+            entries = tel.launch_ledger()[base:]
+            assert len(entries) == prep.SINGLE_LAUNCH_BUDGET == 1
+            e = entries[0]
+            assert e["program"] == "_single_launch_verify"
+            assert e["size_class"] == 8  # both batches share the pow-2 class
         # compile-miss once per (program, size_class): first batch miss,
         # second batch hit — the jit cache holds one executable per key
         misses = [m for m in probe.compile_misses.events if m[1] == ("_single_launch_verify",)]

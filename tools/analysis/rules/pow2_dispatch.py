@@ -44,7 +44,6 @@ SEAM_ARGS = {
     "_dispatch": 1,
     "_device_level": 0,
     "device_batch_verify": 0,
-    "device_batch_verify_many": 0,
     "device_batch_verify_sharded": 1,
 }
 

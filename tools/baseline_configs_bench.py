@@ -45,34 +45,16 @@ def _line(metric, value, unit, vs, digits=1):
     }), flush=True)
 
 
-def config2_gossip_replay(device_prep: bool = False, single_launch: bool = False):
-    """Per-slot gossip attestation load through the production pool —
-    one replay harness, three reported lines (same n/jobs/warm-up, so
-    the comparands can't drift apart).
-
-    With device_prep=True the whole per-set input pipeline (decompress +
-    subgroup + hash-to-G2) runs on-chip (`--bls-device-prep on`); the
-    prep-off run is the PERF.md r5 396.5 sigs/s baseline shape where one
-    host core feeds the device. Both of those are split-schedule
-    reference lines (the comparands of
-    `single_launch_replay_sigs_per_sec`), so single-launch is pinned
-    OFF — on a Pallas host the auto mode would otherwise route the pool
-    through the one-launch program and the line would measure the
-    schedule it is the reference against. With single_launch=True the
-    whole verification chain of every package is ONE resident program
-    (`--bls-single-launch on`; device prep stays at its ambient mode —
-    the prep stages only serve that run's fault-fallback leg), reported
-    as `single_launch_replay_sigs_per_sec` — the line to read against
-    `gossip_replay_sigs_per_sec_device_prep`."""
+def config2_gossip_replay():
+    """Per-slot gossip attestation load through the production pool, at
+    the verify schedule the backend runs (models/batch_verify
+    `single_launch_active`: the single launch on an accelerator, host
+    prep and the monolithic program on the CPU)."""
     import asyncio
 
     from lodestar_tpu.chain.bls.interface import VerifySignatureOpts
     from lodestar_tpu.chain.bls.pool import BlsDeviceVerifierPool
-    from lodestar_tpu.models.batch_verify import (
-        configure_device_prep,
-        configure_single_launch,
-        make_synthetic_sets,
-    )
+    from lodestar_tpu.models.batch_verify import make_synthetic_sets
 
     n = 1024 if QUICK else 4096
     sets = make_synthetic_sets(n, seed=31)
@@ -95,21 +77,8 @@ def config2_gossip_replay(device_prep: bool = False, single_launch: bool = False
         await pool.close()
         return n / dt
 
-    prev = configure_device_prep(
-        mode=None if single_launch else ("on" if device_prep else "off")
-    )
-    prev_single = configure_single_launch(mode="on" if single_launch else "off")
-    try:
-        rate = asyncio.run(run())
-    finally:
-        configure_single_launch(mode=prev_single)
-        configure_device_prep(mode=prev)
-    if single_launch:
-        _line("single_launch_replay_sigs_per_sec", rate, "sigs/s",
-              rate / REFERENCE_SIGS_PER_SEC_PER_CORE)
-        return
-    suffix = "_device_prep" if device_prep else ""
-    _line(f"gossip_replay_sigs_per_sec{suffix}", rate, "sigs/s",
+    rate = asyncio.run(run())
+    _line("gossip_replay_sigs_per_sec", rate, "sigs/s",
           rate / REFERENCE_SIGS_PER_SEC_PER_CORE)
 
 
@@ -205,35 +174,11 @@ def config4_merkle_1m():
 
 def config5_backfill_window():
     """32-slot window: blocks (1 proposer sig each) + attestations."""
-    from lodestar_tpu.models.batch_verify import (
-        configure_device_prep,
-        make_synthetic_sets,
-        verify_signature_sets_device,
-    )
-
     from lodestar_tpu.models import batch_verify as bv
 
     n = 32 * (8 if QUICK else 100)
-    sets = make_synthetic_sets(n, seed=37)
-    # end-to-end (host prep EVERY iteration — dominated by this host's
-    # single prep core; real hosts thread the native prep). Prep is
-    # PINNED to the host path so this line stays comparable to the r5
-    # baseline regardless of the ambient --bls-device-prep/auto mode;
-    # the prep-on delta is measured by config2's _device_prep variant.
-    prev = configure_device_prep(mode="off")
-    try:
-        if not verify_signature_sets_device(sets):
-            raise RuntimeError("backfill window rejected valid sets")
-        iters = 3
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            if not verify_signature_sets_device(sets):
-                raise RuntimeError("backfill window rejected valid sets")
-        dt = (time.perf_counter() - t0) / iters
-    finally:
-        configure_device_prep(mode=prev)
-    _line("backfill_window_e2e_sigs_per_sec_1core_host", n / dt, "sigs/s",
-          (n / dt) / REFERENCE_SIGS_PER_SEC_PER_CORE)
+    sets = bv.make_synthetic_sets(n, seed=37)
+    iters = 3
     # device-only (prepared inputs reused, fresh blinding per launch —
     # the shape a threaded prep host sustains)
     inputs = bv.build_device_inputs(sets)
@@ -293,41 +238,30 @@ def device_prep_rate():
 
 
 def prep_launch_fusion():
-    """Launch count before/after fusing the prep dispatch chains: the
-    same batch through the pre-fusion one-launch-per-leg schedule and
-    the fused stages, counted at ops/prep.py's dispatch seam (the same
-    number `lodestar_bls_prep_launches_total` increments)."""
+    """Launches per set of the fused prep stages, counted at
+    ops/prep.py's dispatch seam (the same number
+    `lodestar_bls_prep_launches_total` increments), against the
+    pre-fusion one-launch-per-leg schedule's `UNFUSED_PREP_LAUNCHES`."""
     from lodestar_tpu.models import batch_verify as bv
     from lodestar_tpu.ops import prep as dp
 
     n = 32
     sets = bv.make_synthetic_sets(n, seed=47)
-    per_set = {}
-    for fused, name in (
-        (False, "prep_launches_per_set_unfused"),
-        (True, "prep_launches_per_set"),
-    ):
-        if bv.prepare_sets_device(sets, fused=fused) is None:  # warm compiles
-            raise RuntimeError("prep rejected valid sets")
-        base = dp.prep_launches_total()
-        if bv.prepare_sets_device(sets, fused=fused) is None:
-            raise RuntimeError("prep rejected valid sets")
-        per_set[name] = (dp.prep_launches_total() - base) / n
+    if bv.prepare_sets_device(sets) is None:  # warm compiles
+        raise RuntimeError("prep rejected valid sets")
+    base = dp.prep_launches_total()
+    if bv.prepare_sets_device(sets) is None:
+        raise RuntimeError("prep rejected valid sets")
+    per_set = (dp.prep_launches_total() - base) / n
     _line(
-        "prep_launches_per_set_unfused", per_set["prep_launches_per_set_unfused"],
-        "launches/set", 1.0, digits=4,
-    )
-    _line(
-        "prep_launches_per_set", per_set["prep_launches_per_set"],
-        "launches/set",
-        per_set["prep_launches_per_set"] / per_set["prep_launches_per_set_unfused"],
-        digits=4,
+        "prep_launches_per_set", per_set, "launches/set",
+        per_set / (dp.UNFUSED_PREP_LAUNCHES / n), digits=4,
     )
 
 
 def single_launch_schedule():
     """End-to-end launch count per verified batch: the single-launch
-    resident program (`--bls-single-launch on`, ONE counted dispatch)
+    resident program (ONE counted dispatch)
     vs the split reference (3-launch fused prep + the RLC verify
     dispatch), both counted at the telemetry seam — the dispatch-budget
     invariant the chip run's launch dashboard reads."""
@@ -337,7 +271,6 @@ def single_launch_schedule():
     n = 32
     sets = bv.make_synthetic_sets(n, seed=53)
     prev_tel = telemetry.configure_launch_telemetry(mode="on")
-    prev_prep = bv.configure_device_prep(mode="on")
     try:
         counts = {}
         for fn, name in (
@@ -351,7 +284,6 @@ def single_launch_schedule():
                 raise RuntimeError(f"{name} bench rejected valid sets")
             counts[name] = telemetry.launch_totals()["launches"] - base
     finally:
-        bv.configure_device_prep(mode=prev_prep)
         telemetry.configure_launch_telemetry(mode=prev_tel)
     split = counts["e2e_launches_per_batch_split"]
     _line("e2e_launches_per_batch", counts["e2e_launches_per_batch"],
@@ -360,29 +292,23 @@ def single_launch_schedule():
 
 
 def config2_gossip_replay_pipelined():
-    """Config-2 gossip replay with the prep→verify pipeline ON (1-lane
-    interleave on this container) and device prep on — the line to read
-    against gossip_replay_sigs_per_sec_device_prep — plus the measured
-    fraction of verify wall time with a prep stage in flight.
-    Single-launch is pinned OFF like its comparand: this line measures
-    the SPLIT pipeline (3-launch staged prep overlapping the verify
-    dispatch), not the single-launch host-parse overlap."""
+    """Config-2 gossip replay arriving as a stream, where the pool stages
+    prep (an accelerator: the host byte parse of package k+1 behind the
+    single launch of package k) — plus the measured fraction of verify
+    wall time with a prep stage in flight. Raises where the pool does
+    not stage (one lane on the CPU backend)."""
     import asyncio
 
     from lodestar_tpu.chain.bls.interface import VerifySignatureOpts
     from lodestar_tpu.chain.bls.pool import BlsDeviceVerifierPool
-    from lodestar_tpu.models.batch_verify import (
-        configure_device_prep,
-        configure_single_launch,
-        make_synthetic_sets,
-    )
+    from lodestar_tpu.models.batch_verify import make_synthetic_sets
 
     n = 1024 if QUICK else 4096
     sets = make_synthetic_sets(n, seed=31)
     opts = VerifySignatureOpts(batchable=True)
 
     async def run():
-        pool = BlsDeviceVerifierPool(pipeline="on")
+        pool = BlsDeviceVerifierPool()
         jobs = [sets[i : i + 32] for i in range(0, n, 32)]
 
         async def replay():
@@ -417,13 +343,7 @@ def config2_gossip_replay_pipelined():
         verify = stats["verify_ns"] - base["verify_ns"]
         return n / dt, (100.0 * overlap / verify) if verify else 0.0
 
-    prev = configure_device_prep(mode="on")
-    prev_single = configure_single_launch(mode="off")
-    try:
-        rate, overlap_pct = asyncio.run(run())
-    finally:
-        configure_single_launch(mode=prev_single)
-        configure_device_prep(mode=prev)
+    rate, overlap_pct = asyncio.run(run())
     _line("pipelined_gossip_replay_sigs_per_sec", rate, "sigs/s",
           rate / REFERENCE_SIGS_PER_SEC_PER_CORE)
     _line("prep_verify_overlap_occupancy_pct", overlap_pct, "pct",
@@ -592,35 +512,31 @@ def mesh_scaling():
     counts = [n for n in (1, 2, 4, 8) if n <= len(devices)]
     n = 256 if QUICK else 1024
     sets = bv.make_synthetic_sets(n, seed=43)
-    prev = bv.configure_device_prep(mode="off")
-    try:
-        inputs = bv.build_device_inputs(sets, size=n)
-        if inputs is None:
-            raise RuntimeError("mesh bench rejected valid sets")
-        pk, h, sig, bits, mask = inputs
-        iters = 3
-        for n_dev in counts:
-            if n_dev == 1:
-                run = lambda b: bv.device_batch_verify(pk, h, sig, b, mask)
-            else:
-                mesh = Mesh(np.asarray(devices[:n_dev]), ("data",))
-                run = lambda b, m=mesh: bv.device_batch_verify_sharded(
-                    m, pk, h, sig, b, mask
+    inputs = bv.build_device_inputs(sets, size=n)
+    if inputs is None:
+        raise RuntimeError("mesh bench rejected valid sets")
+    pk, h, sig, bits, mask = inputs
+    iters = 3
+    for n_dev in counts:
+        if n_dev == 1:
+            run = lambda b: bv.device_batch_verify(pk, h, sig, b, mask)
+        else:
+            mesh = Mesh(np.asarray(devices[:n_dev]), ("data",))
+            run = lambda b, m=mesh: bv.device_batch_verify_sharded(
+                m, pk, h, sig, b, mask
+            )
+        if not bool(np.asarray(run(bits))):  # warm the compile
+            raise RuntimeError(f"mesh bench rejected valid sets at {n_dev} devices")
+        t0 = time.perf_counter()
+        for _ in range(iters):
+            fresh = bv._bits_msb(bv._random_coeffs(n), bv.COEFF_BITS)
+            if not bool(np.asarray(run(fresh))):
+                raise RuntimeError(
+                    f"mesh bench rejected valid sets at {n_dev} devices"
                 )
-            if not bool(np.asarray(run(bits))):  # warm the compile
-                raise RuntimeError(f"mesh bench rejected valid sets at {n_dev} devices")
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                fresh = bv._bits_msb(bv._random_coeffs(n), bv.COEFF_BITS)
-                if not bool(np.asarray(run(fresh))):
-                    raise RuntimeError(
-                        f"mesh bench rejected valid sets at {n_dev} devices"
-                    )
-            dt = (time.perf_counter() - t0) / iters
-            _line(f"mesh_sigs_per_sec_{n_dev}dev", n / dt, "sigs/s",
-                  (n / dt) / REFERENCE_SIGS_PER_SEC_PER_CORE)
-    finally:
-        bv.configure_device_prep(mode=prev)
+        dt = (time.perf_counter() - t0) / iters
+        _line(f"mesh_sigs_per_sec_{n_dev}dev", n / dt, "sigs/s",
+              (n / dt) / REFERENCE_SIGS_PER_SEC_PER_CORE)
 
 
 def two_tenant_fairness_replay():
@@ -726,8 +642,6 @@ def main():
     config5_backfill_window()
     single_launch_schedule()
     config2_gossip_replay()
-    config2_gossip_replay(device_prep=True)
-    config2_gossip_replay(single_launch=True)
     config2_gossip_replay_pipelined()
     config3_sync_committee_aggregate()
     mesh_scaling()

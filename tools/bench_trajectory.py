@@ -55,21 +55,17 @@ THRESHOLDS: dict[str, float] = {
     "host_prep_sets_per_sec_single_core": 0.5,
     "device_prep_sets_per_sec": 0.5,
     "prep_launches_per_set": 0.05,
-    "prep_launches_per_set_unfused": 0.05,
     # single-launch dispatch budget: 1 program per verified batch vs the
     # 3+verify split reference — a schedule invariant, gated tight (a
     # fused chain quietly growing a second launch IS the regression)
     "e2e_launches_per_batch": 0.05,
     "e2e_launches_per_batch_split": 0.05,
-    "single_launch_replay_sigs_per_sec": 0.5,
     "merkle_sha256_pair_hashes_per_sec": 0.5,
     "state_htr_chunks_per_sec": 0.5,
     "epoch_htr_ms_device": 0.75,
     "epoch_htr_ms_cpu": 0.75,
-    "backfill_window_e2e_sigs_per_sec_1core_host": 0.5,
     "backfill_window_device_sigs_per_sec": 0.5,
     "gossip_replay_sigs_per_sec": 0.5,
-    "gossip_replay_sigs_per_sec_device_prep": 0.5,
     "pipelined_gossip_replay_sigs_per_sec": 0.5,
     "prep_verify_overlap_occupancy_pct": 0.75,
     "sync_committee_fast_aggregate_verifies_per_sec": 0.5,
@@ -100,7 +96,6 @@ LOWER_IS_BETTER: set = {
     "epoch_htr_ms_cpu",
     "two_tenant_fairness_share_error_pct",
     "prep_launches_per_set",
-    "prep_launches_per_set_unfused",
     "e2e_launches_per_batch",
     "e2e_launches_per_batch_split",
     "chaos_recovery_slots",

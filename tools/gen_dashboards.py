@@ -163,7 +163,7 @@ def bls_pool():
             # with the `or vector(0)` guard so the subtraction (and the
             # panel) still renders when telemetry is off or no
             # single-launch traffic exists. Known over-reads, both
-            # deliberate: with telemetry off + single-launch on the
+            # deliberate: with telemetry off under the single launch the
             # series blends the schedules (no per-program signal to
             # subtract), and during a single-launch fallback storm the
             # FAILED dispatches stay in the numerator (the counter
@@ -171,8 +171,8 @@ def bls_pool():
             # elevated split series next to a busy fallbacks panel is
             # the storm being visible, not a split-schedule regression.
             # The single-launch
-            # series reads the one-program schedule
-            # (--bls-single-launch): numerator = the single-launch
+            # series reads the one-program schedule (an accelerator
+            # backend's): numerator = the single-launch
             # program's dispatches, denominator the sets staged under
             # the single_launch prep layer — at budget it tracks
             # 1/batch-size while the split series tracks 3/batch-size.
@@ -197,7 +197,8 @@ def bls_pool():
             # verify wall time carried a prep stage in flight (the PR 9
             # bench line, now readable during a run) and whether the
             # double buffer engaged at all (0 staged packages = it
-            # never did — 1-lane auto, or no stageable lanes)
+            # never did — one lane on the split schedule, or no
+            # stageable lanes)
             "Prep→verify pipeline overlap",
             [
                 ("lodestar_bls_pipeline_overlap_occupancy_pct", "overlap % of verify time"),
