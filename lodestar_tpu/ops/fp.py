@@ -86,6 +86,8 @@ __all__ = [
     "redc",
     "canon",
     "pow_const",
+    "pow_window",
+    "pow_windowed",
     "inv",
     "is_zero",
     "is_zero_mod",
@@ -400,22 +402,53 @@ def _exp_bits(e: int) -> np.ndarray:
     return np.array([int(b) for b in bin(e)[2:]], dtype=np.int32)
 
 
+def pow_window(nbits: int) -> int:
+    """The window width w that minimises 2^w - 2 + ceil(nbits / w): the
+    multiplies of a fixed-window chain over an nbits-bit exponent (5 for
+    the launch's 379-, 381- and 758-bit chains, 1 up to 5 bits)."""
+    return min(range(1, 9), key=lambda w: (1 << w) - 2 + -(-nbits // w))
+
+
+def pow_windowed(a, bits, sq, mul, one):
+    """a^e for a static MSB-first bit array (leading bit 1), fixed window:
+    a table a^0 .. a^(2^w - 1) stacked on a new leading axis (a^2 by `sq`:
+    never two identical operands into `mul`), then per remaining window w
+    squarings and ONE multiply by the entry its static digit indexes
+    (digit 0 multiplies by `one`, so the rolled loop stays branch-free).
+    Serves Fp (`pow_const`) and Fp2 (`ops.prep`) alike; relaxed in and
+    out, and exact zero stays exact zero (entries >= 1 of its table are
+    exact zeros, and 0 * one = 0 exactly)."""
+    bits = np.asarray(bits)
+    w = pow_window(bits.shape[0])
+    digits = np.pad(bits, (-bits.shape[0] % w, 0)).reshape(-1, w) @ (1 << np.arange(w)[::-1])
+    table = jnp.zeros((1 << w, *a.shape), a.dtype).at[0].set(one).at[1].set(a)
+
+    def entry(t, k):
+        return jax.lax.dynamic_index_in_dim(t, k, 0, keepdims=False)
+
+    if w > 1:
+        table = table.at[2].set(sq(a))
+        table = jax.lax.fori_loop(
+            3, 1 << w, lambda k, t: t.at[k].set(mul(entry(t, k - 1), a)), table
+        )
+
+    def body(r, d):
+        for _ in range(w):
+            r = sq(r)
+        return mul(r, entry(table, d)), None
+
+    # the top window (short where w does not divide the length) is never 0
+    r, _ = jax.lax.scan(body, table[int(digits[0])], jnp.asarray(digits[1:], jnp.int32))
+    return r
+
+
 def pow_const(a, e: int):
-    """a^e for a static exponent (square-and-always-multiply over the bit
-    array — branch-free, jit-stable). a in Montgomery form, relaxed."""
-    if e == 0:
-        return one_mont(a.shape[:-1])
-    bits = jnp.asarray(_exp_bits(e))
+    """a^e for a static exponent (fixed-window over its bits: branch-free,
+    jit-stable). a in Montgomery form, relaxed."""
     one = one_mont(a.shape[:-1])
-
-    def body(i, r):
-        r = mont_sq(r)
-        bit = bits[i]
-        mul = jnp.where(bit[..., None] != 0, a, one)
-        return mont_mul(r, mul)
-
-    # first bit is always 1: start from a
-    return jax.lax.fori_loop(1, bits.shape[0], body, a)
+    if e == 0:
+        return one
+    return pow_windowed(a, _exp_bits(e), mont_sq, mont_mul, one)
 
 
 def inv(a):

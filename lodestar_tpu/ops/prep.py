@@ -134,9 +134,7 @@ _R3_LIMBS = fp.limbs_from_int(pow(1 << (LIMBS * LIMB_BITS), 3, P))
 
 # static exponent bit arrays (MSB-first; leading bit is always 1)
 _E_FP_SQRT = (P + 1) // 4
-_E_FP2_SQRT_BITS = np.array(
-    [int(b) for b in bin((P * P + 7) // 16)[2:]], dtype=np.int32
-)
+_E_FP2_SQRT_BITS = fp._exp_bits((P * P + 7) // 16)
 
 # mont-form Fp2 "one" for affine_to_jac on G2 points
 _ONE2 = np.zeros((2, LIMBS), dtype=np.int32)
@@ -368,17 +366,9 @@ def _sel_pt(cond, a, b):
 
 
 def _fp2_pow_bits(a, bits) -> jax.Array:
-    """a^e for a static MSB-first bit array (leading bit 1): square-and-
-    always-multiply, branch-free (mirrors fp.pow_const). a mont, relaxed."""
-    one = tw.fp2_one(a.shape[:-2])
-    bits = jnp.asarray(bits)
-
-    def body(i, r):
-        r = tw.fp2_sq(r)
-        sel = jnp.where(bits[i][..., None, None] != 0, a, one)
-        return tw.fp2_mul(r, sel)
-
-    return jax.lax.fori_loop(1, bits.shape[0], body, a)
+    """a^e for a static MSB-first bit array (leading bit 1): the fixed-
+    window chain of fp.pow_windowed over Fp2. a mont, relaxed."""
+    return fp.pow_windowed(a, bits, tw.fp2_sq, tw.fp2_mul, tw.fp2_one(a.shape[:-2]))
 
 
 def fp2_sqrt_with_flag(a):
@@ -643,7 +633,7 @@ def hash_to_g2_device(msgs, dst: bytes = H.DST_G2):
 # The pre-fusion schedule launched one program per pipeline leg — five
 # dispatches per batch, each ending in a host round-trip before the next
 # leg could start, and the two Fp2 sqrt chains (G2 decompression and the
-# SSWU candidates) each paid their own ~760-step sequential chain. The
+# SSWU candidates) each paid their own sequential 758-bit chain. The
 # fused schedule is `FUSED_PREP_LAUNCHES` (= 3) staged programs — NOT
 # one monolithic jit, per the r5 Pallas whole-program miscompile
 # doctrine (the verify pipeline splits the same way):
@@ -652,7 +642,8 @@ def hash_to_g2_device(msgs, dst: bytes = H.DST_G2):
 #    reduction, SSWU candidates, then ONE Fp2 sqrt chain deciding the
 #    G2 root and all four SSWU candidate roots together (five Fp2
 #    sqrts per set stacked on the batch axis — the chain is sequential
-#    in its ~760 squarings but batch-parallel across its inputs), sign
+#    in its 152 windows of five squarings and one table multiply
+#    (`fp.pow_windowed`) but batch-parallel across its inputs), sign
 #    selects, and the 3-isogeny.
 # 2. `_prep_subgroup_stage`: the φ/ψ eigenvalue ladders (both legs in
 #    one program) folded with the on-curve flags.
