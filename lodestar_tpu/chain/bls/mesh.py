@@ -8,8 +8,8 @@ drove one chip. This module is the mesh's serving shape:
   `OccupancyTracker`, and its own wedge `CircuitBreaker` so a sick
   device (driver hang, OOM loop) degrades the pool to an (N-1)-chip
   mesh instead of tripping the whole pool.
-* `VerifierMesh` — the lane set plus an optional data-parallel sharded
-  verify callable (bulk range-sync/backfill batches run one launch
+* `VerifierMesh` — the lane set plus, under the split schedule only,
+  a data-parallel sharded verify callable (one bulk job, one launch
   across several idle chips). The mesh also answers the fleet-level
   questions the offload Status frame ships to clients: aggregate
   occupancy over *available* chips and the per-chip table (a wedged
@@ -17,18 +17,24 @@ drove one chip. This module is the mesh's serving shape:
 * `build_device_mesh` — production construction from the models layer's
   device enumeration, and the one place a production lane is made: the
   models layer says which verify schedule the backend runs, and the
-  lanes carry the answer (what they can take, and whether prep staged
-  for them touches a device). `"auto"` engages only when the backend is
+  lanes carry the answer (what they can take, whether prep staged
+  for them touches a device, and whether the mesh has the collective
+  at all). `"auto"` engages only when the backend is
   a TPU AND more than one device is visible: on the CPU-forced 8-device
   test platform auto stays single-lane, so a default pool behaves
   exactly like the pre-mesh code unless a test asks for the mesh
   explicitly. A backend that cannot initialise raises — it is never
   read as "one CPU lane".
 
-Placement policy lives in the pool (`chain/bls/pool.py`): latency-class
-work dequeues to the least-occupied free lane; bulk work shards across
-idle lanes when at least two are free and the batch is large enough to
-amortize the collective launch.
+Placement policy lives in the pool (`chain/bls/pool.py`): a package
+goes to the least-occupied free lane, whatever its class. On a TPU
+(the single launch) that is the only road: a bulk package is four
+jobs in one (512, 4) launch on one chip, its parse staged, and N
+lanes run N such launches side by side — the road one chip runs, a
+lane at a time (the backfill cell of the benchmark measures it on a
+v5e-4). Only where the lanes run the split schedule (a forced CPU
+mesh) does a bulk job big enough to amortize a collective launch
+shard across the idle lanes.
 """
 
 from __future__ import annotations
@@ -85,14 +91,17 @@ class PreparedSets:
     REJECTED the batch (a structural verdict — final, never re-prepped).
     `error` carries a prep-stage exception; a launch seeing one re-preps
     through the lane's plain `verify_fn`, which re-raises through the
-    exact pre-pipeline fail-closed path."""
+    exact pre-pipeline fail-closed path. `waited_s` is what the
+    dispatcher waited for this outcome with a lane free; the launch
+    carries it as its `bls.parse_wait` phase."""
 
-    __slots__ = ("inputs", "error", "info")
+    __slots__ = ("inputs", "error", "info", "waited_s")
 
     def __init__(self, inputs=None, error: Exception | None = None, info=None):
         self.inputs = inputs
         self.error = error
         self.info = info  # prep span record carried across threads
+        self.waited_s = 0.0
 
 
 class MeshLane:
@@ -305,6 +314,8 @@ def mesh_launch(
                             # staged on the prep thread: its parse seconds
                             # cross threads with the inputs
                             tel.add_phase("bls.parse", (info["end_ns"] - info["start_ns"]) / 1e9)
+                        if prepared.waited_s:
+                            tel.add_phase("bls.parse_wait", prepared.waited_s)
                         ok = current.verify_prepared_fn(prepared.inputs)
                     elif grouped:
                         ok = current.verify_grouped_fn(sets)
@@ -364,17 +375,20 @@ def build_device_mesh(
 
     The verify schedule is asked of the models layer HERE, once a mesh,
     and the lanes carry it: where the backend runs the single launch they
-    take multi-job units and their staged prep is the host byte parse;
-    where it runs the split schedule they have no grouped entry and
-    their staged prep is device work."""
+    take multi-job units, their staged prep is the host byte parse, and
+    the mesh has no collective (a bulk package is one multi-job launch
+    on one lane, as on one chip); where it runs the split schedule they
+    have no grouped entry, their staged prep is device work, and a bulk
+    job may shard over the idle lanes."""
     if mode not in MESH_MODES:
         raise ValueError(f"bls_mesh must be one of {MESH_MODES}, got {mode!r}")
     from lodestar_tpu.models import batch_verify as bv
 
+    single_launch = bv.single_launch_active()
+
     def _lanes(entries) -> list[MeshLane]:
         """Lanes over (verify_fn, verify_prepared_fn, verify_grouped_fn)
         entries, one a device, with the backend's schedule as facts."""
-        single_launch = bv.single_launch_active()
         return [
             MeshLane(
                 index,
@@ -416,4 +430,4 @@ def build_device_mesh(
             for i in range(n)
         ]
     )
-    return VerifierMesh(lanes, sharded_fn=bv.make_mesh_sharded_fn())
+    return VerifierMesh(lanes, sharded_fn=None if single_launch else bv.make_mesh_sharded_fn())

@@ -29,10 +29,15 @@ N-worker thread pool replaced by one device pipeline:
   keep their own launches.
 * **Mesh lanes** (`chain/bls/mesh.py`): the pool serves a `VerifierMesh`
   of per-device launch lanes. One dispatcher waits for a free lane,
-  dequeues through the shared priority queue, and places the package:
-  latency-class work goes to the least-occupied free chip; bulk
-  range-sync/backfill batches big enough to amortize a collective go
-  data-parallel (`verify_signature_sets_sharded`) across the idle chips.
+  dequeues through the shared priority queue, and places the package on
+  the least-occupied free chip, whatever its class. On a TPU that is
+  the whole policy: a bulk range-sync/backfill package is four jobs in
+  one (512, 4) launch on one lane, its parse staged, and N lanes run N
+  such launches side by side — four lanes are fed the way one lane is
+  fed, and one host thread's parses set their pace. Only a mesh built
+  with the collective (the lanes of the split schedule: a forced CPU
+  mesh) sends a bulk job big enough to amortize it data-parallel
+  (`verify_signature_sets_sharded`) across the idle chips.
   With a single visible device the mesh is one lane and the launch
   schedule is bit-identical to the pre-mesh pool (regression-tested).
 * **Staged prep** (`_staging`): where it can be hidden, a package's
@@ -42,8 +47,12 @@ N-worker thread pool replaced by one device pipeline:
   the lanes say their staged prep touches no device. One lane under
   the split schedule keeps the exact pre-pipeline launch schedule:
   staged prep there is device launches on the die that verifies. A
-  package that finds a lane free and is one launch unit has no launch
-  to hide its prep behind and takes the inline road. The dispatcher
+  package that finds every lane free and is one launch unit has no
+  launch to hide its prep behind and takes the inline road; with a
+  sibling lane at work it is staged, so that the parses of the lanes'
+  packages run one after another in launch order and not side by side
+  under one GIL, all ending late together. Where the dispatcher then
+  waits for a parse with a lane free, that is `bls.parse_wait`. The dispatcher
   takes the next package while the lanes are busy only once the queue
   holds all of it (nothing that arrives later could join it, so no
   launch's composition changes); an urgent arrival still overtakes the
@@ -428,6 +437,8 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             # unit's prep ends); inline prep is in neither
             "parse_ns": 0,
             "parse_hidden_ns": 0,
+            # what the dispatcher waited for a staged parse with a lane free
+            "parse_wait_ns": 0,
         }
 
     @property
@@ -763,7 +774,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
                         continue
                 if prepped is not None:
                     # only the first unit: the launch takes the rest as it gets to them
-                    await asyncio.wrap_future((prepped.chunks + prepped.units)[0].prepared)
+                    await self._await_staged((prepped.chunks + prepped.units)[0])
                 placing, package = package, None  # _place_and_launch answers for it from here
                 await self._place_and_launch(placing, cls, prepped=prepped)
                 # the launch reaches its thread, and its dispatch the GIL,
@@ -782,17 +793,33 @@ class BlsDeviceVerifierPool(IBlsVerifier):
 
     # -- prep→verify pipeline ---------------------------------------------------
 
+    async def _await_staged(self, unit: _PrepUnit) -> None:
+        """A lane is free and the package in hand waits for its first
+        unit's staged prep: the host holds that chip idle. The wait is
+        the span `bls.parse_wait`, `parse_wait_ns` counts it, and the
+        unit's launch carries it as a phase of its ledger entry."""
+        if unit.prepared.done():
+            return
+        t0_ns = time.monotonic_ns()
+        with telemetry.phase("bls.parse_wait"):
+            staged = await asyncio.wrap_future(unit.prepared)
+        waited_ns = time.monotonic_ns() - t0_ns
+        self.metrics["parse_wait_ns"] += waited_ns
+        staged.waited_s = waited_ns / 1e9
+
     def _stage(self, package: list[_Job], cls: PriorityClass) -> _PreppedPackage | None:
         """Form the package's launch units and hand their prep to an
         executor thread; None where the package keeps its inline prep: a
         bulk package on a mesh that can shard (the collective launch
-        preps inline), and a package of one launch unit that finds a
-        lane free (no launch to hide its prep behind, so staging would
-        only add two thread hops to its verdict)."""
+        preps inline), and a package of one launch unit that finds
+        every lane free (no launch to hide its prep behind, so staging
+        would only add two thread hops to its verdict). With a sibling
+        lane at work it is staged: prep inline on four launch threads
+        runs side by side under one GIL and ends late on all of them."""
         if self.scheduler_enabled and cls in BULK_CLASSES and self.mesh.sharding_available():
             return None
         chunks, units = _launch_units(package, self.mesh.grouping_available())
-        if len(chunks) + len(units) == 1 and self._free_lanes():
+        if len(chunks) + len(units) == 1 and not any(lane.inflight for lane in self.mesh.lanes):
             return None
         prepped = _PreppedPackage(
             [_PrepUnit(chunk, [s for j in chunk for s in j.sets]) for chunk in chunks],

@@ -28,6 +28,7 @@ coefficient is 1, as in the oracle.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import threading
@@ -772,28 +773,39 @@ def make_synthetic_sets(n: int, seed: int = 1) -> list[SignatureSet]:
     return sets
 
 
-def verify_signature_sets_device(sets: list[SignatureSet]) -> bool:
-    """End-to-end single-device batch verify of N signature sets.
+def verify_signature_sets_device(sets: list[SignatureSet], device=None) -> bool:
+    """End-to-end single-device batch verify of N signature sets, on
+    `device` (a lane's chip) or, None, wherever JAX puts it.
 
     On an accelerator the single-launch program (one counted dispatch,
     bytes-in → verdict-out, with its own degradation chain back to the
     split schedule); otherwise the split schedule: host prep followed
     by the RLC verify dispatch."""
     if single_launch_active():
-        return verify_sets_single_launch(sets)
-    return _verify_sets_split(sets)
+        return verify_sets_single_launch(sets, device)
+    return _verify_sets_split(sets, device)
 
 
-def _verify_sets_split(sets: list[SignatureSet]) -> bool:
+def _placed_on(device):
+    """Placement for the roads that make their own device arrays (the
+    split schedule, and every road on a CPU backend): JAX's default
+    device. It is part of a jitted program's trace key, so a program
+    under it is traced again for every chip; the single launch is
+    placed by its inputs instead (`_dispatch_launch`)."""
+    return jax.default_device(device) if device is not None else contextlib.nullcontext()
+
+
+def _verify_sets_split(sets: list[SignatureSet], device=None) -> bool:
     """The split (prep-then-verify) schedule: `build_device_inputs`
     (fused 3-launch device prep, host prep on error or off an
     accelerator) plus
     the separate RLC verify dispatch — the single-launch program's
     differential reference and per-batch fallback."""
-    inputs = build_device_inputs(sets)
-    if inputs is None:
-        return False
-    return _verify_split_prepared(inputs)
+    with _placed_on(device):
+        inputs = build_device_inputs(sets)
+        if inputs is None:
+            return False
+        return _verify_split_prepared(inputs)
 
 
 def _verify_split_prepared(inputs) -> bool:
@@ -849,15 +861,40 @@ def prepare_single_launch_inputs(sets: list[SignatureSet]):
         )
 
 
-def _dispatch_launch(program, what: str, shape: tuple, *args, **static):
+_traced_launches: set = set()  # guarded by: _trace_lock (programs, by static arguments and shapes, whose trace JAX holds)
+_trace_lock = threading.Lock()
+
+
+def _trace_once(program, args, static) -> None:
+    """One trace of a single-launch program for every lane. A lane's
+    launch is placed by its inputs (committed to the lane's chip), which
+    are no part of JAX's trace key: the first lane to get here traces,
+    the others wait for it instead of tracing the same program beside
+    it under one GIL, and then each only lowers and compiles (or loads)
+    for its own chip."""
+    key = (program, tuple(sorted(static.items())), tuple((a.shape, a.dtype.str) for a in args))
+    if key in _traced_launches:
+        return
+    with _trace_lock:
+        if key not in _traced_launches:
+            program.trace(*args, **static)
+            _traced_launches.add(key)
+
+
+def _dispatch_launch(program, what: str, shape: tuple, *args, device=None, **static):
     """ONE counted dispatch of a single-launch program and the wait for
     its two outputs, BOTH shape-checked: a miscompile returning a
     malformed batch_valid must raise here, inside the caller's guarded
     region, and degrade like any other anomaly, not reach the
-    lane/breaker. Returns (verdict, batch_valid) as numpy bools."""
+    lane/breaker. Returns (verdict, batch_valid) as numpy bools. With a
+    `device` (a lane's chip) the launch runs there, on one trace for all
+    lanes (`_trace_once`)."""
     from lodestar_tpu.ops import prep as dp
 
     with telemetry.phase("bls.dispatch"):  # transfer and enqueue
+        if device is not None:
+            _trace_once(program, args, static)
+            args = jax.device_put(args, device)
         verdict, batch_valid = dp._dispatch(program, *args, **static)
     with telemetry.phase("bls.wait"):  # blocks on the verdict
         v = np.asarray(verdict)
@@ -868,18 +905,18 @@ def _dispatch_launch(program, what: str, shape: tuple, *args, **static):
     return v, bvld
 
 
-def _verify_single_prepared(si: SingleLaunchInputs) -> bool:
+def _verify_single_prepared(si: SingleLaunchInputs, device=None) -> bool:
     """Dispatch ONE single-launch program on host-staged inputs. A
     device error or a verdict-shape anomaly degrades the batch to the
     split schedule (counted + warned) — which itself degrades device
     prep to host prep, the full staged-jit miscompile chain."""
     try:
         v, bvld = _dispatch_launch(
-            _single_launch_verify, "single-launch", (), *si.arrays, si.bits, si.mask
+            _single_launch_verify, "single-launch", (), *si.arrays, si.bits, si.mask, device=device
         )
     except Exception as e:  # degrade to the split schedule, never resolve here
         _note_single_launch_fallback(e)
-        return _verify_sets_split(si.sets)
+        return _verify_sets_split(si.sets, device)
     if not bool(bvld):
         m = _prep_metrics
         if m is not None:
@@ -887,7 +924,7 @@ def _verify_single_prepared(si: SingleLaunchInputs) -> bool:
     return bool(v)
 
 
-def verify_sets_single_launch(sets: list[SignatureSet]) -> bool:
+def verify_sets_single_launch(sets: list[SignatureSet], device=None) -> bool:
     """End-to-end single-launch batch verify: compressed bytes in, ONE
     counted device dispatch (`ops.prep.SINGLE_LAUNCH_BUDGET`), verdict
     out — verdicts identical to `verify_signature_sets_device` on the
@@ -902,10 +939,10 @@ def verify_sets_single_launch(sets: list[SignatureSet]) -> bool:
         # lands on host prep, so a poisoned batch can never raise out
         # of here and charge every lane's breaker in turn
         _note_single_launch_fallback(e)
-        return _verify_sets_split(sets)
+        return _verify_sets_split(sets, device)
     if si is None:
         return False
-    return _verify_single_prepared(si)
+    return _verify_single_prepared(si, device)
 
 
 class GroupedLaunchInputs:
@@ -972,7 +1009,7 @@ def prepare_grouped_launch_inputs(jobs: list[list[SignatureSet]]) -> GroupedLaun
         )
 
 
-def _verify_grouped_prepared(gi: GroupedLaunchInputs) -> list[bool]:
+def _verify_grouped_prepared(gi: GroupedLaunchInputs, device=None) -> list[bool]:
     """Dispatch ONE multi-job program on host-staged inputs: a verdict a
     job. A device error or a shape anomaly of either output degrades the
     unit to one `verify_sets_single_launch` a job (counted + warned)."""
@@ -982,11 +1019,11 @@ def _verify_grouped_prepared(gi: GroupedLaunchInputs) -> list[bool]:
     try:
         v, bvld = _dispatch_launch(
             _grouped_launch_verify, "grouped-launch", (gi.groups,),
-            *gi.arrays, gi.bits, gi.mask, groups=gi.groups,
+            *gi.arrays, gi.bits, gi.mask, device=device, groups=gi.groups,
         )
     except Exception as e:  # degrade to one launch a job, never resolve here
         _note_single_launch_fallback(e)
-        return [verify_sets_single_launch(job) for job in gi.jobs]
+        return [verify_sets_single_launch(job, device) for job in gi.jobs]
     m = _prep_metrics
     for g, i in enumerate(gi.riding):
         verdicts[i] = bool(v[g])
@@ -995,7 +1032,7 @@ def _verify_grouped_prepared(gi: GroupedLaunchInputs) -> list[bool]:
     return verdicts
 
 
-def verify_sets_grouped_launch(jobs: list[list[SignatureSet]]) -> list[bool]:
+def verify_sets_grouped_launch(jobs: list[list[SignatureSet]], device=None) -> list[bool]:
     """Up to four jobs, ONE counted device dispatch, a verdict a job —
     each identical to `verify_sets_single_launch` on that job alone. A
     host-parse ERROR degrades to that road, a job at a time."""
@@ -1003,11 +1040,11 @@ def verify_sets_grouped_launch(jobs: list[list[SignatureSet]]) -> list[bool]:
         gi = prepare_grouped_launch_inputs(jobs)
     except Exception as e:
         _note_single_launch_fallback(e)
-        return [verify_sets_single_launch(job) for job in jobs]
-    return _verify_grouped_prepared(gi)
+        return [verify_sets_single_launch(job, device) for job in jobs]
+    return _verify_grouped_prepared(gi, device)
 
 
-def verify_prepared(inputs) -> bool | list[bool]:
+def verify_prepared(inputs, device=None) -> bool | list[bool]:
     """Verify a batch whose inputs were already staged by the pipeline's
     prep stage (chain/bls/pool.py double-buffers prep of batch k+1
     against this call on batch k). Three staged shapes: the split
@@ -1018,12 +1055,14 @@ def verify_prepared(inputs) -> bool | list[bool]:
     overlaps the host parse of batch k+1), or a `GroupedLaunchInputs`
     (the same for a multi-job launch: a list of verdicts, one a job).
     Either way a verdict is identical to `verify_signature_sets_device`
-    on the same sets."""
+    on the same sets, and it is computed on `device` where one is given
+    (a lane's chip)."""
     if isinstance(inputs, GroupedLaunchInputs):
-        return _verify_grouped_prepared(inputs)
+        return _verify_grouped_prepared(inputs, device)
     if isinstance(inputs, SingleLaunchInputs):
-        return _verify_single_prepared(inputs)
-    return _verify_split_prepared(inputs)
+        return _verify_single_prepared(inputs, device)
+    with _placed_on(device):
+        return _verify_split_prepared(inputs)
 
 
 def prepare_inputs_for_lane(sets: list[SignatureSet], lane_index: int | None = None):
@@ -1078,14 +1117,13 @@ def mesh_device_count() -> int:
 
 def make_lane_verify_fn(device_index: int):
     """Single-device verify callable pinned to one chip: the per-lane
-    backend of the mesh pool. Placement rides `jax.default_device`, so
-    each lane compiles/launches against its own die while sharing the
-    host-side prep and the per-size-class program cache."""
+    backend of the mesh pool. The single launch is placed by its inputs
+    (`_dispatch_launch`), so the lanes share the host-side prep and ONE
+    trace of each program and every lane lowers and compiles only for
+    its own die; the split schedule rides `jax.default_device`."""
 
     def lane_verify(sets: list[SignatureSet]) -> bool:
-        dev = jax.devices()[device_index]
-        with jax.default_device(dev):
-            return verify_signature_sets_device(sets)
+        return verify_signature_sets_device(sets, jax.devices()[device_index])
 
     lane_verify.__name__ = f"lane_verify_dev{device_index}"
     return lane_verify
@@ -1100,9 +1138,7 @@ def make_lane_verify_prepared_fn(device_index: int):
     see verify_prepared)."""
 
     def lane_verify_prepared(inputs) -> bool:
-        dev = jax.devices()[device_index]
-        with jax.default_device(dev):
-            return verify_prepared(inputs)
+        return verify_prepared(inputs, jax.devices()[device_index])
 
     lane_verify_prepared.__name__ = f"lane_verify_prepared_dev{device_index}"
     return lane_verify_prepared
@@ -1113,9 +1149,7 @@ def make_lane_verify_grouped_fn(device_index: int):
     list of jobs in, one launch, a list of verdicts out."""
 
     def lane_verify_grouped(jobs: list[list[SignatureSet]]) -> list[bool]:
-        dev = jax.devices()[device_index]
-        with jax.default_device(dev):
-            return verify_sets_grouped_launch(jobs)
+        return verify_sets_grouped_launch(jobs, jax.devices()[device_index])
 
     lane_verify_grouped.__name__ = f"lane_verify_grouped_dev{device_index}"
     return lane_verify_grouped

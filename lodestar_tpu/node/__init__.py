@@ -322,7 +322,7 @@ def configure_device_runtime(opts: BeaconNodeOptions, metrics: BeaconMetrics) ->
     its metric family. The verify schedule is not among them: it is
     what the backend is (models/batch_verify `single_launch_active`).
     Returns what the node logs once at start: platform, device_kind,
-    count, verifier, hasher.
+    count, verifier, hasher (and, once its device pool is built, lanes).
 
     A node that verifies through `--bls-offload` without a local device
     fallback leaves the chip to the process that owns it: it
@@ -477,6 +477,7 @@ class BeaconNode:
             bls = _offload_verifier(opts, metrics)
         elif device_runtime["verifier"] == "device":
             bls = _device_pool(opts, metrics)
+            device_runtime["lanes"] = len(bls.mesh)  # one a chip the pool serves on (--bls-mesh)
         else:
             bls = BlsSingleThreadVerifier()
 

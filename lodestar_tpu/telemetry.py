@@ -10,7 +10,7 @@ collective in `models/batch_verify.py`):
   dispatch: wall seconds inside the `with` block (on an async backend:
   dispatch plus whatever blocking transfer the block performs), the
   program's name, the pow-2 size class its executable was compiled for,
-  the lane, first-call-per-(program, size class) **compile** detection,
+  the lane, first-call-per-(program, size class, lane) **compile** detection,
   the thread (`tid`), the launch that encloses it on that thread
   (`parent`, its `seq`; None at top level) and `phases`. Launches nest
   (`bls_lane_verify` holds `_single_launch_verify`), so sums are taken
@@ -85,7 +85,7 @@ _metrics = None  # guarded by: config-time (DeviceLaunchMetrics slot, set once a
 
 _lock = threading.Lock()
 _ledger: deque = deque(maxlen=DEFAULT_LEDGER_SIZE)  # guarded by: _lock
-_seen_keys: set = set()  # guarded by: _lock — (program, size_class) compile-detection keys
+_seen_keys: set = set()  # guarded by: _lock — (program, size_class, lane) compile-detection keys
 _seq = 0  # guarded by: _lock — monotonic dispatch sequence number
 _compiles = 0  # guarded by: _lock — first-call dispatches observed
 _tls = threading.local()  # .open: this thread's stack of open launches
@@ -291,14 +291,16 @@ def record_launch(
 
 
 def _record(program, size_class, seconds, lane, *, seq=None, parent=None, phases=None) -> dict:
-    """Compile detection is first-call-per-(program, size_class): the jit
-    caches hold one executable per key, so the first dispatch of a key
-    in this process carries trace+compile (or the persistent-cache
-    load) and is counted as a miss; every later dispatch of the key is
-    a hit. First calls nest like their launches, so only a top-level
-    one adds its seconds to the compile counter."""
+    """Compile detection is first-call-per-(program, size_class, lane):
+    the jit caches hold one executable per key and chip, so the first
+    dispatch of a key in this process carries trace+compile (or the
+    persistent-cache load), a lane's first carries the lowering and the
+    compile (or load) for its chip, and each is counted as a miss; every
+    later dispatch of the key is a hit. First calls nest like their
+    launches, so only a top-level one adds its seconds to the compile
+    counter."""
     global _seq, _compiles
-    key = (program, size_class)
+    key = (program, size_class, lane)
     with _lock:
         if seq is None:
             _seq += 1

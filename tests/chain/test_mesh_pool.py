@@ -307,8 +307,9 @@ def test_forced_host_platform_exposes_virtual_mesh():
     assert virtual_device_count() >= 8
 
 
-def test_build_device_mesh_modes_on_forced_platform():
+def test_build_device_mesh_modes_on_forced_platform(monkeypatch):
     from lodestar_tpu.chain.bls.mesh import build_device_mesh
+    from lodestar_tpu.models import batch_verify as bv
 
     # off: single lane, no collective
     off = build_device_mesh("off", fallback_verify_fn=lambda s: True)
@@ -323,6 +324,28 @@ def test_build_device_mesh_modes_on_forced_platform():
     assert forced.sharded_fn is not None
     labels = [lane.label for lane in forced.lanes]
     assert len(set(labels)) == len(labels)
+    # the split schedule's lanes (this CPU backend): no grouped entry,
+    # staged prep is device work, a bulk job may take the collective
+    assert forced.sharding_available() and not forced.grouping_available()
+    assert not forced.staged_prep_is_host_only()
+    # where the lanes carry the single launch (a TPU backend; asked of
+    # the models layer once, here) the mesh is built WITHOUT the
+    # collective: the bulk road is the one-lane road, a lane at a time
+    monkeypatch.setattr(bv, "single_launch_active", lambda: True)
+    tpu = build_device_mesh("on")
+    assert len(tpu) == virtual_device_count() and tpu.sharded_fn is None
+    assert not tpu.sharding_available()
+    assert tpu.grouping_available() and tpu.staged_prep_is_host_only()
+    assert [lane.label for lane in tpu.lanes] == labels
+    pool = BlsDeviceVerifierPool(mesh=tpu)
+    assert pool._staging
+    jobs = [SimpleNamespace(sets=_sets(66), batchable=False) for _ in range(6)]
+    # a bulk package is four jobs of the 128 class: one (512, 4) launch
+    assert pool._package_extent(PriorityClass.RANGE_SYNC, jobs) == (4, True)
+    assert pool._pick_placement(PriorityClass.RANGE_SYNC, jobs[:4], tpu.lanes)[0] == "single"
+    split_pool = BlsDeviceVerifierPool(mesh=forced)
+    assert split_pool._package_extent(PriorityClass.RANGE_SYNC, jobs) == (1, True)
+    assert split_pool._pick_placement(PriorityClass.RANGE_SYNC, jobs[:1], forced.lanes)[0] == "sharded"
 
 
 @pytest.mark.slow

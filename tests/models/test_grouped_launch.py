@@ -141,6 +141,7 @@ def _stand_in(verdicts, valids=None):
         return v, np.asarray(v if valids is None else valids, dtype=bool)
 
     program.__name__ = "_grouped_launch_verify"
+    program.trace = lambda *arrays, groups: None  # a lane traces a program once for all lanes before its first call
     return program
 
 
@@ -173,7 +174,7 @@ def test_three_jobs_ignore_the_empty_slot_and_a_rejected_job_is_false(sets, monk
 def test_a_shape_anomaly_degrades_to_one_launch_a_job(sets, monkeypatch, prep_metrics, program):
     served = []
     monkeypatch.setattr(bv, "_grouped_launch_verify", program)
-    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job: served.append(len(job)) or len(job) == 3)
+    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job, device=None: served.append(len(job)) or len(job) == 3)
     assert bv.verify_sets_grouped_launch([sets[:3], sets[3:5]]) == [True, False]
     assert served == [3, 2]
     assert prep_metrics.single_launch_fallbacks._value.get() == 1
@@ -185,7 +186,7 @@ def test_a_device_error_degrades_to_one_launch_a_job_counted_once(sets, monkeypa
 
     served = []
     monkeypatch.setattr(bv, "_grouped_launch_verify", boom)
-    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job: served.append(len(job)) or True)
+    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job, device=None: served.append(len(job)) or True)
     assert bv.verify_sets_grouped_launch([sets[:3], sets[3:5], sets[5:7]]) == [True, True, True]
     assert served == [3, 2, 2]
     assert prep_metrics.single_launch_fallbacks._value.get() == 1
@@ -197,7 +198,7 @@ def test_a_host_parse_error_degrades_to_one_launch_a_job(sets, monkeypatch, prep
 
     served = []
     monkeypatch.setattr(bv, "_parse_host_arrays", boom)
-    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job: served.append(len(job)) or True)
+    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job, device=None: served.append(len(job)) or True)
     assert bv.verify_sets_grouped_launch([sets[:3], sets[3:5]]) == [True, True]
     assert served == [3, 2]
     assert prep_metrics.single_launch_fallbacks._value.get() == 1

@@ -81,7 +81,7 @@ def _boot(opts: BeaconNodeOptions) -> BeaconNode:
 def test_node_default_flags_on_one_chip(one_chip):
     node = _boot(BeaconNodeOptions(rest_enabled=False, manual_clock=True))
     assert isinstance(node.bls, BlsDeviceVerifierPool)
-    assert node.device_runtime == {**ONE_CHIP, "verifier": "device", "hasher": "device"}
+    assert node.device_runtime == {**ONE_CHIP, "verifier": "device", "hasher": "device", "lanes": 1}
     assert device_htr.device_htr_active()
 
 

@@ -64,12 +64,14 @@ def test_ledger_deterministic_order_and_fields(tel):
     a = tel.record_launch("field_stage", 8, 0.010)
     b = tel.record_launch("field_stage", 8, 0.002, lane="dev1")
     c = tel.record_launch("hash_finish", 16, 0.020)
-    assert (a["seq"], b["seq"], c["seq"]) == (1, 2, 3)
+    d = tel.record_launch("field_stage", 8, 0.002, lane="dev1")
+    assert (a["seq"], b["seq"], c["seq"], d["seq"]) == (1, 2, 3, 4)
     entries = tel.launch_ledger()
-    assert [e["program"] for e in entries] == ["field_stage", "field_stage", "hash_finish"]
-    assert [e["size_class"] for e in entries] == [8, 8, 16]
-    assert [e["lane"] for e in entries] == [None, "dev1", None]
-    assert [e["compile"] for e in entries] == [True, False, True]
+    assert [e["program"] for e in entries] == ["field_stage", "field_stage", "hash_finish", "field_stage"]
+    assert [e["size_class"] for e in entries] == [8, 8, 16, 8]
+    assert [e["lane"] for e in entries] == [None, "dev1", None, "dev1"]
+    # a lane's first call of a key lowers and compiles (or loads) for its own chip
+    assert [e["compile"] for e in entries] == [True, True, True, False]
     # entries are copies: mutating a returned dict can't corrupt the ledger
     entries[0]["program"] = "tampered"
     assert tel.launch_ledger()[0]["program"] == "field_stage"
