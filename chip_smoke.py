@@ -14,16 +14,21 @@ CPU reference (the pure-Python BLS oracle, hashlib):
 3. the offload host as `offload.server.main()` builds it
    (`offload.server.boot_host`: the verifier pool behind the wire, the
    port bound only after the flat 128-row program and the multi-job
-   programs at (256, 2) and (512, 4) have each answered a valid and a
-   tampered known batch), behind real gRPC on localhost, answers eight
-   concurrent 128-set jobs, one tampered job and one malformed job
-   through `BlsOffloadClient`;
+   programs at (144, 2) and (288, 4), the shapes a block's halves ride,
+   have each answered a valid and a tampered known batch), behind real
+   gRPC on localhost, answers ten concurrent jobs, each a half of a
+   131-set block (eight valid, one with a cancelling pair, one
+   malformed), through `BlsOffloadClient`: the jobs a fleet sends, which
+   ride the warmed programs (a job of 73 to 128 sets that shares a
+   launch rides 128-row slots, and that program's first call is at its
+   first use: more than an RPC's deadline);
 4. the node's own `BlsDeviceVerifierPool` takes 1,024 sets at
    gossip-attestation priority, so packages reach the 512-set cap;
-   the multi-job launch at (2 slots, 128 rows) gives each job the
+   the multi-job launch at (2 slots, 72 rows) gives each job the
    oracle's verdict with a cancelling pair in the first job only and
-   an off-subgroup key in the last only, and the pool answers a
-   131-set block with ONE such launch;
+   an off-subgroup key in the last only, and so it does at (2 slots,
+   128 rows), the rung a job of more than 72 sets lifts its launch to;
+   the pool answers a 131-set block with ONE launch of 144 rows;
 5. `merkle_root_device` at 2^20 chunks (the 1M-validator shape) and
    `DirtyCollector` flushes of a 2^20-leaf stack with 512 and 2^17 dirty
    leaves must give hashlib's roots with `backend == "device"`;
@@ -140,9 +145,11 @@ def make_batches(seed: int) -> dict:
     off_key[i] = SignatureSet(
         pubkey=off_subgroup_pk, message=last[i].message, signature=last[i].signature
     )
-    batches["jobs"] = {"first": first, "last": last, "cancelling": cancelling, "off_key": off_key}
-    malformed = list(base)
-    malformed[rng.randrange(len(base))] = SignatureSet(
+    # a job of more than 72 sets beside them: the launch's slots are 128 rows then
+    batches["jobs"] = {"first": first, "last": last, "cancelling": cancelling, "off_key": off_key,
+                       "long": base[:100]}
+    malformed = list(first)
+    malformed[rng.randrange(len(first))] = SignatureSet(
         pubkey=b"\x00" * 48, message=base[0].message, signature=b"\xff" * 96
     )
     batches["malformed"] = malformed
@@ -230,12 +237,13 @@ async def phase_served(host, batches: dict, oracle) -> dict:
     check(backend.description["verifier"] == "device",
           f"offload server resolved {backend.description}")
     check(host.pool is not None, "the offload host serves no verifier pool")
-    check([(w["rows"], w["jobs"]) for w in host.warmed] == [(128, 1), (256, 2), (512, 4)],
+    check([(w["rows"], w["jobs"]) for w in host.warmed] == [(128, 1), (144, 2), (288, 4)],
           f"warm start answered {host.warmed}")
     client = BlsOffloadClient(f"127.0.0.1:{host.port}")
-    size = SIZE_CLASSES[0]
-    jobs = [("valid", batches[size]["valid"])] * 8 + [
-        ("tampered", batches[size]["tampered"]),
+    # a block's halves: every launch they form is one the warm start made the first call of
+    halves = batches["jobs"]
+    jobs = [("first", halves["first"]), ("last", halves["last"])] * 4 + [
+        ("cancelling", halves["cancelling"]),
         ("malformed", batches["malformed"]),
     ]
     try:
@@ -243,7 +251,7 @@ async def phase_served(host, batches: dict, oracle) -> dict:
     finally:
         await client.close()
     for (kind, _), verdict in zip(jobs, got):
-        want = oracle()["malformed" if kind == "malformed" else (size, kind)]
+        want = oracle()["malformed" if kind == "malformed" else ("job", kind)]
         check(verdict == want, f"served {kind} job: {verdict}, oracle {want}")
     log(f"served path: {len(jobs)} jobs, verdicts {[bool(v) for v in got]}")
     return {"description": backend.description, "verdicts": [bool(v) for v in got],
@@ -275,12 +283,14 @@ async def phase_node_pool(node, batches: dict, oracle) -> dict:
     return {"verdict": bool(got), "metrics": m, "lanes": pool.mesh.lane_states()}
 
 
-GROUPED_CASES = (("first", "last"), ("cancelling", "last"), ("first", "off_key"))
+GROUPED_CASES = (("first", "last"), ("cancelling", "last"), ("first", "off_key"),
+                 ("cancelling", "long"), ("long", "off_key"))
 
 
 async def phase_grouped(node, batches: dict, oracle) -> dict:
-    """The multi-job launch at (2, 128): a verdict a job, each the
-    oracle's for that job alone; then the pool's road to it."""
+    """The multi-job launch at (144, 2) and, where a job is longer than
+    72 sets, at (256, 2): a verdict a job, each the oracle's for that
+    job alone; then the pool's road to it."""
     from lodestar_tpu.chain.bls import VerifySignatureOpts
     from lodestar_tpu.models import batch_verify as bv
     from lodestar_tpu.scheduler import PriorityClass
@@ -294,7 +304,8 @@ async def phase_grouped(node, batches: dict, oracle) -> dict:
         log(f"grouped launch {names}: {got} (oracle {want}) {time.monotonic() - t0:.1f}s")
         check(got == want, f"grouped launch on {names}: {got}, oracle {want}")
         out["/".join(names)] = got
-    check(out["cancelling/last"] == [False, True] and out["first/off_key"] == [True, False],
+    check(out["cancelling/last"] == out["cancelling/long"] == [False, True]
+          and out["first/off_key"] == out["long/off_key"] == [True, False],
           f"the planted faults did not fail their own jobs only: {out}")
     # a 131-set block through the pool: jobs of 66 and 65, one launch
     pool = node.bls
@@ -454,8 +465,9 @@ async def run(seed: int, device: dict, compile_stats: dict) -> dict:
         for program in ("_single_launch_verify", "_prep_field_stage", "_prep_subgroup_stage",
                         "hash_finish", "batch_verify_staged", "bls_lane_verify"):
             check(f"{program}/{size}" in ran, f"ledger has no {program}/{size}: {sorted(ran)}")
-    for program in ("_grouped_launch_verify", "bls_lane_verify"):
-        check(f"{program}/256" in ran, f"ledger has no {program}/256: {sorted(ran)}")
+    # a block's launch is 144 rows, the program's own entry and the lane's; the longer job's, 256
+    for launch in ("_grouped_launch_verify/144", "bls_lane_verify/144", "_grouped_launch_verify/256"):
+        check(launch in ran, f"ledger has no {launch}: {sorted(ran)}")
     check(f"_merkle_root_fixed/{1 << TREE_DEPTH}" in ran, f"ledger has no merkle root: {sorted(ran)}")
     check(any(k.startswith("merkle_level/") for k in ran), "ledger has no merkle_level launch")
     report["compile"] = {k: round(v, 1) for k, v in compile_stats.items()}

@@ -29,7 +29,8 @@ drove one chip. This module is the mesh's serving shape:
 Placement policy lives in the pool (`chain/bls/pool.py`): a package
 goes to the least-occupied free lane, whatever its class. On a TPU
 (the single launch) that is the only road: a bulk package is four
-jobs in one (512, 4) launch on one chip, its parse staged, and N
+jobs in one launch on one chip ((288, 4) for a block's halves, the
+slot rule's rows: `telemetry.group_slot_rows`), its parse staged, and N
 lanes run N such launches side by side — the road one chip runs, a
 lane at a time (the backfill cell of the benchmark measures it on a
 v5e-4). Only where the lanes run the split schedule (a forced CPU
@@ -274,10 +275,11 @@ def mesh_launch(
     on staged inputs) serves them in ONE launch, and `ok` is a list of
     verdicts, one a job. Breaker accounting, cross-lane retry and the
     one `bls_lane_verify` ledger entry are the same; its size class is
-    the launch's rows (slots times a slot's size class)."""
+    the launch's rows (slots times a slot's rows, the slot rule's:
+    `telemetry.group_slot_rows`)."""
     if grouped:
-        size_class = telemetry.size_class_of(len(sets), floor=2) * max(
-            telemetry.size_class_of(len(job)) for job in sets
+        size_class = telemetry.size_class_of(len(sets), floor=2) * telemetry.group_slot_rows(
+            len(job) for job in sets
         )
     else:
         size_class = telemetry.size_class_of(len(sets))

@@ -25,14 +25,17 @@ N-worker thread pool replaced by one device pipeline:
   of the program's rows and a verdict each — on one chip the reason
   for the reference's cut (a job a core) is gone, and what a launch
   costs before its first row (a dispatch, a round trip, the serial
-  depth of its chains) is paid once. Smaller jobs and batchable jobs
-  keep their own launches.
+  depth of its chains) is paid once. A slot is as long as
+  `telemetry.group_slot_rows` says for the jobs that ride: 72 rows for
+  a block's halves of 66 and 65 sets, (144, 2) and (288, 4); 128 rows
+  for jobs of 73 sets and more, (256, 2) and (512, 4). Smaller jobs
+  and batchable jobs keep their own launches.
 * **Mesh lanes** (`chain/bls/mesh.py`): the pool serves a `VerifierMesh`
   of per-device launch lanes. One dispatcher waits for a free lane,
   dequeues through the shared priority queue, and places the package on
   the least-occupied free chip, whatever its class. On a TPU that is
   the whole policy: a bulk range-sync/backfill package is four jobs in
-  one (512, 4) launch on one lane, its parse staged, and N lanes run N
+  one (288, 4) launch on one lane, its parse staged, and N lanes run N
   such launches side by side — four lanes are fed the way one lane is
   fed, and one host thread's parses set their pace. Only a mesh built
   with the collective (the lanes of the split schedule: a forced CPU

@@ -359,8 +359,10 @@ def _fold_sum_slots(F, pts, groups: int):
 
 def _fp12_product_fold_slots(fs, mask, groups: int):
     """`prg.fp12_product_fold` per slot, masked rows replaced with one:
-    (groups, 2, 3, 2, 33). A slot is a power of two long (the size
-    classes are), so the fold pads nothing."""
+    (groups, 2, 3, 2, 33). A slot that is no power of two long (the
+    72-row rung, `telemetry.group_slot_rows`) is padded with ones by the
+    fold, as `cv.fold_sum` pads it with infinity: it folds as a 128-row
+    slot does."""
     with jax.named_scope("bls.fold"):
         ones = tw.fp12_one(fs.shape[:1])
         fs = _slot_major(jnp.where(mask[:, None, None, None, None], fs, ones), groups)
@@ -529,7 +531,9 @@ def _grouped_launch_verify(
     """The multi-job launch: `groups` jobs ride one program, a slot of
     rows each, and each gets the verdict `_single_launch_verify` gives
     it alone. One resident program per (rows, groups); the pool forms
-    (256, 2) and (512, 4)."""
+    (144, 2) and (288, 4) from jobs of 65 to 72 sets, a block's halves,
+    and (256, 2) and (512, 4) from longer ones: the same body traced at
+    the slot length `telemetry.group_slot_rows` gives."""
     return _single_launch_body(
         pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, groups
     )
@@ -973,8 +977,8 @@ def prepare_grouped_launch_inputs(jobs: list[list[SignatureSet]]) -> GroupedLaun
     """Host byte stage of the multi-job launch: each job parsed into its
     own slot of the launch's rows, with its own blinding and mask — what
     `prepare_single_launch_inputs` makes of the job alone, side by side.
-    A slot is as long as the largest job's size class; zero device
-    dispatches."""
+    A slot is as long as `telemetry.group_slot_rows` says for the jobs
+    that ride; zero device dispatches."""
     with telemetry.phase("bls.parse"):
         t0 = time.monotonic_ns()
         riding = [i for i, job in enumerate(jobs) if job and _encodings_have_their_lengths(job)]
@@ -984,7 +988,7 @@ def prepare_grouped_launch_inputs(jobs: list[list[SignatureSet]]) -> GroupedLaun
             _note_prep("single_launch", sum(len(j) for j in jobs), t0, rejected=True)
             return GroupedLaunchInputs(jobs, None, None, None, 0, riding)
         groups = grouped_launch_groups(len(riding))
-        slot = max(_pad_pow2(len(jobs[i])) for i in riding)
+        slot = telemetry.group_slot_rows(len(jobs[i]) for i in riding)
         # padding rows repeat a real row and are masked by every
         # consumer, an empty slot's rows too
         filler = jobs[riding[0]][0]

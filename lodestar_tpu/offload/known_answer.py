@@ -1,20 +1,26 @@
 """Known-answer warm start of an offload host (ROADMAP D2 and M6): before
-the port is bound, every verify program that a non-batchable job of the
-128 size class can ride answers two known batches through the pool, one
+the port is bound, every verify program that a non-batchable half of a
+block can ride answers two known batches through the pool, one
 valid and one with a tampered set, and each verdict is held against the
 CPU oracle's. That is the first call of each program (the trace and the
 compile, or the load from the persistent cache: 85–200 s each on a v5e),
 so no RPC pays it inside a 2 s `GOSSIP_BLOCK` budget, and a program that
 disagrees with the oracle stops the boot instead of serving.
 
-The programs are the pool's launch units (`chain/bls/pool._launch_units`):
-one job alone rides the flat 128-row program; two to four jobs of one
-package ride `_grouped_launch_verify` at (256 rows, 2 slots) or (512, 4).
+The programs are the pool's launch units (`chain/bls/pool._launch_units`)
+for jobs of `KNOWN_JOB_SETS` sets, a block's half: one job alone rides
+the flat 128-row program; two to four jobs of one package ride
+`_grouped_launch_verify` at the slot the slot rule gives such jobs
+(`telemetry.group_slot_rows`: 72 rows), (144 rows, 2 slots) or (288, 4).
+Three first calls, the programs a fleet's blocks ride.
 Where the mesh cannot group (its lanes have no grouped entry: a backend
 on the split schedule, a faked lane) every job rides the flat program of
 whatever schedule serves, and that alone is warmed.
-Smaller size classes (a job of 64 sets or fewer) stay cold: their first
-use compiles (ROADMAP S4 (a)).
+What stays cold, and compiles at its first use: the multi-job launch's
+128-row rung, (256, 2) and (512, 4), which jobs of 73 to 128 sets ride
+(no block is cut that way; warming it too would be two more first calls,
++110 to 170 s of boot), and every smaller size class (a job of 64 sets
+or fewer; ROADMAP S4 (a), M6).
 """
 
 from __future__ import annotations
@@ -70,14 +76,15 @@ def _job(valid: list[SignatureSet], tampered: SignatureSet | None) -> list[Signa
 
 
 def programs_of(pool) -> list[tuple[int, int]]:
-    """(rows, jobs) of every program a 128-class non-batchable job of
-    this pool can ride."""
-    from lodestar_tpu.chain.bls.pool import MAX_GROUP_JOBS, MAX_SIGNATURE_SETS_PER_JOB
+    """(rows, jobs) of every program a non-batchable job of
+    `KNOWN_JOB_SETS` sets can ride in this pool."""
+    from lodestar_tpu.chain.bls.pool import MAX_GROUP_JOBS
 
-    programs = [(MAX_SIGNATURE_SETS_PER_JOB, 1)]
+    programs = [(telemetry.size_class_of(KNOWN_JOB_SETS), 1)]
+    slot = telemetry.group_slot_rows([KNOWN_JOB_SETS])
     slots = 2
     while pool.mesh.grouping_available() and slots <= MAX_GROUP_JOBS:
-        programs.append((slots * MAX_SIGNATURE_SETS_PER_JOB, slots))
+        programs.append((slots * slot, slots))
         slots *= 2
     return programs
 

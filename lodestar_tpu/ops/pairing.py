@@ -231,9 +231,11 @@ def pairing(p_aff, q_aff):
 def fp12_product_fold(f, mask=None):
     """Product of a batch of Fp12 values down axis 0 (tree fold).
 
-    f: (B, 2, 3, 2, 33). mask: optional (B,) bool — False entries are
-    replaced with one (the device analogue of the oracle's skip-infinity
-    in `multi_pairing`). Returns (2, 3, 2, 33).
+    f: (B, 2, 3, 2, 33), or (B, ..., 2, 3, 2, 33) with further batch axes
+    that the fold carries (the multi-job launch's slots). mask: optional
+    (B,) bool — False entries are replaced with one (the device analogue
+    of the oracle's skip-infinity in `multi_pairing`). B is padded to a
+    power of two with ones. Returns f's shape without axis 0.
     """
     with jax.named_scope("bls.fold"):
         if mask is not None:
@@ -242,7 +244,7 @@ def fp12_product_fold(f, mask=None):
         b = f.shape[0]
         size = 1 if b <= 1 else 1 << (b - 1).bit_length()
         if size != b:
-            pad_ones = tw.fp12_one((size - b,))
+            pad_ones = tw.fp12_one((size - b,) + f.shape[1:-4])
             f = jnp.concatenate([f, pad_ones], axis=0)
         while f.shape[0] > 1:
             half = f.shape[0] // 2

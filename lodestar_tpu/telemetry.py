@@ -66,6 +66,8 @@ __all__ = [
     "record_launch",
     "launch_size_class",
     "size_class_of",
+    "GROUP_SLOT_RUNG",
+    "group_slot_rows",
     "launch_ledger",
     "launch_totals",
     "known_programs",
@@ -131,6 +133,31 @@ def size_class_of(n: int, floor: int = 8) -> int:
     reimplemented here so jax-free seams like chain/bls/mesh.py can
     label without importing the ops layer)."""
     return max(floor, 1 << (max(1, int(n)) - 1).bit_length())
+
+
+#: the one rung of the multi-job launch's slot ladder that is no power
+#: of two: the 64 class and one 8-row grain. The reference cuts a list
+#: of 129 to 144 sets into halves of 65 to 72
+#: (`chunkify_maximize_chunk_size` under MAX_SIGNATURE_SETS_PER_JOB), a
+#: full mainnet block's 131 into 66 and 65. One rung and no more: a slot
+#: length is a traced program (TUNING.md has the provenance).
+GROUP_SLOT_RUNG = 72
+
+
+def group_slot_rows(job_sizes) -> int:
+    """THE slot rule of the multi-job launch: the rows of a slot, from
+    the set counts of the jobs that ride. A slot is as long as the
+    longest job's size class, except that jobs of the 128 class which
+    all fit GROUP_SLOT_RUNG rows get that many: a block's halves ride
+    (144, 2) and four of them (288, 4), jobs of 73 to 128 sets (256, 2)
+    and (512, 4). The host parse that lays the slots out
+    (`models/batch_verify.prepare_grouped_launch_inputs`), the launch's
+    ledger label (`chain/bls/mesh.mesh_launch`) and the offload host's
+    warm list (`offload/known_answer.programs_of`) all ask here; it
+    stands beside `size_class_of` for the reason that one does."""
+    longest = max(job_sizes)
+    size_class = size_class_of(longest)
+    return GROUP_SLOT_RUNG if longest <= GROUP_SLOT_RUNG < size_class else size_class
 
 
 def launch_size_class(args) -> int:

@@ -162,7 +162,7 @@ def test_a_window_over_four_lanes_is_fed_the_way_one_lane_is_fed(ledger):
     assert sorted(entry for _, entry, _ in lanes.launches) == ["grouped", "prepared", "prepared", "prepared"]
     launches = [e for e in ledger() if e["program"] == "bls_lane_verify"]
     assert sorted(e["lane"] for e in launches) == ["dev0", "dev1", "dev2", "dev3"]
-    assert all(e["compile"] and e["size_class"] == 512 for e in launches)  # each lane's first call
+    assert all(e["compile"] and e["size_class"] == 288 for e in launches)  # each lane's first call, (288, 4)
     waited = [e["phases"].get("bls.parse_wait", 0.0) for e in launches]
     assert sum(1 for w in waited if w > 0) == 3
     assert all("bls.parse" in e["phases"] for e in launches if "bls.parse_wait" in e["phases"])

@@ -145,6 +145,31 @@ def test_group_limit_follows_the_package_cap():
     assert MAX_GROUP_JOBS == MAX_PACKAGE_SETS // MAX_SIGNATURE_SETS_PER_JOB == 4
 
 
+@pytest.mark.parametrize(
+    "sizes, slot",
+    [
+        ((65,), 72), ((66,), 72), ((72,), 72), ((73,), 128), ((100,), 128), ((128,), 128),
+        ((66, 65), 72), ((66, 100), 128), ((66, 65, 66, 65), 72), ((72, 72, 72, 73), 128),
+        ((1,), None), ((33,), None), ((64,), None), ((129,), None),
+    ],
+    ids=str,
+)
+def test_the_slot_of_the_jobs_that_may_share_a_launch(sizes, slot):
+    """The former's rule and the slot rule, side by side: jobs of the 128
+    class share a launch, in slots of 72 rows where every one of them
+    fits and of 128 otherwise; a job of 64 sets or fewer (or more than a
+    job may hold) shares none, so the rung is asked about no other."""
+    from lodestar_tpu import telemetry
+    from lodestar_tpu.chain.bls.pool import _groupable
+
+    jobs = _jobs(sizes)
+    assert [_groupable(j) for j in jobs] == [slot is not None] * len(jobs)
+    assert not _groupable(_jobs(sizes, batchable=(0,))[0])
+    if slot is not None:
+        assert telemetry.group_slot_rows(len(j.sets) for j in jobs) == slot
+        assert slot in (telemetry.GROUP_SLOT_RUNG, MAX_SIGNATURE_SETS_PER_JOB)
+
+
 # -- a gossip block: one launch, a verdict a job ---------------------------------
 
 
@@ -371,22 +396,37 @@ def test_grouped_launch_retries_on_a_sibling_lane():
 # -- the ledger entry --------------------------------------------------------------
 
 
-@pytest.mark.parametrize("sizes, rows", [((66, 65), 256), ((66, 65, 70), 512), ((128,) * 4, 512)],
-                         ids=["two", "three", "four"])
+@pytest.mark.parametrize(
+    "sizes, rows",
+    [((66, 65), 144), ((66, 65, 70), 288), ((66, 65, 66, 65), 288), ((72,) * 4, 288),
+     ((66, 100), 256), ((73, 66, 65), 512), ((128,) * 4, 512)],
+    ids=["block", "three", "two-blocks", "four-at-the-rung", "one-longer", "three-one-longer", "sync-committee"],
+)
 def test_grouped_launch_is_one_ledger_entry_with_the_launch_rows_as_its_class(sizes, rows):
+    """The ledger's label is written where no array is in sight, from
+    the slot rule; the host stage lays the arrays out by the same rule:
+    the label is the rows of what the lane dispatched."""
     from lodestar_tpu import telemetry
     from lodestar_tpu.chain.bls.mesh import mesh_launch
 
-    rig = Rig()
+    dispatched = []
+
+    def grouped(jobs):
+        gi = bv.prepare_grouped_launch_inputs(jobs)
+        dispatched.append({a.shape[0] for a in (*gi.arrays, gi.bits, gi.mask)})
+        return [True] * len(jobs)
+
+    mesh = VerifierMesh([MeshLane(0, lambda sets: True, verify_grouped_fn=grouped)])
     telemetry.reset_launch_telemetry()
     telemetry.configure_launch_telemetry("on")
     try:
-        ok, lane = mesh_launch(rig.mesh, [_sets(n, tag=i) for i, n in enumerate(sizes)], grouped=True)
+        ok, lane = mesh_launch(mesh, [_sets(n, tag=i) for i, n in enumerate(sizes)], grouped=True)
         entries = [e for e in telemetry.launch_ledger() if e["program"] == "bls_lane_verify"]
     finally:
         telemetry.reset_launch_telemetry()
-    assert ok == [True] * len(sizes) and lane is rig.mesh.lanes[0]
+    assert ok == [True] * len(sizes) and lane is mesh.lanes[0]
     assert [e["size_class"] for e in entries] == [rows]
+    assert dispatched == [{rows}]
 
 
 # -- the staged pipeline forms the same units --------------------------------------

@@ -340,7 +340,7 @@ def test_build_device_mesh_modes_on_forced_platform(monkeypatch):
     pool = BlsDeviceVerifierPool(mesh=tpu)
     assert pool._staging
     jobs = [SimpleNamespace(sets=_sets(66), batchable=False) for _ in range(6)]
-    # a bulk package is four jobs of the 128 class: one (512, 4) launch
+    # a bulk package is four jobs of the 128 class: one launch, (288, 4) for a block's halves
     assert pool._package_extent(PriorityClass.RANGE_SYNC, jobs) == (4, True)
     assert pool._pick_placement(PriorityClass.RANGE_SYNC, jobs[:4], tpu.lanes)[0] == "single"
     split_pool = BlsDeviceVerifierPool(mesh=forced)

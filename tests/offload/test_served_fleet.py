@@ -32,7 +32,7 @@ from perfbench.reference import bls as ref
 
 SEED = 2_900_000_029
 TENANTS = 4
-BLOCK = 131  # sets a call: jobs of 66 and 65, the 128 size class
+BLOCK = 131  # sets a call: jobs of 66 and 65, the 128 size class, 72 rows a slot
 
 
 class OracleLane:
@@ -147,7 +147,8 @@ def test_four_tenants_get_the_references_verdicts_and_a_fault_fails_its_tenant_a
     lane = OracleLane()
     before = {t.ident for t in threading.enumerate()}
     host = boot(lane)
-    assert [(w["rows"], w["jobs"]) for w in host.warmed] == [(128, 1), (256, 2), (512, 4)]
+    # three first calls: the programs a 66-set job rides under the slot rule (72 rows a slot)
+    assert [(w["rows"], w["jobs"]) for w in host.warmed] == [(128, 1), (144, 2), (288, 4)]
     started: list[tuple[int, int]] = []
     enqueue = host.pool._enqueue
     host.pool._enqueue = lambda job: (started.append((len(job.sets), int(job.priority))), enqueue(job))[1]
@@ -182,8 +183,8 @@ def test_four_tenants_get_the_references_verdicts_and_a_fault_fails_its_tenant_a
     for launches in (l1, l2, l3):
         # every job of a wave rode a multi-job launch (the ledger's size class is
         # the launch's rows), one of them with more than one tenant's jobs
-        assert all(e["size_class"] in (256, 512) for e in launches)
-        assert any(e["size_class"] == 512 for e in launches)
+        assert all(e["size_class"] in (144, 288) for e in launches)
+        assert any(e["size_class"] == 288 for e in launches)
     served = lane.grouped[-len(l1 + l2 + l3):]
     assert sum(len(v) for v in served) == 3 * TENANTS * 2
     # the two faulty jobs failed inside launches they shared, and nothing else failed
@@ -237,11 +238,66 @@ def refused(port: int) -> bool:
 def test_a_host_whose_known_answer_check_disagrees_does_not_bind_its_port(seams):
     port = free_port()
     before = {t.ident for t in threading.enumerate()}
-    with pytest.raises(KnownAnswerError, match="256-row"):
+    with pytest.raises(KnownAnswerError, match="144-row"):
         boot(OracleLane(lie=True), port=port)
     assert refused(port)
     time.sleep(0.2)
     assert [t for t in threading.enumerate() if t.ident not in before and t.name.startswith("offload-")] == []
+
+
+@pytest.fixture(scope="module")
+def known_answer_run():
+    """One known-answer check of a pool over the oracle lane, with the
+    launch ledger on: (the records, the verify launches it made)."""
+    lane = OracleLane()
+    telemetry.reset_launch_telemetry()
+    telemetry.configure_launch_telemetry("on")
+
+    async def go():
+        pool = BlsDeviceVerifierPool(mesh=lane.mesh)
+        try:
+            return known_answer.programs_of(pool), await known_answer.check_known_answers(pool)
+        finally:
+            await pool.close()
+
+    try:
+        programs, records = asyncio.run(go())
+        return programs, records, lane_launches(0)
+    finally:
+        telemetry.reset_launch_telemetry()
+
+
+@pytest.mark.parametrize("at, rows, jobs", [(0, 128, 1), (1, 144, 2), (2, 288, 4)], ids=["flat", "two", "four"])
+def test_the_warm_list_is_what_a_blocks_half_rides_and_each_launch_has_its_rows(known_answer_run, at, rows, jobs):
+    """`programs_of` asks the slot rule about jobs of `KNOWN_JOB_SETS`
+    sets, and the check holds each known batch's one launch to the rows
+    the list named: three programs, two launches each."""
+    programs, records, launches = known_answer_run
+    assert len(programs) == len(records) == 3 and len(launches) == 6
+    assert programs[at] == (rows, jobs)
+    assert (records[at]["rows"], records[at]["jobs"]) == (rows, jobs)
+    assert [e["size_class"] for e in launches[2 * at : 2 * at + 2]] == [rows, rows]
+
+
+def test_a_known_batch_answered_by_a_launch_of_other_rows_stops_the_boot(seams, monkeypatch):
+    """A list and a launch that disagree on the slot (two rules where
+    there is one) do not pass for a warmed program."""
+    monkeypatch.setattr(known_answer, "programs_of", lambda pool: [(128, 1), (256, 2)])
+    with pytest.raises(KnownAnswerError, match=r"256-row program was answered by launches of \[144\] rows"):
+        boot(OracleLane())
+
+
+def test_a_pool_that_cannot_group_warms_the_flat_program_alone():
+    mesh = VerifierMesh([MeshLane(0, lambda sets: True)])
+
+    async def go():
+        pool = BlsDeviceVerifierPool(mesh=mesh)
+        try:
+            return known_answer.programs_of(pool)
+        finally:
+            await pool.close()
+
+    assert asyncio.run(go()) == [(128, 1)]
 
 
 def test_an_rpc_sent_during_the_warm_start_is_refused_not_answered_late(seams):
@@ -304,7 +360,7 @@ def test_callers_counted_together_are_enqueued_together_and_a_stalled_one_holds_
     """The hand-over itself, on the host's backend: four callers on four
     threads, all counted before the first hands over, are in the pool's
     queue before its runner takes a package (one package of eight jobs,
-    two 512-row launches); a counted caller that never comes costs the
+    two 288-row launches); a counted caller that never comes costs the
     others the cap and no more."""
     lane = OracleLane()
     host = boot(lane)
@@ -345,7 +401,7 @@ def test_callers_counted_together_are_enqueued_together_and_a_stalled_one_holds_
         since = len(telemetry.launch_ledger())
         assert wave() == [True] * TENANTS
         assert packages == [2 * TENANTS]
-        assert [e["size_class"] for e in lane_launches(since)] == [512, 512]
+        assert [e["size_class"] for e in lane_launches(since)] == [288, 288]
         # a sender stalled between its call's headers and its request
         monkeypatch.setattr(offload_server, "HANDOVER_HOLD_CAP_S", 0.010)
         backend.accepted()
