@@ -29,11 +29,19 @@ CPU reference (the pure-Python BLS oracle, hashlib):
    an off-subgroup key in the last only, and so it does at (2 slots,
    128 rows), the rung a job of more than 72 sets lifts its launch to;
    the pool answers a 131-set block with ONE launch of 144 rows;
-5. `merkle_root_device` at 2^20 chunks (the 1M-validator shape) and
+5. the node's pool, its pubkey table grown to 64 entries (the genesis
+   validators' keys from node init, the rest appended as deposits are),
+   takes a 131-set block whose sets name their signers by registry
+   index: its halves ride one (144, 2) launch that gathers and sums
+   their signers on the chip (`bls.aggregate`) and agree with the
+   oracle; a swapped signer in the first job and an index outside the
+   table in the last each fail their own job only;
+6. `merkle_root_device` at 2^20 chunks (the 1M-validator shape) and
    `DirtyCollector` flushes of a 2^20-leaf stack with 512 and 2^17 dirty
    leaves must give hashlib's roots with `backend == "device"`;
-6. no fallback, degradation or wedge counter may have moved, and the
-   launch ledger must name the programs and size classes that ran.
+7. no fallback, degradation or wedge counter may have moved
+   (`lodestar_bls_aggregate_fallback_total` among them), and the launch
+   ledger must name the programs and size classes that ran.
 
 On a host with several chips the smoke checks nothing chip by chip:
 that every lane serves, each on its own chip, is what the benchmark's
@@ -67,6 +75,7 @@ DIRTY_COUNTS = (512, 1 << 17)
 ZERO_COUNTERS = (
     "lodestar_bls_prep_fallback_total",
     "lodestar_bls_single_launch_fallback_total",
+    "lodestar_bls_aggregate_fallback_total",
     "lodestar_ssz_htr_fallback_total",
     "lodestar_kzg_device_fallback_total",
     "lodestar_resilience_fallback_total",
@@ -329,6 +338,63 @@ async def phase_grouped(node, batches: dict, oracle) -> dict:
     return out
 
 
+TABLE_ENTRIES = 64
+
+
+async def phase_indexed(node, seed: int) -> dict:
+    """A block whose sets name their signers by registry index, through
+    the node's pool and its pubkey table: the launch sums each row's
+    signers on the chip. Interop keys, so a set of several signers is
+    signed once, by the sum of their secret keys."""
+    from lodestar_tpu.chain.bls import VerifySignatureOpts
+    from lodestar_tpu.crypto.bls.api import IndexedSignatureSet, SecretKey, sign, verify_signature_sets
+    from lodestar_tpu.crypto.bls.fields import R
+    from lodestar_tpu.models import batch_verify as bv
+    from lodestar_tpu.scheduler import PriorityClass
+    from lodestar_tpu.state_transition.genesis import interop_secret_keys
+
+    pool = node.bls
+    table = pool.pubkey_table
+    check(pool.takes_indexed_sets, "the node's pool holds no pubkey table on its lanes")
+    sks = interop_secret_keys(TABLE_ENTRIES)
+    check([table.pubkey_at(i) for i in range(len(table))] == [sk.to_pubkey() for sk in sks[: len(table)]],
+          "node init did not load the anchor state's registry into the table")
+    table.extend([sk.to_pubkey() for sk in sks[len(table):]])  # deposits: checked
+    check(table.lanes() == {lane.label: TABLE_ENTRIES for lane in pool.mesh.lanes}, f"table {table.lanes()}")
+
+    rng = random.Random(seed)
+    block = []
+    for _ in range(131):
+        signers = [rng.randrange(TABLE_ENTRIES) for _ in range(rng.randint(1, 6))]  # repeats are data
+        message = rng.randbytes(32)
+        scalar = sum(sks[i].scalar for i in signers) % R
+        block.append(IndexedSignatureSet(tuple(signers), message, sign(SecretKey(scalar), message)))
+    first, last = block[:66], block[66:]
+    s = first[5]
+    swapped = list(first)
+    swapped[5] = IndexedSignatureSet(((s.indices[0] + 1) % TABLE_ENTRIES,) + s.indices[1:], s.message, s.signature)
+    beyond = list(last)
+    beyond[7] = IndexedSignatureSet(last[7].indices + (TABLE_ENTRIES,), last[7].message, last[7].signature)
+    out = {}
+    for name, jobs in (("honest", [first, last]), ("swapped", [swapped, last]), ("beyond", [first, beyond])):
+        t0 = time.monotonic()
+        got = bv.verify_sets_grouped_launch(jobs, None, table)
+        want = [verify_signature_sets(job, table.pubkey_at) for job in jobs]
+        log(f"indexed launch {name}: {got} (oracle {want}) {time.monotonic() - t0:.1f}s")
+        check(got == want, f"indexed launch {name}: {got}, oracle {want}")
+        out[name] = got
+    check(out == {"honest": [True, True], "swapped": [False, True], "beyond": [True, False]},
+          f"the planted faults did not fail their own jobs only: {out}")
+    before = dict(pool.metrics)
+    opts = VerifySignatureOpts(priority=PriorityClass.GOSSIP_BLOCK)
+    check(await pool.verify_signature_sets(block, opts) is True, "pool on an honest indexed block")
+    check(await pool.verify_signature_sets(swapped + last, opts) is False, "pool passed a swapped signer")
+    check(pool.metrics["indexed_rows_started"] - before["indexed_rows_started"] == 262, f"pool {pool.metrics}")
+    out["aggregate_points_started"] = pool.metrics["aggregate_points_started"] - before["aggregate_points_started"]
+    log(f"pool: two indexed blocks, {out['aggregate_points_started']} signers named")
+    return out
+
+
 def phase_state_root(seed: int) -> dict:
     import jax
     import numpy as np
@@ -448,6 +514,7 @@ async def run(seed: int, device: dict, compile_stats: dict) -> dict:
                 host.stop()
             report["node_pool"] = await phase_node_pool(node, batches, oracle)
             report["grouped"] = await phase_grouped(node, batches, oracle)
+            report["indexed"] = await phase_indexed(node, seed)
         report["oracle"] = {str(k): v for k, v in oracle().items()}
         report["state_root"] = phase_state_root(seed)
         report["counters"] = counter_totals(node.metrics.creator.registry, host.creator.registry)

@@ -66,17 +66,20 @@ class IBlsVerifier(abc.ABC):
 
 
 class BlsSingleThreadVerifier(IBlsVerifier):
-    """Inline oracle verification (reference `singleThread.ts`)."""
+    """Inline oracle verification (reference `singleThread.ts`).
+    `resolver` (registry index -> compressed pubkey) lets it answer
+    `IndexedSignatureSet`s; without one such a set is False."""
 
-    def __init__(self) -> None:
+    def __init__(self, resolver=None) -> None:
         self._closed = False
+        self._resolver = resolver
 
     async def verify_signature_sets(
         self, sets: list[SignatureSet], opts: VerifySignatureOpts | None = None
     ) -> bool:
         from lodestar_tpu.crypto.bls.api import verify_signature_sets
 
-        return verify_signature_sets(sets)
+        return verify_signature_sets(sets, self._resolver)
 
     def can_accept_work(self) -> bool:
         return not self._closed

@@ -176,12 +176,19 @@ class VerifierMesh:
     With one lane this is exactly that lane's tracker value, so the
     pre-mesh admission behavior is unchanged."""
 
-    def __init__(self, lanes: Sequence[MeshLane], *, sharded_fn: Callable | None = None):
+    def __init__(
+        self, lanes: Sequence[MeshLane], *, sharded_fn: Callable | None = None, table=None
+    ):
         if not lanes:
             raise ValueError("a verifier mesh needs at least one lane")
         self.lanes = list(lanes)
         #: sharded_fn(sets, device_indices) -> bool over >=2 lanes
         self.sharded_fn = sharded_fn
+        #: the `PubkeyTable` the lanes' entries resolve indexed sets from
+        #: (`verify_fn(sets, table=...)`, `verify_grouped_fn` likewise);
+        #: None for lanes that speak pubkey bytes only (mocks, an
+        #: offload host: it holds no registry)
+        self.table = table
         from lodestar_tpu.offload.resilience import CircuitBreaker
 
         # gates the collective program only: a sharded error cannot name
@@ -276,7 +283,13 @@ def mesh_launch(
     verdicts, one a job. Breaker accounting, cross-lane retry and the
     one `bls_lane_verify` ledger entry are the same; its size class is
     the launch's rows (slots times a slot's rows, the slot rule's:
-    `telemetry.group_slot_rows`)."""
+    `telemetry.group_slot_rows`). The entry carries `indexed_rows`, the
+    sets of the launch that name their signers by registry index."""
+    from lodestar_tpu.crypto.bls.api import IndexedSignatureSet
+
+    indexed_rows = sum(
+        isinstance(s, IndexedSignatureSet) for job in (sets if grouped else [sets]) for s in job
+    )
     if grouped:
         size_class = telemetry.size_class_of(len(sets), floor=2) * telemetry.group_slot_rows(
             len(job) for job in sets
@@ -291,6 +304,7 @@ def mesh_launch(
         prefer = min(lanes, key=lambda l: l.occupancy.occupancy())
     tried: list[MeshLane] = []
     current = prefer
+    resolving = {"table": mesh.table} if mesh.table is not None else {}
     while True:
         tried.append(current)
         try:
@@ -308,7 +322,7 @@ def mesh_launch(
                     ok = [False] * len(sets) if grouped else False
             else:
                 with telemetry.launch(
-                    "bls_lane_verify", size_class, lane=current.label
+                    "bls_lane_verify", size_class, lane=current.label, indexed_rows=indexed_rows
                 ) as tel, current.occupancy.launch():
                     if use_staged and current.verify_prepared_fn is not None:
                         info = prepared.info
@@ -320,9 +334,9 @@ def mesh_launch(
                             tel.add_phase("bls.parse_wait", prepared.waited_s)
                         ok = current.verify_prepared_fn(prepared.inputs)
                     elif grouped:
-                        ok = current.verify_grouped_fn(sets)
+                        ok = current.verify_grouped_fn(sets, **resolving)
                     else:
-                        ok = bool(current.verify_fn(sets))
+                        ok = bool(current.verify_fn(sets, **resolving))
                     ok = [bool(v) for v in ok] if grouped else bool(ok)
         except Exception:
             # an error on a staged-inputs attempt may be input-bound
@@ -363,6 +377,7 @@ def build_device_mesh(
     *,
     fallback_verify_fn: Callable | None = None,
     wedge_threshold: int = LANE_WEDGE_THRESHOLD,
+    table=None,
 ) -> VerifierMesh:
     """Production mesh from the models layer's device enumeration.
 
@@ -381,17 +396,23 @@ def build_device_mesh(
     the mesh has no collective (a bulk package is one multi-job launch
     on one lane, as on one chip); where it runs the split schedule they
     have no grouped entry, their staged prep is device work, and a bulk
-    job may shard over the idle lanes."""
+    job may shard over the idle lanes.
+
+    `table` (the pool's `PubkeyTable`) is what the lanes resolve indexed
+    sets from. Lanes of the single launch sum a set's signers on their
+    chip, so each gets a copy of it here (`place_on`, one a device);
+    lanes of the split schedule take pubkey bytes and leave it on the
+    host (the counted fallback)."""
     if mode not in MESH_MODES:
         raise ValueError(f"bls_mesh must be one of {MESH_MODES}, got {mode!r}")
     from lodestar_tpu.models import batch_verify as bv
 
     single_launch = bv.single_launch_active()
 
-    def _lanes(entries) -> list[MeshLane]:
+    def _lanes(entries, devices) -> list[MeshLane]:
         """Lanes over (verify_fn, verify_prepared_fn, verify_grouped_fn)
         entries, one a device, with the backend's schedule as facts."""
-        return [
+        lanes = [
             MeshLane(
                 index,
                 verify_fn,
@@ -402,14 +423,19 @@ def build_device_mesh(
             )
             for index, (verify_fn, verify_prepared_fn, verify_grouped_fn) in enumerate(entries)
         ]
+        if table is not None and single_launch:
+            table.place_on(devices, [lane.label for lane in lanes])
+        return lanes
 
     def _single() -> VerifierMesh:
         if fallback_verify_fn is not None:
             return single_lane_mesh(fallback_verify_fn, wedge_threshold=wedge_threshold)
         return VerifierMesh(
             _lanes(
-                [(bv.verify_signature_sets_device, bv.verify_prepared, bv.verify_sets_grouped_launch)]
-            )
+                [(bv.verify_signature_sets_device, bv.verify_prepared, bv.verify_sets_grouped_launch)],
+                [None],  # wherever JAX puts it
+            ),
+            table=table,
         )
 
     if mode == "off":
@@ -430,6 +456,9 @@ def build_device_mesh(
                 bv.make_lane_verify_grouped_fn(i),
             )
             for i in range(n)
-        ]
+        ],
+        bv.mesh_devices(),
     )
-    return VerifierMesh(lanes, sharded_fn=None if single_launch else bv.make_mesh_sharded_fn())
+    return VerifierMesh(
+        lanes, sharded_fn=None if single_launch else bv.make_mesh_sharded_fn(table), table=table
+    )

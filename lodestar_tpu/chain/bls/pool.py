@@ -60,6 +60,16 @@ N-worker thread pool replaced by one device pipeline:
   holds all of it (nothing that arrives later could join it, so no
   launch's composition changes); an urgent arrival still overtakes the
   package taken ahead.
+* **The registry table** (`pubkey_table`, `chain/bls/pubkey_table.py`):
+  the pool owns the validator registry's pubkeys, one copy on every
+  lane's chip where the lanes run the single launch. A set may name
+  its signers by registry index (`IndexedSignatureSet`, the
+  reference's aggregate form of ISignatureSet): it is a row like any
+  other to everything here (size classes, slots, units, staging), its
+  parse is an index row, and its launch gathers and sums its signers
+  on the chip. A set with more signers than a launch's index matrix
+  has columns, or lanes without the table, takes host aggregation,
+  counted in `lodestar_bls_aggregate_fallback_total`.
 * **Wedge detection** (`offload/resilience.CircuitBreaker`): each lane
   carries its OWN wedge breaker — consecutive launch errors on a chip
   open it, the dispatcher stops placing work there, and in-flight work
@@ -99,7 +109,7 @@ import time
 from typing import Awaitable, Callable, Sequence
 
 from lodestar_tpu import slo, telemetry, tracing
-from lodestar_tpu.crypto.bls.api import SignatureSet
+from lodestar_tpu.crypto.bls.api import IndexedSignatureSet, SignatureSet
 from lodestar_tpu.logger import get_logger
 from lodestar_tpu.scheduler import (
     BULK_CLASSES,
@@ -348,9 +358,16 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         elif verify_fn is not None:
             self.mesh = single_lane_mesh(verify_fn, wedge_threshold=DEVICE_WEDGE_THRESHOLD)
         else:
+            from .pubkey_table import PubkeyTable
+
             self.mesh = build_device_mesh(
-                mesh_mode or "off", wedge_threshold=DEVICE_WEDGE_THRESHOLD
+                mesh_mode or "off", wedge_threshold=DEVICE_WEDGE_THRESHOLD, table=PubkeyTable()
             )
+        # the registry's pubkeys, which indexed sets are resolved from:
+        # the lanes' own (None where they speak pubkey bytes only). Its
+        # owner fills it: node init from the anchor state, the chain on
+        # every deposit
+        self.pubkey_table = self.mesh.table
 
         # prep→verify double buffering: whether packages' prep is
         # staged. Staging requires lanes that can CONSUME staged inputs
@@ -442,6 +459,10 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             "parse_hidden_ns": 0,
             # what the dispatcher waited for a staged parse with a lane free
             "parse_wait_ns": 0,
+            # sets that name their signers by registry index, and the
+            # signers they name (padding not counted)
+            "indexed_rows_started": 0,
+            "aggregate_points_started": 0,
         }
 
     @property
@@ -451,6 +472,13 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         return self.mesh.lanes[0].breaker
 
     # -- IBlsVerifier ---------------------------------------------------------
+
+    @property
+    def takes_indexed_sets(self) -> bool:
+        """Whether the lanes sum a set's signers from the registry table
+        on their chip: the producers then name signers by index
+        (`IndexedSignatureSet`) instead of summing pubkeys on the host."""
+        return self.pubkey_table is not None and self.pubkey_table.on_device
 
     def is_down(self) -> bool:
         """Every lane wedged (breaker open) or closed — the degradation
@@ -475,7 +503,8 @@ class BlsDeviceVerifierPool(IBlsVerifier):
             # inline path for cheap time-critical single sets
             from lodestar_tpu.crypto.bls.api import verify_signature_sets
 
-            return verify_signature_sets(sets)
+            table = self.pubkey_table
+            return verify_signature_sets(sets, table.pubkey_at if table is not None else None)
 
         priority = (
             PriorityClass(opts.priority) if opts.priority is not None else PriorityClass.API
@@ -831,10 +860,10 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         asyncio.get_event_loop().run_in_executor(None, self._prep_package, prepped)
         return prepped
 
-    def _default_prep_fn(self, sets: list[SignatureSet], lane_hint: int | None):
+    def _default_prep_fn(self, sets: list, lane_hint: int | None):
         from lodestar_tpu.models.batch_verify import prepare_inputs_for_lane
 
-        return prepare_inputs_for_lane(sets, lane_hint)
+        return prepare_inputs_for_lane(sets, lane_hint, self.pubkey_table)
 
     def _prep_lane_hint(self) -> int | None:
         """A free sibling lane to stage prep on (mesh with >1 chip);
@@ -867,7 +896,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         with self._overlap.prep():
             try:
                 if grouped:
-                    inputs = prepare_grouped_launch_inputs(sets)
+                    inputs = prepare_grouped_launch_inputs(sets, self.pubkey_table)
                 else:
                     inputs = self._prep_fn(sets, self._prep_lane_hint())
             except Exception as e:
@@ -980,6 +1009,14 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         if m is not None:
             m.lane_launches.labels(lane.label, mode).inc()
 
+    def _count_started(self, package: list[_Job]) -> None:
+        self.metrics["jobs_started"] += len(package)
+        self.metrics["sig_sets_started"] += sum(len(j.sets) for j in package)
+        indexed = [s for j in package for s in j.sets if isinstance(s, IndexedSignatureSet)]
+        if indexed:
+            self.metrics["indexed_rows_started"] += len(indexed)
+            self.metrics["aggregate_points_started"] += sum(len(s.indices) for s in indexed)
+
     def _launch_sets(
         self,
         lane: MeshLane,
@@ -1029,8 +1066,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         bad signature can't poison its neighbors, and a stale staged
         prep can't poison the retry)."""
         if not counted:
-            self.metrics["jobs_started"] += len(package)
-            self.metrics["sig_sets_started"] += sum(len(j.sets) for j in package)
+            self._count_started(package)
             # SLO launch stamp once per job: the sharded fallback road
             # (counted=True) already stamped at its collective launch
             for j in package:
@@ -1188,8 +1224,7 @@ class BlsDeviceVerifierPool(IBlsVerifier):
         re-verified per job so one bad signature can't poison its
         package (and so a lying collective can't be weaker than the
         single-device policy)."""
-        self.metrics["jobs_started"] += len(package)
-        self.metrics["sig_sets_started"] += sum(len(j.sets) for j in package)
+        self._count_started(package)
         for j in package:
             slo.job_launch(j.slo)
         all_sets = [s for j in package for s in j.sets]

@@ -133,6 +133,7 @@ class BeaconChain:
         self.p = p = p or active_preset()
         self.cfg = cfg
         self.bls = bls_verifier
+        self._pubkey2index: dict[bytes, int] = {}  # guarded by: event-loop (validation runs on the node's loop)
         self.metrics = metrics
         self.log = get_logger(name="lodestar.chain")
         t = ssz_types(p)
@@ -347,6 +348,38 @@ class BeaconChain:
 
     # -- block import ---------------------------------------------------------
 
+    @property
+    def indexed_sets(self) -> bool:
+        """Whether the signature-set producers name signers by registry
+        index: the verifier sums them from its pubkey table on the chip
+        (`BlsDeviceVerifierPool.takes_indexed_sets`)."""
+        return bool(getattr(self.bls, "takes_indexed_sets", False))
+
+    def registry_indices(self, state, pubkeys) -> tuple[int, ...]:
+        """The registry indices of validators named by pubkey (a sync
+        committee's members), from one pubkey -> index map kept for the
+        chain and extended as the registry grows (reference
+        `pubkey2index`, `cache/pubkeyCache.ts`)."""
+        known = self._pubkey2index
+        validators = state.validators
+        for i in range(len(known), len(validators)):
+            known[bytes(validators[i].pubkey)] = i
+        return tuple(known[bytes(pk)] for pk in pubkeys)
+
+    def _note_deposits(self, post_state) -> None:
+        """Append the validators a block's deposits added to the
+        verifier's pubkey table (the reference extends `index2pubkey`
+        the same way, `cache/pubkeyCache.ts` syncPubkeys). The registry
+        is append-only and its order is the deposit contract's, the
+        same on every fork, so an index names one key whichever state
+        a set was produced from."""
+        if not self.indexed_sets:
+            return
+        table = self.bls.pubkey_table
+        validators = post_state.validators
+        if len(table) < len(validators):
+            table.extend([bytes(validators[i].pubkey) for i in range(len(table), len(validators))])
+
     async def process_block(self, signed_block, *, is_timely: bool = False, priority=None):
         """Full import pipeline for one gossip/sync block. Serialized
         with other chain mutations via import_lock (REST threads vs the
@@ -428,7 +461,7 @@ class BeaconChain:
         # verification through the device pool (verifyBlock.ts:89-111)
         import asyncio
 
-        sets = get_block_signature_sets(work_state, signed_block, ctx)
+        sets = get_block_signature_sets(work_state, signed_block, ctx, indexed=self.indexed_sets)
 
         async def run_sigs():
             # own task: ensure_future snapshots the context, so the span
@@ -520,6 +553,7 @@ class BeaconChain:
         with tracing.span("persist_block"):
             self.blocks_db.put_binary(block_root, signed_type.serialize(signed_block))
             self.state_cache.add(block_root, post_state)
+        self._note_deposits(post_state)
 
         blk_epoch = compute_epoch_at_slot(block.slot, self.p)
         jc = post_state.current_justified_checkpoint

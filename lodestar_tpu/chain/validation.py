@@ -17,7 +17,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from lodestar_tpu import tracing
-from lodestar_tpu.crypto.bls.api import SignatureSet
+from lodestar_tpu.crypto.bls.api import IndexedSignatureSet, SignatureSet, aggregate_pubkeys
 from lodestar_tpu.params import (
     DOMAIN_AGGREGATE_AND_PROOF,
     DOMAIN_BEACON_ATTESTER,
@@ -126,7 +126,9 @@ def validate_gossip_attestation(
     from lodestar_tpu.state_transition.block import get_indexed_attestation
 
     indexed = get_indexed_attestation(attestation, ctx)
-    sig_set = indexed_attestation_signature_set(state, indexed, ctx)
+    sig_set = indexed_attestation_signature_set(
+        state, indexed, ctx, getattr(chain, "indexed_sets", False)
+    )
     return AttestationValidationResult(
         indexed_attestation=indexed,
         attesting_indices=attesting,
@@ -208,7 +210,9 @@ def validate_gossip_aggregate_and_proof(chain, signed_agg) -> AttestationValidat
         ),
     ]
     indexed = get_indexed_attestation(attestation, ctx)
-    sets.append(indexed_attestation_signature_set(state, indexed, ctx))
+    sets.append(indexed_attestation_signature_set(
+        state, indexed, ctx, getattr(chain, "indexed_sets", False)
+    ))
     return AttestationValidationResult(
         indexed_attestation=indexed,
         attesting_indices=[int(i) for i in indexed.attesting_indices],
@@ -378,7 +382,6 @@ def validate_sync_committee_contribution(chain, signed) -> SyncCommitteeValidati
     """sync_committee_contribution_and_proof topic checks; returns three
     signature sets (selection proof, outer signature, aggregate
     contribution)."""
-    from lodestar_tpu.crypto.bls.api import aggregate_pubkeys
     from lodestar_tpu.params import (
         DOMAIN_CONTRIBUTION_AND_PROOF,
         DOMAIN_SYNC_COMMITTEE,
@@ -431,13 +434,22 @@ def validate_sync_committee_contribution(chain, signed) -> SyncCommitteeValidati
         message=compute_signing_root(t.ContributionAndProof, cp, outer_domain),
         signature=bytes(signed.signature),
     )
+    # the participants by registry index: the set names them (a device
+    # node's verifier sums them on the chip) or sums their pubkeys here
     participating = [sub_pks[i] for i, b in enumerate(bits) if b]
     sync_domain = get_domain(state, DOMAIN_SYNC_COMMITTEE, epoch)
-    contribution_set = SignatureSet(
-        pubkey=aggregate_pubkeys(participating),
-        message=_sync_signing_root(bytes(contribution.beacon_block_root), sync_domain),
-        signature=bytes(contribution.signature),
-    )
+    if getattr(chain, "indexed_sets", False):
+        contribution_set = IndexedSignatureSet(
+            indices=chain.registry_indices(state, participating),
+            message=_sync_signing_root(bytes(contribution.beacon_block_root), sync_domain),
+            signature=bytes(contribution.signature),
+        )
+    else:
+        contribution_set = SignatureSet(
+            pubkey=aggregate_pubkeys(participating),
+            message=_sync_signing_root(bytes(contribution.beacon_block_root), sync_domain),
+            signature=bytes(contribution.signature),
+        )
     return SyncCommitteeValidationResult(
         indices_in_subcommittee=[],
         signature_sets=[selection_set, outer_set, contribution_set],

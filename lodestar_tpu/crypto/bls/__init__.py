@@ -7,6 +7,7 @@ arithmetic, batched Miller loops) and ``lodestar_tpu.models.batch_verify``
 
 from .api import (
     G2_INFINITY,
+    IndexedSignatureSet,
     PointDecodeError,
     SecretKey,
     SignatureSet,
@@ -23,6 +24,7 @@ from .api import (
 
 __all__ = [
     "G2_INFINITY",
+    "IndexedSignatureSet",
     "PointDecodeError",
     "SecretKey",
     "SignatureSet",

@@ -41,6 +41,8 @@ __all__ = [
     "G2_INFINITY",
     "aggregate_verify",
     "SignatureSet",
+    "IndexedSignatureSet",
+    "resolve_signature_set",
     "verify_signature_sets",
     "PointDecodeError",
 ]
@@ -189,11 +191,54 @@ class SignatureSet:
     aggregation has been applied — i.e. the exact wire shape shipped to the
     worker pool as SignatureSetsWorkerReq
     (`packages/beacon-node/src/chain/bls/multithread/types.ts:8-17`).
+    `IndexedSignatureSet` is the form before it.
     """
 
     pubkey: bytes  # 48B compressed G1
     message: bytes  # 32B signing root
     signature: bytes  # 96B compressed G2
+
+
+@dataclass(frozen=True)
+class IndexedSignatureSet:
+    """A work item that names its signers by validator-registry index:
+    ISignatureSet's two forms before aggregation (`signatureSets.ts:10`:
+    `single`, one index; `aggregate`, `pubkeys: PublicKey[]`), resolved
+    where the reference resolves them, against the deserialized registry
+    (`EpochContext.index2pubkey`): here the verifier's pubkey table
+    (`chain/bls/pubkey_table.py`), whose device copy the launch gathers
+    from and sums, or a host resolver for the oracle. Its verdict is
+    `fast_aggregate_verify`'s over the named pubkeys: no index, an index
+    the registry lacks, or signers that sum to the identity make it
+    False."""
+
+    indices: tuple[int, ...]  # registry indices of the signers, repeats allowed
+    message: bytes  # 32B signing root
+    signature: bytes  # 96B compressed G2
+
+
+def resolve_signature_set(s, resolver) -> "SignatureSet | None":
+    """`s` as a `SignatureSet` (itself, or an indexed set with its
+    signers' pubkeys summed on the host: the oracle's road and the
+    device pool's counted fallback), or None where it has no valid
+    aggregate. `resolver(index)` gives the compressed pubkey at a
+    registry index, or None where the registry has none."""
+    if isinstance(s, SignatureSet):
+        return s
+    if resolver is None or not s.indices:
+        return None
+    pks = [resolver(i) for i in s.indices]
+    if any(pk is None for pk in pks):
+        return None
+    try:
+        agg = None
+        for pk in pks:
+            agg = g1_add(agg, _decode_pubkey(bytes(pk)))
+    except PointDecodeError:
+        return None
+    if agg is None:
+        return None
+    return SignatureSet(pubkey=g1_to_bytes(agg), message=s.message, signature=s.signature)
 
 
 def _random_coeff() -> int:
@@ -204,7 +249,7 @@ def _random_coeff() -> int:
             return k
 
 
-def verify_signature_sets(sets: list[SignatureSet]) -> bool:
+def verify_signature_sets(sets: "list[SignatureSet | IndexedSignatureSet]", resolver=None) -> bool:
     """Random-linear-combination batch verification (always randomized).
 
     Checks e(-g1, sum_i r_i S_i) * prod_i e(r_i PK_i, H(m_i)) == 1 with one
@@ -215,8 +260,15 @@ def verify_signature_sets(sets: list[SignatureSet]) -> bool:
     own bound (`chain/bls/interface.ts:8`). There is deliberately no
     way to disable the blinding coefficients: an unrandomized batch is
     forgeable (defects in different sets can cancel).
+
+    An `IndexedSignatureSet` is resolved through `resolver` (registry
+    index -> compressed pubkey, None for an index the registry lacks)
+    and its signers' pubkeys summed first (`resolve_signature_set`).
     """
     if not sets:
+        return False
+    sets = [resolve_signature_set(s, resolver) for s in sets]
+    if any(s is None for s in sets):
         return False
     try:
         decoded = [

@@ -393,6 +393,8 @@ class BlsPrepMetrics:
     fallbacks: Counter  # device-prep errors degraded to host prep
     single_launch_fallbacks: Counter  # single-launch errors degraded to the split schedule
     rejected: Counter  # prep calls that rejected a structurally invalid batch
+    aggregate_fallbacks: Counter  # indexed sets whose signers' pubkeys the host summed
+    table_entries: Gauge  # pubkey-table entries on each lane's device
     launches: Counter  # ALL dispatches at ops/prep.py's seam (prep legs AND single-launch verifies)
 
 
@@ -564,6 +566,19 @@ def create_bls_prep_metrics(c: "RegistryMetricCreator") -> BlsPrepMetrics:
         rejected=c.counter(
             "lodestar_bls_prep_rejected_total",
             "Prep calls that rejected a structurally invalid batch",
+        ),
+        aggregate_fallbacks=c.counter(
+            "lodestar_bls_aggregate_fallback_total",
+            "Indexed signature sets whose signers' pubkeys were summed on "
+            "the host (more signers than a launch's index matrix has "
+            "columns, or lanes that hold no pubkey table) instead of on "
+            "the device",
+        ),
+        table_entries=c.gauge(
+            "lodestar_bls_pubkey_table_entries",
+            "Validator pubkeys in the verify lanes' resident registry "
+            "table, by lane",
+            ["lane"],
         ),
         launches=c.counter(
             "lodestar_bls_prep_launches_total",

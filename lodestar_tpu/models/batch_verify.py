@@ -9,17 +9,24 @@ shared final exponentiation — exactly the semantics of blst's
 
 Split of labor (SURVEY §7 phase 1):
 
-* **Host**: decompression (sqrt), KeyValidate/subgroup checks, hash-to-G2
-  of the 32-byte signing roots, blinding-coefficient sampling. These are
-  per-set scalar work with data-dependent failure paths — the wrong shape
-  for a lockstep device program — and their cost is amortized by the
-  pubkey/hash caches in the verifier layer above (the reference holds the
-  same split: pubkeys are deserialized once into `EpochContext.index2pubkey`
-  and reused, `state-transition/src/cache/pubkeyCache.ts`).
-* **Device** (one jitted program per padded batch size): 64-bit blinded
-  scalar multiplications in G1 and G2, the G2 fold to the aggregate
-  signature, N+1 Miller loops in lockstep, one product fold, one final
-  exponentiation, the ==1 predicate.
+* **Host**, on a CPU backend (the split schedule): decompression (sqrt),
+  KeyValidate/subgroup checks, hash-to-G2 of the 32-byte signing roots.
+  On an accelerator (the single launch) the host does byte work only:
+  flag and limb parsing, expand_message_xmd, blinding-coefficient
+  sampling, and for a set that names its signers by registry index
+  (`IndexedSignatureSet`) the bounds check of its index row.
+* **Device** (one jitted program per padded batch size): on an
+  accelerator the decompression, subgroup checks and hash-to-G2 too,
+  and the pubkey aggregation of indexed sets (`bls.aggregate`: a gather
+  from the registry table resident on the chip,
+  `chain/bls/pubkey_table.py`, and a K-point sum a row — the
+  reference's main-thread `getAggregatedPubkey` over
+  `EpochContext.index2pubkey`, `state-transition/src/cache/pubkeyCache.ts`);
+  then 64-bit blinded scalar multiplications in G1 and G2, the G2 fold
+  to the aggregate signature, N+1 Miller loops in lockstep, one product
+  fold, one final exponentiation, the ==1 predicate. Only the counted
+  fallback (`lodestar_bls_aggregate_fallback_total`: more than K
+  signers, or lanes without the table) still sums pubkeys on the host.
 
 The blinding is mandatory: an unrandomized batch is forgeable (defects in
 different sets can cancel). Coefficient 0 is resampled; the first
@@ -40,10 +47,10 @@ import numpy as np
 
 from lodestar_tpu import telemetry
 from lodestar_tpu.crypto.bls import curve as C
-from lodestar_tpu.crypto.bls.api import SignatureSet
+from lodestar_tpu.crypto.bls.api import IndexedSignatureSet, SignatureSet, resolve_signature_set
 from lodestar_tpu.crypto.bls.curve import G1_GEN
 from lodestar_tpu.crypto.bls.hash_to_curve import hash_to_g2
-from lodestar_tpu.crypto.bls.serdes import PointDecodeError, g1_from_bytes, g2_from_bytes
+from lodestar_tpu.crypto.bls.serdes import PointDecodeError, g1_from_bytes, g1_to_bytes, g2_from_bytes
 from lodestar_tpu.ops import curve as cv
 from lodestar_tpu.ops import fp
 from lodestar_tpu.ops import pairing as prg
@@ -51,6 +58,7 @@ from lodestar_tpu.ops import tower as tw
 
 __all__ = [
     "COEFF_BITS",
+    "AGGREGATE_ROW_POINTS",
     "SingleLaunchInputs",
     "GroupedLaunchInputs",
     "configure_device_prep",
@@ -71,6 +79,7 @@ __all__ = [
     "prepare_inputs_for_lane",
     "verify_signature_sets_sharded",
     "mesh_device_count",
+    "mesh_devices",
     "make_lane_verify_fn",
     "make_lane_verify_prepared_fn",
     "make_lane_verify_grouped_fn",
@@ -78,6 +87,13 @@ __all__ = [
 ]
 
 COEFF_BITS = 64  # blinding scalar width, matches blst's 64-bit rand coeffs
+# K, the columns of a launch's index matrix: the most signers of a set
+# whose pubkeys the launch sums from the registry table. The mainnet
+# preset's SYNC_COMMITTEE_SIZE (512), which is also a beacon committee at
+# 2^20 validators (2^20 / 32 slots / 64 committees); a set with more
+# signers (MAX_VALIDATORS_PER_COMMITTEE is 2,048, reached above 4 M
+# validators) takes the counted host aggregation. TUNING.md has the row.
+AGGREGATE_ROW_POINTS = 512
 
 # --- the verify schedule -----------------------------------------------------
 # Which schedule a batch runs is a fact of the backend, constant for a
@@ -175,6 +191,27 @@ def _note_single_launch_fallback(err: Exception) -> None:
     )
 
 
+def _note_aggregate_fallback(n_sets: int) -> None:
+    """Indexed sets whose signers were summed on the host."""
+    m = _prep_metrics
+    if m is not None:
+        m.aggregate_fallbacks.inc(n_sets)
+
+
+def _host_aggregated(sets: list, table) -> "list[SignatureSet] | None":
+    """`sets` with every indexed one as a byte set, its signers' pubkeys
+    summed on the host from the table's host side (the counted
+    fallback: the roads that take pubkey bytes only); None where one
+    has no valid aggregate, a final structural verdict."""
+    n_indexed = sum(1 for s in sets if isinstance(s, IndexedSignatureSet))
+    if not n_indexed:
+        return sets
+    _note_aggregate_fallback(n_indexed)
+    resolver = table.pubkey_at if table is not None else None
+    out = [resolve_signature_set(s, resolver) for s in sets]
+    return None if any(s is None for s in out) else out
+
+
 def _fp_to_mont_host(xs: list[int]) -> np.ndarray:
     """Pure-numpy mont conversion: host prep must never bounce arrays
     through the device (a jitted to_mont plus the pull-back is a device
@@ -260,8 +297,66 @@ def prepare_sets(sets: list[SignatureSet]):
     )
 
 
-def _encodings_have_their_lengths(sets: list[SignatureSet]) -> bool:
-    return all(len(bytes(s.pubkey)) == 48 and len(bytes(s.signature)) == 96 for s in sets)
+def _encodings_have_their_lengths(sets: list) -> bool:
+    return all(
+        len(bytes(s.signature)) == 96
+        and (isinstance(s, IndexedSignatureSet) or len(bytes(s.pubkey)) == 48)
+        for s in sets
+    )
+
+
+# what an indexed row carries where a byte row carries its pubkey: any
+# well-formed key does (the row's pubkey comes from the table)
+_FILLER_PUBKEY = g1_to_bytes(G1_GEN)
+
+
+def _byte_rows(sets: list, size: int, table):
+    """`sets` as the rows of a launch: (byte sets, rows_ok, indexed). A
+    byte set is its own row. An indexed set whose signers the launch
+    can sum (the lanes hold the table, 1 to K signers, every index in
+    the table: a gather clamps silently, so the bounds are checked
+    here) becomes a row of `indexed` = (idx (size, K) int32 of table
+    rows, the identity's in the padded columns; is_indexed (size,)
+    bool), None where no set is such, and a filler pubkey stands where
+    a byte row has its own. One with more than K signers, or lanes
+    without the table, takes the counted host aggregation and rides as
+    a byte row. No signer, or an index the registry lacks, makes the
+    row structurally invalid (`rows_ok` (size,) bool)."""
+    from lodestar_tpu.chain.bls.pubkey_table import IDENTITY_ROW
+
+    rows_ok = np.ones(size, dtype=bool)
+    if not any(isinstance(s, IndexedSignatureSet) for s in sets):
+        return sets, rows_ok, None
+    rows: list[SignatureSet] = []
+    idx = is_indexed = None
+    fallbacks = 0
+    on_device = table is not None and table.on_device
+    resolver = table.pubkey_at if table is not None else None
+    for row, s in enumerate(sets):
+        if not isinstance(s, IndexedSignatureSet):
+            rows.append(s)
+            continue
+        pubkey = _FILLER_PUBKEY
+        if on_device and len(s.indices) <= AGGREGATE_ROW_POINTS:
+            signers = np.fromiter(s.indices, dtype=np.int64, count=len(s.indices))
+            if not signers.size or not table.contains(signers):
+                rows_ok[row] = False
+            else:
+                if idx is None:
+                    idx = np.full((size, AGGREGATE_ROW_POINTS), IDENTITY_ROW, dtype=np.int32)
+                    is_indexed = np.zeros(size, dtype=bool)
+                idx[row, : signers.size] = signers + 1  # registry index i is table row i + 1
+                is_indexed[row] = True
+        else:
+            fallbacks += 1
+            resolved = resolve_signature_set(s, resolver)
+            rows_ok[row] = resolved is not None
+            if resolved is not None:
+                pubkey = resolved.pubkey
+        rows.append(SignatureSet(pubkey=pubkey, message=s.message, signature=s.signature))
+    if fallbacks:
+        _note_aggregate_fallback(fallbacks)
+    return rows, rows_ok, None if idx is None else (idx, is_indexed)
 
 
 def _parse_host_arrays(sets: list[SignatureSet], size: int):
@@ -290,6 +385,20 @@ def _parse_host_arrays(sets: list[SignatureSet], size: int):
     sig_limbs, sig_sign, sig_struct = dp.parse_g2_compressed(dp.pad_rows(sig_raw, size))
     lo, hi = dp.hash_to_field_limbs(msgs + [msgs[0]] * (size - n))
     return pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi
+
+
+def _parse_launch_rows(sets: list, size: int, table):
+    """The single launch's host stage over sets of either form:
+    `_byte_rows`, then `_parse_host_arrays` of the rows. Returns
+    ((pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi, struct_ok),
+    indexed), or None on a wrong-length encoding."""
+    rows, rows_ok, indexed = _byte_rows(sets, size, table)
+    parsed = _parse_host_arrays(rows, size)
+    if parsed is None:
+        return None
+    pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi = parsed
+    struct_ok = pk_struct & sig_struct & rows_ok
+    return (pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi, struct_ok), indexed
 
 
 def _prepare_sets_device_arrays(sets: list[SignatureSet], size: int):
@@ -449,7 +558,8 @@ _stage_fold_verdict = jax.jit(_fold_verdict_one)
 
 
 def _single_launch_body(
-    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, groups: int
+    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, groups: int,
+    indexed: tuple = (),
 ):
     """The single-launch chain over `groups` slots of equal length, one
     RLC batch a slot: compressed-point limbs + hash-to-field halves in,
@@ -466,7 +576,14 @@ def _single_launch_body(
     flat over all rows; what is per batch (the signature aggregate, its
     (-g1, S_agg) pair, the Fp12 product, the final exponentiation, the
     structural veto) is per slot, so a slot's verdict is what the
-    program returns for that slot's rows alone. Structurally invalid
+    program returns for that slot's rows alone. `indexed`, where the
+    launch has rows that name their signers by registry index, is
+    (table_x, table_y, idx, is_indexed): such a row's pubkey is the sum
+    of the table rows its index row names (`bls.aggregate`:
+    `ops/msm.py:aggregate_rows_g1`; an identity sum makes the row
+    invalid), the other rows' comes from their bytes as in a launch
+    without the four; everything after the pubkey is the same.
+    Structurally invalid
     rows (host parse flags in `struct_ok`, on-curve/subgroup flags
     decided here) fold into the verdict on device: any invalid unmasked
     row makes its slot False, exactly the fail-fast the split schedule
@@ -490,6 +607,15 @@ def _single_launch_body(
         )
     with jax.named_scope("bls.hash_finish"):
         h_x, h_y = dp.hash_finish(q0, q1)
+    if indexed:
+        from lodestar_tpu.ops import msm
+
+        table_x, table_y, idx, is_indexed = indexed
+        with jax.named_scope("bls.aggregate"):
+            agg_x, agg_y, agg_ok = msm.aggregate_rows_g1(table_x, table_y, idx)
+            pk_x = jnp.where(is_indexed[:, None], agg_x, pk_x)
+            pk_y = jnp.where(is_indexed[:, None], agg_y, pk_y)
+            pk_ok = jnp.where(is_indexed, agg_ok, pk_ok)
 
     # RLC aggregation + Miller loop + final exponentiation. Invalid rows
     # carry in-contract relaxed limbs (the pow-chain outputs), so the
@@ -511,31 +637,33 @@ def _single_launch_body(
 
 @jax.jit
 def _single_launch_verify(
-    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask
+    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, *indexed
 ):
     """THE single-launch program: one batch, scalar verdict out — one
     resident device program per pow-2 size class
     (`ops.prep.SINGLE_LAUNCH_BUDGET` dispatches per batch, counted at
-    ops/prep.py's `_dispatch` seam). `_single_launch_body` with one
-    slot."""
+    ops/prep.py's `_dispatch` seam), and one more per size class for
+    batches with indexed rows (`indexed`: the body's four arrays).
+    `_single_launch_body` with one slot."""
     verdict, batch_valid = _single_launch_body(
-        pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, 1
+        pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, 1, indexed
     )
     return verdict[0], batch_valid[0]
 
 
 @functools.partial(jax.jit, static_argnames="groups")
 def _grouped_launch_verify(
-    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, *, groups
+    pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, *indexed, groups
 ):
     """The multi-job launch: `groups` jobs ride one program, a slot of
     rows each, and each gets the verdict `_single_launch_verify` gives
     it alone. One resident program per (rows, groups); the pool forms
     (144, 2) and (288, 4) from jobs of 65 to 72 sets, a block's halves,
     and (256, 2) and (512, 4) from longer ones: the same body traced at
-    the slot length `telemetry.group_slot_rows` gives."""
+    the slot length `telemetry.group_slot_rows` gives, with or without
+    the body's four `indexed` arrays."""
     return _single_launch_body(
-        pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, groups
+        pk_x_std, pk_sign, sig_x_std, sig_sign, lo, hi, struct_ok, coeff_bits, mask, groups, indexed
     )
 
 
@@ -713,7 +841,7 @@ def _finish_inputs(pk, h, sig, n: int, size: int):
     return pk, h, sig, bits, mask
 
 
-def build_device_inputs(sets: list[SignatureSet], size: int | None = None):
+def build_device_inputs(sets: list, size: int | None = None, table=None):
     """Input prep + padding: decode/validate/hash N sets and pad the
     arrays to `size` (default: next power of two >= 8, the size-class
     bucketing that keeps one compiled program per class — the device
@@ -725,9 +853,13 @@ def build_device_inputs(sets: list[SignatureSet], size: int | None = None):
     (`single_launch_active`), on the host otherwise. A device prep
     ERROR falls back to the verified host pipeline (native C++ → python
     oracle); a structural-invalid verdict is final on whichever layer
-    produced it.
+    produced it. This road takes pubkey bytes: an indexed set's signers
+    are summed on the host first, from `table` (counted).
     """
     if not sets:
+        return None
+    sets = _host_aggregated(sets, table)
+    if sets is None:
         return None
     n = len(sets)
     if size is None:
@@ -777,17 +909,18 @@ def make_synthetic_sets(n: int, seed: int = 1) -> list[SignatureSet]:
     return sets
 
 
-def verify_signature_sets_device(sets: list[SignatureSet], device=None) -> bool:
+def verify_signature_sets_device(sets: list, device=None, table=None) -> bool:
     """End-to-end single-device batch verify of N signature sets, on
-    `device` (a lane's chip) or, None, wherever JAX puts it.
+    `device` (a lane's chip) or, None, wherever JAX puts it. `table`
+    (the pool's `PubkeyTable`) is what indexed sets are resolved from.
 
     On an accelerator the single-launch program (one counted dispatch,
     bytes-in → verdict-out, with its own degradation chain back to the
     split schedule); otherwise the split schedule: host prep followed
     by the RLC verify dispatch."""
     if single_launch_active():
-        return verify_sets_single_launch(sets, device)
-    return _verify_sets_split(sets, device)
+        return verify_sets_single_launch(sets, device, table)
+    return _verify_sets_split(sets, device, table)
 
 
 def _placed_on(device):
@@ -799,14 +932,14 @@ def _placed_on(device):
     return jax.default_device(device) if device is not None else contextlib.nullcontext()
 
 
-def _verify_sets_split(sets: list[SignatureSet], device=None) -> bool:
+def _verify_sets_split(sets: list, device=None, table=None) -> bool:
     """The split (prep-then-verify) schedule: `build_device_inputs`
     (fused 3-launch device prep, host prep on error or off an
     accelerator) plus
     the separate RLC verify dispatch — the single-launch program's
     differential reference and per-batch fallback."""
     with _placed_on(device):
-        inputs = build_device_inputs(sets)
+        inputs = build_device_inputs(sets, table=table)
         if inputs is None:
             return False
         return _verify_split_prepared(inputs)
@@ -827,19 +960,34 @@ class SingleLaunchInputs:
     limb/flag/hash arrays, fresh blinding bits, and the padding mask —
     everything `_single_launch_verify` consumes, produced by byte work
     only (no device dispatches). Carries the original sets so the
-    verify side can degrade to the split schedule on a device error."""
+    verify side can degrade to the split schedule on a device error.
+    `table` is what the sets' indices were resolved against; `indexed`
+    is None, or (idx, is_indexed) where rows name their signers by
+    index: the dispatch takes the table's arrays on the chip it runs on
+    (`_indexed_args`)."""
 
-    __slots__ = ("sets", "arrays", "bits", "mask", "n")
+    __slots__ = ("sets", "arrays", "bits", "mask", "n", "table", "indexed")
 
-    def __init__(self, sets, arrays, bits, mask, n):
+    def __init__(self, sets, arrays, bits, mask, n, table=None, indexed=None):
         self.sets = sets
         self.arrays = arrays  # (pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi, struct)
         self.bits = bits
         self.mask = mask
         self.n = n
+        self.table = table
+        self.indexed = indexed
 
 
-def prepare_single_launch_inputs(sets: list[SignatureSet]):
+def _indexed_args(inputs, device) -> tuple:
+    """The program's four `indexed` arguments on `device`, none for a
+    byte-only launch. The table's copy is taken now: rows are written
+    once, so it holds every row the parse checked."""
+    if inputs.indexed is None:
+        return ()
+    return (*inputs.table.arrays_on(device), *inputs.indexed)
+
+
+def prepare_single_launch_inputs(sets: list, table=None):
     """Host byte stage of the single-launch path: compressed-flag
     parsing, limb unpacking, expand_message_xmd, blinding sampling —
     zero device dispatches. Returns SingleLaunchInputs, or None when a
@@ -852,17 +1000,14 @@ def prepare_single_launch_inputs(sets: list[SignatureSet]):
     with telemetry.phase("bls.parse"):
         t0 = time.monotonic_ns()
         size = _pad_pow2(n)
-        parsed = _parse_host_arrays(sets, size)
+        parsed = _parse_launch_rows(sets, size, table)
         if parsed is None:
             _note_prep("single_launch", n, t0, rejected=True)
             return None
-        pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi = parsed
-        struct = pk_struct & sig_struct
+        arrays, indexed = parsed
         bits, mask = _blinding_and_mask(n, size)
         _note_prep("single_launch", n, t0)
-        return SingleLaunchInputs(
-            list(sets), (pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi, struct), bits, mask, n
-        )
+        return SingleLaunchInputs(list(sets), arrays, bits, mask, n, table, indexed)
 
 
 _traced_launches: set = set()  # guarded by: _trace_lock (programs, by static arguments and shapes, whose trace JAX holds)
@@ -916,11 +1061,12 @@ def _verify_single_prepared(si: SingleLaunchInputs, device=None) -> bool:
     prep to host prep, the full staged-jit miscompile chain."""
     try:
         v, bvld = _dispatch_launch(
-            _single_launch_verify, "single-launch", (), *si.arrays, si.bits, si.mask, device=device
+            _single_launch_verify, "single-launch", (), *si.arrays, si.bits, si.mask,
+            *_indexed_args(si, device), device=device,
         )
     except Exception as e:  # degrade to the split schedule, never resolve here
         _note_single_launch_fallback(e)
-        return _verify_sets_split(si.sets, device)
+        return _verify_sets_split(si.sets, device, si.table)
     if not bool(bvld):
         m = _prep_metrics
         if m is not None:
@@ -928,14 +1074,14 @@ def _verify_single_prepared(si: SingleLaunchInputs, device=None) -> bool:
     return bool(v)
 
 
-def verify_sets_single_launch(sets: list[SignatureSet], device=None) -> bool:
+def verify_sets_single_launch(sets: list, device=None, table=None) -> bool:
     """End-to-end single-launch batch verify: compressed bytes in, ONE
     counted device dispatch (`ops.prep.SINGLE_LAUNCH_BUDGET`), verdict
     out — verdicts identical to `verify_signature_sets_device` on the
     same sets. Host-parse rejects cost zero dispatches; device errors
     degrade per-batch to the split schedule."""
     try:
-        si = prepare_single_launch_inputs(sets)
+        si = prepare_single_launch_inputs(sets, table)
     except Exception as e:
         # a host-parse ERROR (not a structural reject) degrades to the
         # split schedule like any other single-launch fault — the split
@@ -943,7 +1089,7 @@ def verify_sets_single_launch(sets: list[SignatureSet], device=None) -> bool:
         # lands on host prep, so a poisoned batch can never raise out
         # of here and charge every lane's breaker in turn
         _note_single_launch_fallback(e)
-        return _verify_sets_split(sets, device)
+        return _verify_sets_split(sets, device, table)
     if si is None:
         return False
     return _verify_single_prepared(si, device)
@@ -954,17 +1100,20 @@ class GroupedLaunchInputs:
     came, the parsed arrays of `groups` slots of `slot` rows, blinding
     bits and mask a slot, and `riding`, the indices of the jobs that got
     a slot in slot order (a job with a wrong-length encoding is False at
-    parse time and gets none)."""
+    parse time and gets none). `table` and `indexed` as
+    `SingleLaunchInputs`'."""
 
-    __slots__ = ("jobs", "arrays", "bits", "mask", "groups", "riding")
+    __slots__ = ("jobs", "arrays", "bits", "mask", "groups", "riding", "table", "indexed")
 
-    def __init__(self, jobs, arrays, bits, mask, groups, riding):
+    def __init__(self, jobs, arrays, bits, mask, groups, riding, table=None, indexed=None):
         self.jobs = jobs
         self.arrays = arrays  # as SingleLaunchInputs.arrays, groups * slot rows
         self.bits = bits
         self.mask = mask
         self.groups = groups
         self.riding = riding
+        self.table = table
+        self.indexed = indexed
 
 
 def grouped_launch_groups(n_jobs: int) -> int:
@@ -973,7 +1122,16 @@ def grouped_launch_groups(n_jobs: int) -> int:
     return 2 if n_jobs <= 2 else 4
 
 
-def prepare_grouped_launch_inputs(jobs: list[list[SignatureSet]]) -> GroupedLaunchInputs:
+def _padding_row(s):
+    """A row to fill a slot with: a set's own message and signature, and
+    for an indexed set a byte pubkey in its signers' place (a padding
+    row is masked: its pubkey is never summed, resolved or counted)."""
+    if isinstance(s, IndexedSignatureSet):
+        return SignatureSet(pubkey=_FILLER_PUBKEY, message=s.message, signature=s.signature)
+    return s
+
+
+def prepare_grouped_launch_inputs(jobs: list[list], table=None) -> GroupedLaunchInputs:
     """Host byte stage of the multi-job launch: each job parsed into its
     own slot of the launch's rows, with its own blinding and mask — what
     `prepare_single_launch_inputs` makes of the job alone, side by side.
@@ -986,30 +1144,30 @@ def prepare_grouped_launch_inputs(jobs: list[list[SignatureSet]]) -> GroupedLaun
         rejected = len(riding) < len(jobs)
         if not riding:
             _note_prep("single_launch", sum(len(j) for j in jobs), t0, rejected=True)
-            return GroupedLaunchInputs(jobs, None, None, None, 0, riding)
+            return GroupedLaunchInputs(jobs, None, None, None, 0, riding, table)
         groups = grouped_launch_groups(len(riding))
         slot = telemetry.group_slot_rows(len(jobs[i]) for i in riding)
         # padding rows repeat a real row and are masked by every
         # consumer, an empty slot's rows too
-        filler = jobs[riding[0]][0]
+        filler = _padding_row(jobs[riding[0]][0])
         rows, bits, mask = [], [], []
         for g in range(groups):
             job = jobs[riding[g]] if g < len(riding) else []
-            rows += list(job) + [job[0] if job else filler] * (slot - len(job))
+            rows += list(job) + [_padding_row(job[0]) if job else filler] * (slot - len(job))
             job_bits, job_mask = _blinding_and_mask(len(job), slot)
             bits.append(job_bits)
             mask.append(job_mask)
-        pk_limbs, pk_sign, pk_struct, sig_limbs, sig_sign, sig_struct, lo, hi = (
-            _parse_host_arrays(rows, groups * slot)
-        )
+        arrays, indexed = _parse_launch_rows(rows, groups * slot, table)
         _note_prep("single_launch", n, t0, rejected=rejected)
         return GroupedLaunchInputs(
             jobs,
-            (pk_limbs, pk_sign, sig_limbs, sig_sign, lo, hi, pk_struct & sig_struct),
+            arrays,
             np.concatenate(bits),
             np.concatenate(mask),
             groups,
             riding,
+            table,
+            indexed,
         )
 
 
@@ -1023,11 +1181,12 @@ def _verify_grouped_prepared(gi: GroupedLaunchInputs, device=None) -> list[bool]
     try:
         v, bvld = _dispatch_launch(
             _grouped_launch_verify, "grouped-launch", (gi.groups,),
-            *gi.arrays, gi.bits, gi.mask, device=device, groups=gi.groups,
+            *gi.arrays, gi.bits, gi.mask, *_indexed_args(gi, device),
+            device=device, groups=gi.groups,
         )
     except Exception as e:  # degrade to one launch a job, never resolve here
         _note_single_launch_fallback(e)
-        return [verify_sets_single_launch(job, device) for job in gi.jobs]
+        return [verify_sets_single_launch(job, device, gi.table) for job in gi.jobs]
     m = _prep_metrics
     for g, i in enumerate(gi.riding):
         verdicts[i] = bool(v[g])
@@ -1036,15 +1195,15 @@ def _verify_grouped_prepared(gi: GroupedLaunchInputs, device=None) -> list[bool]
     return verdicts
 
 
-def verify_sets_grouped_launch(jobs: list[list[SignatureSet]], device=None) -> list[bool]:
+def verify_sets_grouped_launch(jobs: list[list], device=None, table=None) -> list[bool]:
     """Up to four jobs, ONE counted device dispatch, a verdict a job —
     each identical to `verify_sets_single_launch` on that job alone. A
     host-parse ERROR degrades to that road, a job at a time."""
     try:
-        gi = prepare_grouped_launch_inputs(jobs)
+        gi = prepare_grouped_launch_inputs(jobs, table)
     except Exception as e:
         _note_single_launch_fallback(e)
-        return [verify_sets_single_launch(job, device) for job in jobs]
+        return [verify_sets_single_launch(job, device, table) for job in jobs]
     return _verify_grouped_prepared(gi, device)
 
 
@@ -1069,7 +1228,7 @@ def verify_prepared(inputs, device=None) -> bool | list[bool]:
         return _verify_split_prepared(inputs)
 
 
-def prepare_inputs_for_lane(sets: list[SignatureSet], lane_index: int | None = None):
+def prepare_inputs_for_lane(sets: list, lane_index: int | None = None, table=None):
     """Pipeline prep stage: `build_device_inputs`, optionally pinned to
     a sibling chip (`jax.default_device`) so staging batch k+1 doesn't
     contend with the lane verifying batch k. A hint that doesn't resolve
@@ -1083,7 +1242,7 @@ def prepare_inputs_for_lane(sets: list[SignatureSet], lane_index: int | None = N
     parse-time structural reject stages None — a final verdict, still
     not a launch."""
     if single_launch_active():
-        return prepare_single_launch_inputs(sets)
+        return prepare_single_launch_inputs(sets, table)
     if lane_index is not None:
         try:
             dev = jax.devices()[lane_index]
@@ -1091,18 +1250,18 @@ def prepare_inputs_for_lane(sets: list[SignatureSet], lane_index: int | None = N
             dev = None
         if dev is not None:
             with jax.default_device(dev):
-                return build_device_inputs(sets)
-    return build_device_inputs(sets)
+                return build_device_inputs(sets, table=table)
+    return build_device_inputs(sets, table=table)
 
 
-def verify_signature_sets_sharded(sets: list[SignatureSet], mesh) -> bool:
+def verify_signature_sets_sharded(sets: list, mesh, table=None) -> bool:
     """End-to-end data-parallel batch verify over a device mesh."""
     n_dev = int(mesh.devices.size)
     n = len(sets)
     size = max(_pad_pow2(n), n_dev)
     if size % n_dev:
         size += n_dev - size % n_dev
-    inputs = build_device_inputs(sets, size=size)
+    inputs = build_device_inputs(sets, size=size, table=table)
     if inputs is None:
         return False
     pk, h, sig, bits, mask = inputs
@@ -1119,6 +1278,11 @@ def mesh_device_count() -> int:
     return len(jax.devices())
 
 
+def mesh_devices() -> list:
+    """The default backend's devices in `jax.devices()` order: lane i's chip."""
+    return list(jax.devices())
+
+
 def make_lane_verify_fn(device_index: int):
     """Single-device verify callable pinned to one chip: the per-lane
     backend of the mesh pool. The single launch is placed by its inputs
@@ -1126,8 +1290,8 @@ def make_lane_verify_fn(device_index: int):
     trace of each program and every lane lowers and compiles only for
     its own die; the split schedule rides `jax.default_device`."""
 
-    def lane_verify(sets: list[SignatureSet]) -> bool:
-        return verify_signature_sets_device(sets, jax.devices()[device_index])
+    def lane_verify(sets: list, table=None) -> bool:
+        return verify_signature_sets_device(sets, jax.devices()[device_index], table)
 
     lane_verify.__name__ = f"lane_verify_dev{device_index}"
     return lane_verify
@@ -1152,20 +1316,20 @@ def make_lane_verify_grouped_fn(device_index: int):
     """Multi-job twin of `make_lane_verify_fn`, pinned to one chip: a
     list of jobs in, one launch, a list of verdicts out."""
 
-    def lane_verify_grouped(jobs: list[list[SignatureSet]]) -> list[bool]:
-        return verify_sets_grouped_launch(jobs, jax.devices()[device_index])
+    def lane_verify_grouped(jobs: list[list], table=None) -> list[bool]:
+        return verify_sets_grouped_launch(jobs, jax.devices()[device_index], table)
 
     lane_verify_grouped.__name__ = f"lane_verify_grouped_dev{device_index}"
     return lane_verify_grouped
 
 
-def make_mesh_sharded_fn():
+def make_mesh_sharded_fn(table=None):
     """Collective verify callable over a lane subset: builds the jax
     Mesh for the given device indices and runs the data-parallel
     program. One executable is compiled (and memoized, see
     device_batch_verify_sharded) per (device subset, batch size)."""
 
-    def sharded_verify(sets: list[SignatureSet], device_indices) -> bool:
+    def sharded_verify(sets: list, device_indices) -> bool:
         from jax.sharding import Mesh
 
         devs = jax.devices()
@@ -1177,6 +1341,6 @@ def make_mesh_sharded_fn():
         if len(picked) < 2:
             raise ValueError("sharded verify needs at least two devices")
         mesh = Mesh(np.asarray(picked), ("data",))
-        return verify_signature_sets_sharded(sets, mesh)
+        return verify_signature_sets_sharded(sets, mesh, table)
 
     return sharded_verify

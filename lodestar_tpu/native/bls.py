@@ -17,9 +17,11 @@ from . import build_shared_lib
 
 __all__ = [
     "available",
+    "ready",
     "prepare_sets_native",
     "hash_to_g2_native",
     "g1_decompress_check_native",
+    "g1_decompress_limbs_native",
     "g2_decompress_check_native",
 ]
 
@@ -57,6 +59,10 @@ def _load():
             lib.bls_g1_decompress_check.restype = ctypes.c_int
             lib.bls_g2_decompress_check.argtypes = [u8p, u8p]
             lib.bls_g2_decompress_check.restype = ctypes.c_int
+            lib.bls_g1_decompress_limbs.argtypes = [
+                ctypes.c_uint64, u8p, i32p, u8p, ctypes.c_int, ctypes.c_int,
+            ]
+            lib.bls_g1_decompress_limbs.restype = None
             lib.bls_host_selftest.argtypes = []
             lib.bls_host_selftest.restype = ctypes.c_int
             if lib.bls_host_selftest() != 0:
@@ -71,6 +77,12 @@ def _load():
 
 def available() -> bool:
     return _load() is not None
+
+
+def ready() -> bool:
+    """Whether the library is loaded NOW: never waits for the build that
+    `available()` and the calls below wait for."""
+    return _lib is not None
 
 
 # Warm the build/load off the hot path: the first signature batch of a
@@ -148,6 +160,24 @@ def g1_decompress_check_native(data: bytes):
     x = int.from_bytes(out[:48].tobytes(), "big")
     y = int.from_bytes(out[48:].tobytes(), "big")
     return (x, y)
+
+
+def g1_decompress_limbs_native(pubkeys: bytes, n: int, check_subgroup: bool = True):
+    """`n` compressed G1 points (48 bytes each, concatenated) decoded to
+    device-layout mont limbs in one threaded call: ((n, 2, 33) int32 rows
+    of (x, y), (n,) bool ok), or None where the library is unavailable.
+    ok is False (and the row zero) for an encoding that is malformed,
+    the identity, off the curve or, with `check_subgroup`, outside G1."""
+    lib = _load()
+    if lib is None or len(pubkeys) != 48 * n:
+        return None
+    buf = np.frombuffer(pubkeys, dtype=np.uint8)
+    xy = np.empty((n, 2, 33), dtype=np.int32)
+    ok = np.empty(n, dtype=np.uint8)
+    lib.bls_g1_decompress_limbs(
+        ctypes.c_uint64(n), _u8(buf), _i32(xy), _u8(ok), int(check_subgroup), 0
+    )
+    return xy, ok.astype(bool)
 
 
 def g2_decompress_check_native(data: bytes):

@@ -19,6 +19,7 @@
 //
 // Build: g++ -O3 -std=c++17 -fPIC -shared -pthread bls_host.cpp
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
 #include <cstddef>
@@ -1061,6 +1062,48 @@ int bls_g2_decompress_check(const uint8_t* in96, uint8_t* out192) {
   fp_to_be48(out192 + 96, y.c0);
   fp_to_be48(out192 + 144, y.c1);
   return 0;
+}
+
+// Decompress n G1 points (48 bytes each) straight to device-layout mont
+// limbs, threaded: xy_out row i is (x, y), 2*33 int32; ok_out[i] is 1 for
+// a finite point on the curve (and, with check_subgroup, in the r-order
+// subgroup), else 0 with the row zeroed. The registry table's load
+// (chain/bls/pubkey_table.py): a trusted registry skips the subgroup
+// ladder, as the reference's index2pubkey deserialization does.
+void bls_g1_decompress_limbs(uint64_t n, const uint8_t* in, int32_t* xy_out,
+                             uint8_t* ok_out, int check_subgroup, int n_threads) {
+  if (n == 0) return;
+  if (n_threads <= 0) {
+    n_threads = (int)std::thread::hardware_concurrency();
+    if (n_threads <= 0) n_threads = 4;
+  }
+  const uint64_t chunk = 256;
+  uint64_t n_chunks = (n + chunk - 1) / chunk;
+  if ((uint64_t)n_threads > n_chunks) n_threads = (int)n_chunks;
+  std::atomic<uint64_t> next(0);
+  auto worker = [&]() {
+    for (;;) {
+      uint64_t c = next.fetch_add(1);
+      if (c >= n_chunks) return;
+      uint64_t end = std::min(n, (c + 1) * chunk);
+      for (uint64_t i = c * chunk; i < end; i++) {
+        g1p p;
+        bool ok = g1_decompress(p, in + 48 * i) == 0 && g1_on_curve(p.X, p.Y) &&
+                  (!check_subgroup || g1_in_subgroup(p));
+        ok_out[i] = ok ? 1 : 0;
+        if (ok) {
+          fp_to_device_limbs(xy_out + 66 * i, p.X);
+          fp_to_device_limbs(xy_out + 66 * i + 33, p.Y);
+        } else {
+          memset(xy_out + 66 * i, 0, 66 * sizeof(int32_t));
+        }
+      }
+    }
+  };
+  std::vector<std::thread> ts;
+  for (int t = 1; t < n_threads; t++) ts.emplace_back(worker);
+  worker();
+  for (auto& t : ts) t.join();
 }
 
 int bls_host_selftest(void) {

@@ -211,12 +211,28 @@ def _device_pool(opts: BeaconNodeOptions, metrics: BeaconMetrics):
     """The in-process device verifier as this node's options shape it."""
     from lodestar_tpu.chain.bls import BlsDeviceVerifierPool
 
-    return BlsDeviceVerifierPool(
+    pool = BlsDeviceVerifierPool(
         scheduler_enabled=opts.scheduler_enabled,
         sched_metrics=metrics.sched,
         mesh_mode=opts.bls_mesh,
         pipeline_metrics=metrics.bls_pipeline,
     )
+    if pool.pubkey_table is not None:
+        pool.pubkey_table.entries_gauge = metrics.bls_prep.table_entries
+    return pool
+
+
+def load_pubkey_table(bls, anchor_state) -> None:
+    """Fill a device verifier's pubkey table from the anchor state's
+    registry, where its lanes sum signers from it (the reference builds
+    `index2pubkey` from the anchor state the same way). The registry's
+    keys passed KeyValidate when they were deposited, so they are
+    decompressed without a second subgroup check (`trusted`)."""
+    if getattr(bls, "takes_indexed_sets", False):
+        validators = anchor_state.validators
+        bls.pubkey_table.extend(
+            [bytes(validators[i].pubkey) for i in range(len(validators))], trusted=True
+        )
 
 
 def _offload_verifier(opts: BeaconNodeOptions, metrics: BeaconMetrics) -> IBlsVerifier:
@@ -478,6 +494,7 @@ class BeaconNode:
         elif device_runtime["verifier"] == "device":
             bls = _device_pool(opts, metrics)
             device_runtime["lanes"] = len(bls.mesh)  # one a chip the pool serves on (--bls-mesh)
+            load_pubkey_table(bls, anchor_state)
         else:
             bls = BlsSingleThreadVerifier()
 

@@ -217,10 +217,10 @@ _OFF = _Off()
 
 
 class _Launch:
-    __slots__ = ("program", "size_class", "lane", "phases", "seq", "parent", "entry", "_t0", "_note")
+    __slots__ = ("program", "size_class", "lane", "attrs", "phases", "seq", "parent", "entry", "_t0", "_note")
 
-    def __init__(self, program: str, size_class: int, lane: str | None):
-        self.program, self.size_class, self.lane = program, size_class, lane
+    def __init__(self, program: str, size_class: int, lane: str | None, attrs: dict):
+        self.program, self.size_class, self.lane, self.attrs = program, size_class, lane, attrs
         self.phases: dict[str, float] = {}
         self.entry: dict | None = None
 
@@ -251,7 +251,7 @@ class _Launch:
         if exc_type is None:  # a dispatch that raised is the caller's fallback to count, not a launch
             self.entry = _record(
                 self.program, self.size_class, seconds, self.lane,
-                seq=self.seq, parent=self.parent, phases=self.phases,
+                seq=self.seq, parent=self.parent, phases=self.phases, attrs=self.attrs,
             )
         return False
 
@@ -281,15 +281,18 @@ class _Phase:
         return False
 
 
-def launch(program: str, size_class: int, lane: str | None = None):
+def launch(program: str, size_class: int, lane: str | None = None, **attrs):
     """`with launch(...)` around one device dispatch: a ledger entry and
     the metric observations for its extent (see `record_launch`), with
     the phases opened inside it on this thread, under a profiler span
     named `program`. `.entry` is the ledger entry after the block; a
-    block that raises writes none. Inactive: one flag check."""
+    block that raises writes none. `attrs` are further facts of the
+    launch, kept on its entry (`indexed_rows` of a verify launch); the
+    ones that are set say which variant of the program ran, so they
+    count for first-call detection. Inactive: one flag check."""
     if not launch_telemetry_active():
         return _OFF
-    return _Launch(program, size_class, lane)
+    return _Launch(program, size_class, lane, attrs)
 
 
 def phase(name: str, into: dict | None = None):
@@ -317,8 +320,11 @@ def record_launch(
     return _record(program, size_class, seconds, lane)
 
 
-def _record(program, size_class, seconds, lane, *, seq=None, parent=None, phases=None) -> dict:
-    """Compile detection is first-call-per-(program, size_class, lane):
+def _record(program, size_class, seconds, lane, *, seq=None, parent=None, phases=None, attrs=None) -> dict:
+    """Compile detection is first-call-per-(program, size_class, lane)
+    and, where the launch carries attributes, per set of those that are
+    set (a verify launch with indexed rows is another program than the
+    byte-only one of its size class):
     the jit caches hold one executable per key and chip, so the first
     dispatch of a key in this process carries trace+compile (or the
     persistent-cache load), a lane's first carries the lowering and the
@@ -328,6 +334,8 @@ def _record(program, size_class, seconds, lane, *, seq=None, parent=None, phases
     counter."""
     global _seq, _compiles
     key = (program, size_class, lane)
+    if attrs:
+        key += tuple(sorted(k for k, v in attrs.items() if v))
     with _lock:
         if seq is None:
             _seq += 1
@@ -348,6 +356,8 @@ def _record(program, size_class, seconds, lane, *, seq=None, parent=None, phases
             "parent": parent,
             "phases": phases if phases is not None else {},
         }
+        if attrs:
+            entry.update(attrs)
         _ledger.append(entry)
     m = _metrics
     if m is not None:

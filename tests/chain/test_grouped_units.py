@@ -435,7 +435,7 @@ def test_grouped_launch_is_one_ledger_entry_with_the_launch_rows_as_its_class(si
 def test_prep_package_and_verify_package_form_identical_units(monkeypatch):
     staged_jobs = []
 
-    def fake_grouped_prep(job_sets):
+    def fake_grouped_prep(job_sets, table=None):
         staged_jobs.append([len(s) for s in job_sets])
         return ("grouped-inputs", [_tag(s) for s in job_sets])
 
@@ -482,7 +482,7 @@ def test_the_staged_pipeline_serves_a_bulk_group_with_one_staged_multi_job_launc
     pool's own prep road, which hands a multi-job unit to
     `prepare_grouped_launch_inputs`."""
     monkeypatch.setattr(
-        bv, "prepare_grouped_launch_inputs", lambda job_sets: ("grouped-inputs", [len(s) for s in job_sets])
+        bv, "prepare_grouped_launch_inputs", lambda job_sets, table=None: ("grouped-inputs", [len(s) for s in job_sets])
     )
     prepared_seen = []
 
@@ -531,7 +531,7 @@ class StagedRig(Rig):
         self.parse_s, self.launch_s = parse_s, launch_s
         self.events: list[tuple[str, int]] = []
         self._lock = threading.Lock()
-        monkeypatch.setattr(bv, "prepare_grouped_launch_inputs", lambda jobs: self.parse("grouped", jobs))
+        monkeypatch.setattr(bv, "prepare_grouped_launch_inputs", lambda jobs, table=None: self.parse("grouped", jobs))
 
     def note(self, what: str, first_tag: int) -> None:
         with self._lock:

@@ -262,6 +262,20 @@ def test_validate_sync_committee_contribution(minimal_preset, sks, altair_state)
     for s in res.signature_sets:
         assert bls.verify(s.pubkey, s.message, s.signature)
 
+    # where the verifier holds the registry table, the contribution's set
+    # names its participants by index and resolves to the same set
+    from lodestar_tpu.chain.chain import BeaconChain
+
+    by_index_chain = _fake_chain(state, p, slot)
+    by_index_chain.indexed_sets = True
+    by_index_chain._pubkey2index = {}
+    by_index_chain.registry_indices = lambda st, pks: BeaconChain.registry_indices(by_index_chain, st, pks)
+    by_index = validate_sync_committee_contribution(by_index_chain, signed).signature_sets[2]
+    assert isinstance(by_index, bls.IndexedSignatureSet) and len(by_index.indices) == sum(contribution.aggregation_bits)
+    assert bls.resolve_signature_set(
+        by_index, lambda i: bytes(state.validators[i].pubkey)
+    ) == res.signature_sets[2]
+
     # duplicate aggregator -> IGNORE (after post-verify registration)
     res.register_seen()
     with pytest.raises(GossipValidationError, match="already seen"):

@@ -220,7 +220,7 @@ def test_three_jobs_ignore_the_empty_slot_and_a_rejected_job_is_false(sets, monk
 def test_a_shape_anomaly_degrades_to_one_launch_a_job(sets, monkeypatch, prep_metrics, program):
     served = []
     monkeypatch.setattr(bv, "_grouped_launch_verify", program)
-    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job, device=None: served.append(len(job)) or len(job) == 3)
+    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job, device=None, table=None: served.append(len(job)) or len(job) == 3)
     assert bv.verify_sets_grouped_launch([sets[:3], sets[3:5]]) == [True, False]
     assert served == [3, 2]
     assert prep_metrics.single_launch_fallbacks._value.get() == 1
@@ -233,7 +233,7 @@ def test_a_device_error_degrades_to_one_launch_a_job_counted_once(sets, monkeypa
 
     served = []
     monkeypatch.setattr(bv, "_grouped_launch_verify", boom)
-    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job, device=None: served.append(len(job)) or True)
+    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job, device=None, table=None: served.append(len(job)) or True)
     assert bv.verify_sets_grouped_launch(_jobs(sets, SIZES[slot][:3])) == [True, True, True]
     assert served == list(SIZES[slot][:3])
     assert prep_metrics.single_launch_fallbacks._value.get() == 1
@@ -245,7 +245,7 @@ def test_a_host_parse_error_degrades_to_one_launch_a_job(sets, monkeypatch, prep
 
     served = []
     monkeypatch.setattr(bv, "_parse_host_arrays", boom)
-    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job, device=None: served.append(len(job)) or True)
+    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda job, device=None, table=None: served.append(len(job)) or True)
     assert bv.verify_sets_grouped_launch([sets[:3], sets[3:5]]) == [True, True]
     assert served == [3, 2]
     assert prep_metrics.single_launch_fallbacks._value.get() == 1

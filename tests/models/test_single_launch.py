@@ -409,8 +409,8 @@ def test_the_device_entry_follows_the_backend(monkeypatch, accelerator):
 
     went = []
     monkeypatch.setattr(fp_pallas, "use_pallas", lambda: accelerator)
-    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda sets, device=None: went.append("single") or True)
-    monkeypatch.setattr(bv, "_verify_sets_split", lambda sets, device=None: went.append("split") or True)
+    monkeypatch.setattr(bv, "verify_sets_single_launch", lambda sets, device=None, table=None: went.append("single") or True)
+    monkeypatch.setattr(bv, "_verify_sets_split", lambda sets, device=None, table=None: went.append("split") or True)
     assert bv.single_launch_active() is accelerator
     assert bv.verify_signature_sets_device(bv.make_synthetic_sets(2, seed=167)) is True
     assert went == ["single" if accelerator else "split"]

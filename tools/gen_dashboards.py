@@ -214,6 +214,20 @@ def bls_pool():
             ],
             x=12, y=32, pid=10,
         ),
+        panel(
+            # the registry table the verify lanes sum indexed sets'
+            # signers from (chain/bls/pubkey_table.py): entries on each
+            # lane's chip, and the sets whose signers the host summed
+            # instead (more signers than a launch's index matrix has
+            # columns, or lanes without the table) — a device node's
+            # fallback series should read 0
+            "Pubkey table entries / host-aggregation fallbacks",
+            [
+                ("lodestar_bls_pubkey_table_entries", "entries {{lane}}"),
+                ("rate(lodestar_bls_aggregate_fallback_total[1m])", "host-aggregated sets/s"),
+            ],
+            x=0, y=40, pid=11,
+        ),
     ]
     return dashboard("lodestar-bls-pool", "Lodestar TPU - BLS verifier pool", ps, ["lodestar", "bls"])
 
