@@ -18,7 +18,10 @@ with **one device launch per tree level**, regardless of how many fields
 or subtrees went dirty in the slot. Batches are padded to power-of-two
 size classes (same compile-cache doctrine as `ops/prep.py`: one jitted
 program per class, shared by every caller, amortized by the persistent
-JAX cache).
+JAX cache). A level's pairs are gathered straight into the class's
+launch buffer, kept across flushes, byteswapped to words there, and its
+digests converted back into the class's roots buffer, from which they
+are scattered: one host pass each way and no fresh temporaries.
 
 Degradation doctrine (mirrors `chain/bls/fallback.py` and the BLS prep
 fallback): a device **error** degrades the whole flush to the CPU level
@@ -44,6 +47,7 @@ regression class; same doctrine as `ssz/hash.py`).
 from __future__ import annotations
 
 import functools
+import threading
 import time
 from typing import Iterable
 
@@ -90,9 +94,55 @@ _MIN_PAIR_CLASS = 8
 
 #: what a collector flush spends its wall on, as `DirtyCollector.steps`
 #: and the profiler's host spans name it: index arithmetic over the
-#: dirty set, gathering pairs into launch layout, the device call and
-#: the wait for it, writing roots back, and levels hashed on the host
+#: dirty set, gathering pairs into the launch buffer and turning them
+#: into words there, the device call and the wait for it, converting
+#: the digests and writing roots back, and levels hashed on the host
 FLUSH_STEPS = ("htr.index", "htr.gather", "htr.device", "htr.scatter", "htr.host_hash")
+
+
+class _LevelBuffers:
+    """One size class's launch buffers: `pairs` (size, 64) uint8, a
+    level's pairs gathered row by row (the rows after them hold whatever
+    an earlier level left: their digests are discarded), and `roots`
+    (size, 32) uint8, the digests converted back to bytes."""
+
+    __slots__ = ("size", "pairs", "roots")
+
+    def __init__(self, size: int):
+        self.size = size  # guarded by: flush-thread (held by one flush between take and give back)
+        self.pairs = np.zeros((size, 64), dtype=np.uint8)  # guarded by: flush-thread (same)
+        self.roots = np.zeros((size, 32), dtype=np.uint8)  # guarded by: flush-thread (same)
+
+
+#: launch buffers kept across flushes, at most one `_LevelBuffers` a
+#: size class (~50 MB at a 250,000-leaf flush of a 2^20 stack). A level
+#: takes its class's buffers out and gives them back once its roots are
+#: written, so two flushes on different threads never hold the same
+#: ones: the one that finds its class taken allocates its own.
+_launch_buffers: dict[int, _LevelBuffers] = {}  # guarded by: _launch_buffers_lock
+_launch_buffers_lock = threading.Lock()
+
+
+def _take_buffers(size: int) -> tuple[_LevelBuffers, bool]:
+    """The pool's buffers of a size class, or new ones where the pool
+    has none free; the flag says whether they were allocated."""
+    with _launch_buffers_lock:
+        bufs = _launch_buffers.pop(size, None)
+    if bufs is not None:
+        return bufs, False
+    return _LevelBuffers(size), True
+
+
+def _give_back(bufs: _LevelBuffers) -> None:
+    with _launch_buffers_lock:
+        _launch_buffers.setdefault(bufs.size, bufs)
+
+
+def _rows(a: np.ndarray, width: int) -> np.ndarray:
+    """(R, width) uint8 as R opaque rows: a view, so that one fancy
+    assignment moves whole rows."""
+    return a.view(np.dtype((np.void, width)))[:, 0]
+
 
 #: below this many pairs a level stays on the host hasher even when the
 #: device backend is selected — a tiny level is far cheaper as a couple
@@ -157,24 +207,31 @@ def pad_pow2_pairs(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
-def _device_level(data: np.ndarray, steps: dict | None = None) -> np.ndarray:
+def _device_level(
+    data: np.ndarray, steps: dict | None = None, bufs: _LevelBuffers | None = None
+) -> np.ndarray:
     """One merkle level on the device: (2N, 32) uint8 -> (N, 32) uint8,
-    padded to a power-of-two pair size class (pad pairs repeat pair 0 so
-    padding never manufactures new compile shapes or NaN-style hazards —
-    their digests are computed and discarded). `steps` is the collector's
-    tally of a flush (see `DirtyCollector.steps`); without one the step
-    seconds go to the `merkle_level` ledger entry's `phases`."""
+    launched at a power-of-two pair size class. The pad pairs after the
+    N are whatever bytes the class's launch buffer holds: their digests
+    are computed and discarded, so any initialized bytes will do, and
+    padding never manufactures a new compile shape. A flush hands in
+    `bufs` with the N pairs already gathered into `bufs.pairs[:N]`
+    (`data` is that view) and gets back a view of `bufs.roots`, valid
+    until the buffers go back to the pool; without it the pairs are
+    copied into the pool's buffers and the roots come back as a fresh
+    array. `steps` is the collector's tally of a flush (see
+    `DirtyCollector.steps`); without one the step seconds go to the
+    `merkle_level` ledger entry's `phases`."""
     global _launch_count
     from lodestar_tpu.ops import sha256 as ops
 
     n = data.shape[0] // 2
     size = pad_pow2_pairs(n)
-    if size != n:
+    own = bufs is None
+    if own:
         with telemetry.phase("htr.gather", into=steps):
-            padded = np.empty((2 * size, 32), dtype=np.uint8)
-            padded[: 2 * n] = data
-            padded[2 * n :] = np.tile(data[:2], (size - n, 1))
-            data = padded
+            bufs, _ = _take_buffers(size)
+            bufs.pairs[:n] = data.reshape(n, 64)
     _launch_count += 1
     m = _htr_metrics
     if m is not None:
@@ -186,12 +243,22 @@ def _device_level(data: np.ndarray, steps: dict | None = None) -> np.ndarray:
     # pair count (the compiled program's shape bucket)
     with telemetry.launch("merkle_level", size):
         with telemetry.phase("htr.gather", into=steps):
-            words = ops.words_from_bytes(data.tobytes())
+            # the program reads big-endian words: swap the pairs in place
+            words = bufs.pairs.view(np.uint32)
+            words[:n].byteswap(inplace=True)
         with telemetry.phase("htr.device", into=steps):
-            out = np.asarray(ops.merkle_level(words))
+            # np.asarray waits for the program, so the buffer is free
+            # again; the fetched words may be a read-only view of the
+            # program's output, and nothing writes into them
+            out = np.asarray(ops.merkle_level(words.reshape(2 * size, 8)))
     with telemetry.phase("htr.scatter", into=steps):
-        roots = np.frombuffer(ops.bytes_from_words(out), dtype=np.uint8).reshape(-1, 32)
-    return roots[:n]
+        bufs.roots.view(">u4")[:n] = out[:n]  # back to bytes in one pass
+        del out
+    roots = bufs.roots[:n]
+    if own:
+        roots = roots.copy()
+        _give_back(bufs)
+    return roots
 
 
 def note_fallback(err: Exception, where: str = "flush") -> None:
@@ -274,6 +341,7 @@ class DirtyCollector:
         self.launches = 0  # guarded by: flush-thread (per-call instance, single owner)
         self.levels = 0  # guarded by: flush-thread (per-call instance, single owner)
         self.dirty_chunks = 0  # guarded by: flush-thread (per-call instance, single owner)
+        self.buffers_allocated = 0  # guarded by: flush-thread (per-call instance, single owner)
         self.backend = "cpu"  # guarded by: flush-thread (per-call instance, single owner)
         # seconds by FLUSH_STEPS name while launch telemetry is active, else empty
         self.steps: dict[str, float] = {}  # guarded by: flush-thread (per-call instance, single owner)
@@ -281,10 +349,19 @@ class DirtyCollector:
     # -- feeding ---------------------------------------------------------------
 
     def add_stack_job(self, levels: list[np.ndarray], dirty: Iterable[int]) -> None:
+        """A retained level stack (each level half the rows of the one
+        below) and its dirty leaf rows, as an array or any iterable of
+        ints, in any order, repeats allowed."""
         with telemetry.phase("htr.index", into=self.steps):
-            dirty = np.asarray(sorted(set(int(i) for i in dirty)), dtype=np.int64)
+            if isinstance(dirty, np.ndarray):
+                dirty = np.asarray(dirty, dtype=np.int64)
+            else:
+                dirty = np.fromiter(dirty, dtype=np.int64)
+            dirty = np.unique(dirty)
         if dirty.size == 0:
             return
+        if dirty[0] < 0 or dirty[-1] >= levels[0].shape[0]:
+            raise IndexError(f"dirty leaves {dirty[0]}..{dirty[-1]} outside {levels[0].shape[0]} rows")
         self.dirty_chunks += int(dirty.size)
         self.stack_jobs.append(_StackJob(levels, dirty))
 
@@ -321,60 +398,64 @@ class DirtyCollector:
         max_level = self._max_level()
         self.levels = max_level
         steps = self.steps
-        # per stack job: dirty node indices at the current level
+        # per stack job: dirty node indices at the current level, sorted
         frontiers = [j.dirty for j in self.stack_jobs]
         for lvl in range(1, max_level + 1):
-            sinks: list[tuple] = []  # ("stack", job, parents) | ("node", nodes)
+            # (levels of a stack job, parents) | (None, nodes), in launch order
+            sinks: list[tuple] = []
             with telemetry.phase("htr.index", into=steps):
-                picks: list[tuple[np.ndarray, np.ndarray]] = []  # (level below, its dirty pairs' rows)
                 for ji, job in enumerate(self.stack_jobs):
-                    if lvl >= len(job.levels) or frontiers[ji].size == 0:
+                    f = frontiers[ji]
+                    if lvl >= len(job.levels) or f.size == 0:
                         continue
-                    parents = np.unique(frontiers[ji] >> 1)
-                    pair_idx = np.empty(2 * parents.size, dtype=np.int64)
-                    pair_idx[0::2] = 2 * parents
-                    pair_idx[1::2] = 2 * parents + 1
-                    picks.append((job.levels[lvl - 1], pair_idx))
-                    sinks.append(("stack", ji, parents))
+                    p = f >> 1
+                    parents = p[np.r_[True, p[1:] != p[:-1]]]  # f is sorted: drop repeats
+                    sinks.append((job.levels, parents))
                     frontiers[ji] = parents
-            with telemetry.phase("htr.gather", into=steps):
-                chunks = [below[pair_idx] for below, pair_idx in picks]
                 for job in self.node_jobs:
                     nodes = job.groups.get(lvl)
-                    if not nodes:
-                        continue
-                    data = np.empty((2 * len(nodes), 32), dtype=np.uint8)
-                    for i, n in enumerate(nodes):
-                        data[2 * i] = np.frombuffer(n.left._root, dtype=np.uint8)
-                        data[2 * i + 1] = np.frombuffer(n.right._root, dtype=np.uint8)
-                    chunks.append(data)
-                    sinks.append(("node", nodes))
-                if not chunks:
-                    continue
-                data = chunks[0] if len(chunks) == 1 else np.concatenate(chunks, axis=0)
+                    if nodes:
+                        sinks.append((None, nodes))
+                n = sum(len(rows) for _, rows in sinks)
+            if n == 0:
+                continue
+            with telemetry.phase("htr.gather", into=steps):
+                bufs, allocated = _take_buffers(pad_pow2_pairs(n))
+                self.buffers_allocated += 2 * allocated
+                off = 0
+                for levels, rows in sinks:
+                    out = bufs.pairs[off : off + len(rows)]
+                    if levels is not None:
+                        # a pair is two adjacent rows: one 64-byte row of the level below
+                        np.take(levels[lvl - 1].reshape(-1, 64), rows, axis=0, out=out, mode="clip")
+                    else:
+                        out[:] = np.frombuffer(
+                            b"".join(r for nd in rows for r in (nd.left._root, nd.right._root)),
+                            dtype=np.uint8,
+                        ).reshape(-1, 64)
+                    off += len(rows)
+                data = bufs.pairs[:n].reshape(2 * n, 32)
             # the size asymmetry applies per level even on the device
             # pass: a sparse flush's 1-2-pair tail levels are far
             # cheaper as host digests than as padded dispatches (the
             # invariant is "at most one DEVICE launch per level", so
             # host-hashing a tiny level only tightens it)
-            if count_launches and data.shape[0] // 2 >= _min_flush_pairs():
-                roots = level_fn(data)  # `_device_level` names its own steps
+            if count_launches and n >= _min_flush_pairs():
+                roots = level_fn(data, bufs=bufs)  # `_device_level` names its own steps
                 self.launches += 1
             else:
                 with telemetry.phase("htr.host_hash", into=steps):
                     roots = hash_nodes_cpu(data) if count_launches else level_fn(data)
             with telemetry.phase("htr.scatter", into=steps):
                 off = 0
-                for sink in sinks:
-                    if sink[0] == "stack":
-                        _, ji, parents = sink
-                        self.stack_jobs[ji].levels[lvl][parents] = roots[off : off + parents.size]
-                        off += parents.size
+                for levels, rows in sinks:
+                    if levels is not None:
+                        _rows(levels[lvl], 32)[rows] = _rows(roots[off : off + len(rows)], 32)
                     else:
-                        _, nodes = sink
-                        for i, n in enumerate(nodes):
-                            n._root = roots[off + i].tobytes()
-                        off += len(nodes)
+                        for i, nd in enumerate(rows):
+                            nd._root = roots[off + i].tobytes()
+                    off += len(rows)
+            _give_back(bufs)  # a level that raised keeps its buffers out of the pool
 
     def flush(self) -> dict:
         """One collector flush: at most one `hash_pairs` dispatch per
@@ -383,10 +464,13 @@ class DirtyCollector:
         partially-grafted device roots are overwritten, never trusted).
         Returns the flush stats for span/metric attribution; `steps` is
         where `seconds` (and `add_stack_job` before it) went, by
-        FLUSH_STEPS name, and empty while launch telemetry is inactive."""
+        FLUSH_STEPS name, and empty while launch telemetry is inactive;
+        `buffers_allocated` counts the launch buffers the flush could not
+        take from the pool (0 once every size class it uses is there)."""
         t0 = time.monotonic()
         device = device_htr_active()
         self.launches = 0
+        self.buffers_allocated = 0
         if telemetry.launch_telemetry_active():
             for name in FLUSH_STEPS:
                 self.steps.setdefault(name, 0.0)
@@ -409,6 +493,7 @@ class DirtyCollector:
             "levels": self.levels,
             "launches": self.launches,
             "dirty_chunks": self.dirty_chunks,
+            "buffers_allocated": self.buffers_allocated,
             "seconds": time.monotonic() - t0,
             "steps": self.steps,
         }
